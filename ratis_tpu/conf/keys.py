@@ -1129,8 +1129,6 @@ class RaftServerConfigKeys:
         MAX_PEERS_DEFAULT = 8
         SCALAR_FALLBACK_THRESHOLD_KEY = "raft.tpu.engine.scalar-fallback-threshold"
         SCALAR_FALLBACK_THRESHOLD_DEFAULT = 16  # below this many groups, skip device dispatch
-        PLATFORM_KEY = "raft.tpu.engine.platform"
-        PLATFORM_DEFAULT = ""  # "" = jax default platform
         # Shard the resident engine state over this many local devices
         # (jax.sharding.Mesh over the group axis; ratis_tpu.parallel.mesh).
         # 0 = single-device.  Each device owns one contiguous slice of the

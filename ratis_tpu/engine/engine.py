@@ -51,10 +51,9 @@ def _shared_tally():
     tallied in one ops.quorum.tally_votes dispatch per tick."""
     global _SHARED_TALLY
     if _SHARED_TALLY is None:
-        import jax
-
         from ratis_tpu.ops import quorum as q
-        _SHARED_TALLY = jax.jit(q.tally_votes)
+        from ratis_tpu.util.jaxenv import jit
+        _SHARED_TALLY = jit(q.tally_votes)
     return _SHARED_TALLY
 
 
@@ -62,13 +61,12 @@ def _shared_step():
     """Process-wide jitted resident step (see QuorumEngine._kernels)."""
     global _SHARED_STEP
     if _SHARED_STEP is None:
-        import jax
-
         from ratis_tpu.ops import quorum as q
+        from ratis_tpu.util.jaxenv import jit
         # Donating the DeviceState keeps the [G, P] batch resident on
         # device: each tick consumes the old buffers and returns new ones
         # without a host round-trip.
-        _SHARED_STEP = jax.jit(q.engine_step_resident, donate_argnums=(0,))
+        _SHARED_STEP = jit(q.engine_step_resident, donate_argnums=(0,))
     return _SHARED_STEP
 
 
@@ -76,11 +74,10 @@ def _shared_fast_step():
     """Zero-dirty steady-state variant: packed events in, packed outs back."""
     global _SHARED_FAST_STEP
     if _SHARED_FAST_STEP is None:
-        import jax
-
         from ratis_tpu.ops import quorum as q
-        _SHARED_FAST_STEP = jax.jit(q.engine_step_resident_fast,
-                                    donate_argnums=(0,))
+        from ratis_tpu.util.jaxenv import jit
+        _SHARED_FAST_STEP = jit(q.engine_step_resident_fast,
+                                donate_argnums=(0,))
     return _SHARED_FAST_STEP
 
 
@@ -306,6 +303,11 @@ class QuorumEngine:
         # monotonic time of the last completed tick (engine freshness for
         # the /health endpoint); None until the loop runs once
         self.last_tick_monotonic: Optional[float] = None
+        # The exception that killed the tick loop (None while it lives).
+        # Commits advance inline at ack intake without the loop, so a dead
+        # loop does not stop the server acknowledging writes — /health and
+        # any harness read this instead of inferring it from staleness.
+        self.failure: Optional[BaseException] = None
         # Real metric registry ("engine" component); engine.metrics keeps
         # the historical dict read surface over it.
         self._m = EngineMetrics(
@@ -713,12 +715,11 @@ class QuorumEngine:
         self._home_loop = asyncio.get_running_loop()
         if self.profile_dir and QuorumEngine._profiling_owner is None:
             import jax
-            try:
-                jax.profiler.start_trace(self.profile_dir)
-                QuorumEngine._profiling_owner = self
-                LOG.info("engine profiling -> %s", self.profile_dir)
-            except Exception:
-                LOG.exception("could not start jax profiler trace")
+
+            # a server asked to profile that cannot must not start untraced
+            jax.profiler.start_trace(self.profile_dir)
+            QuorumEngine._profiling_owner = self
+            LOG.info("engine profiling -> %s", self.profile_dir)
         self._task = asyncio.create_task(self._run(), name="quorum-engine")
 
     async def close(self) -> None:
@@ -744,7 +745,26 @@ class QuorumEngine:
         self._m.unregister()
         self.ledger.unregister()
 
+    @property
+    def tick_alive(self) -> bool:
+        """The tick loop task exists and has not ended."""
+        return self._task is not None and not self._task.done()
+
     async def _run(self) -> None:
+        try:
+            await self._run_ticks()
+        except Exception as e:
+            # A tick that raises (a program the compiler refuses, a
+            # donation or sharding error, device memory) ends election
+            # timeouts, staleness sweeps and vote tallies for every hosted
+            # group while inline commits carry on: say so at once, and
+            # leave the cause where the server's health surface reads it.
+            self.failure = e
+            LOG.exception("quorum engine tick loop died; elections and "
+                          "staleness sweeps for %d hosted groups have "
+                          "stopped", len(self.state.active))
+
+    async def _run_ticks(self) -> None:
         loop = asyncio.get_running_loop()
         while self._running:
             if self._wake.is_set():
@@ -1049,6 +1069,11 @@ class QuorumEngine:
         # bigger batch mid-run would be a fresh shape = a synchronous
         # multi-second compile on the event loop
         self._event_bucket_cap = max(self._bucket(ec) for ec in event_counts)
+        # Resident state first: a tick that finds no device copy uploads one
+        # and absorbs the dirty rows, which turned the grid's first entry
+        # into a fast tick and left that refresh shape — the smallest, the
+        # one a server hits first — to compile on the event loop mid-run.
+        self._dev = self._upload_device_state()
         for dc in group_counts:
             if dc > s.capacity:
                 continue
@@ -1095,7 +1120,7 @@ class QuorumEngine:
         """Pad size for event/dirty batches: 64 * 4^k.  Coarser than plain
         pow2 so the jit compiles O(few) shape buckets instead of one per
         power of two — padding costs bytes, recompiles cost tens of
-        milliseconds (CPU) to tens of seconds (remote TPU)."""
+        milliseconds (CPU) to seconds (TPU)."""
         b = 64
         while b < n:
             b *= 4

@@ -49,11 +49,10 @@ def _jitted_pass(num_peers: int, mesh=None):
         else:
             import functools
 
-            import jax
-
             from ratis_tpu.ops import ledger as ops
-            fn = jax.jit(functools.partial(ops.ledger_pass,
-                                           num_peers=num_peers))
+            from ratis_tpu.util.jaxenv import jit
+            fn = jit(functools.partial(ops.ledger_pass,
+                                       num_peers=num_peers))
         _JITTED[key] = fn
     return fn
 
@@ -105,6 +104,9 @@ class LagLedger:
         self._prev_commit = np.full(engine.state.capacity, -1, np.int32)
         self._prev_gen = np.full(engine.state.capacity, -1, np.int32)
         self.last_sample: Optional[LedgerSample] = None
+        # what the last jitted pass raised (None once a pass succeeds):
+        # samplers log and carry on, so /health reads the cause here
+        self.failure: Optional[BaseException] = None
         info = MetricRegistryInfo(prefix=prefix, application="ratis",
                                   component="engine", name="lag_ledger")
         self.registry = MetricRegistries.global_registries().create(info)
@@ -171,12 +173,17 @@ class LagLedger:
         prev_valid = self._prev_gen == gen
         from ratis_tpu.ops.ledger import LAG_BUCKETS, pack_slices
         t0 = time.perf_counter()
-        packed = np.asarray(_jitted_pass(width, self.engine.mesh)(
-            st.role, st.match_index, commit, st.applied_index,
-            st.conf_cur, st.conf_old, st.self_mask, st.last_ack_ms,
-            st.peer_index, self._prev_commit, prev_valid,
-            np.int32(now), np.int32(self.lag_threshold),
-            np.int32(self.up_window_ms)))
+        try:
+            packed = np.asarray(_jitted_pass(width, self.engine.mesh)(
+                st.role, st.match_index, commit, st.applied_index,
+                st.conf_cur, st.conf_old, st.self_mask, st.last_ack_ms,
+                st.peer_index, self._prev_commit, prev_valid,
+                np.int32(now), np.int32(self.lag_threshold),
+                np.int32(self.up_window_ms)))
+        except Exception as e:
+            self.failure = e
+            raise
+        self.failure = None
         elapsed_s = time.perf_counter() - t0
         self.fetch_timer.update(elapsed_s)
         self._prev_commit = commit

@@ -5,10 +5,8 @@ from ratis_tpu.parallel.mesh import (GROUP_AXIS, device_state_shardings,
                                      engine_shardings, make_group_mesh,
                                      shard_batch, shard_device_state,
                                      sharded_engine_step,
-                                     sharded_resident_fast_step,
                                      sharded_resident_step)
 
 __all__ = ["GROUP_AXIS", "device_state_shardings", "engine_shardings",
            "make_group_mesh", "shard_batch", "shard_device_state",
-           "sharded_engine_step", "sharded_resident_fast_step",
-           "sharded_resident_step"]
+           "sharded_engine_step", "sharded_resident_step"]

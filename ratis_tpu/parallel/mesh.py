@@ -76,12 +76,11 @@ def engine_shardings(mesh):
 
 def sharded_engine_step(mesh):
     """jit(engine_step) with the group axis sharded over ``mesh``."""
-    import jax
-
     from ratis_tpu.ops.quorum import engine_step
+    from ratis_tpu.util.jaxenv import jit
     in_shardings, out_shardings = engine_shardings(mesh)
-    return jax.jit(engine_step, in_shardings=in_shardings,
-                   out_shardings=out_shardings)
+    return jit(engine_step, in_shardings=in_shardings,
+               out_shardings=out_shardings)
 
 
 def device_state_shardings(mesh):
@@ -100,25 +99,6 @@ def device_state_shardings(mesh):
         election_deadline_ms=grp)
 
 
-def sharded_resident_fast_step(mesh):
-    """jit(engine_step_resident_fast) with the DeviceState sharded over the
-    group axis, donated (the PRODUCTION steady-state tick, not the
-    stateless engine_step toy): packed events + meta replicate; the [4, G]
-    packed output shards its group axis."""
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from ratis_tpu.ops.quorum import ResidentFastStep, engine_step_resident_fast
-    repl = NamedSharding(mesh, P())
-    out_grp = NamedSharding(mesh, P(None, GROUP_AXIS))
-    return jax.jit(
-        engine_step_resident_fast,
-        in_shardings=(device_state_shardings(mesh), repl, repl),
-        out_shardings=ResidentFastStep(device_state_shardings(mesh),
-                                       out_grp),
-        donate_argnums=(0,))
-
-
 def sliced_event_sharding(mesh):
     """Sharding for the [7, S, E] pre-routed event planes of
     :func:`ratis_tpu.ops.quorum.engine_step_resident_fast_sliced`: the
@@ -130,19 +110,19 @@ def sliced_event_sharding(mesh):
 
 def sharded_resident_fast_step_sliced(mesh):
     """jit(engine_step_resident_fast_sliced) over ``mesh``: DeviceState
-    sharded + donated as in :func:`sharded_resident_fast_step`, but events
-    arrive slice-routed ([7, S, E], slice axis sharded) instead of
-    replicated — the production mesh tick.  Each device scatters only the
-    E/S event columns that target rows it owns; the partitioner keeps the
-    whole step collective-free."""
-    import jax
+    sharded over the group axis + donated, events slice-routed ([7, S, E],
+    slice axis sharded) instead of replicated, the [4, G] packed output
+    sharded on its group axis — the production mesh tick.  Each device
+    scatters only the E/S event columns that target rows it owns; the
+    partitioner keeps the whole step collective-free."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from ratis_tpu.ops.quorum import (ResidentFastStep,
                                       engine_step_resident_fast_sliced)
+    from ratis_tpu.util.jaxenv import jit
     repl = NamedSharding(mesh, P())
     out_grp = NamedSharding(mesh, P(None, GROUP_AXIS))
-    return jax.jit(
+    return jit(
         engine_step_resident_fast_sliced,
         in_shardings=(device_state_shardings(mesh),
                       sliced_event_sharding(mesh), repl),
@@ -164,11 +144,10 @@ def sharded_resident_step(mesh):
     """jit(engine_step_resident): the dirty-row refresh variant of the
     resident tick, DeviceState sharded + donated; refresh rows and packed
     events replicate (the scatter by row index resolves locally)."""
-    import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from ratis_tpu.ops.quorum import (DeviceState, ResidentStep,
-                                      engine_step_resident)
+    from ratis_tpu.ops.quorum import ResidentStep, engine_step_resident
+    from ratis_tpu.util.jaxenv import jit
     repl = NamedSharding(mesh, P())
     grp = NamedSharding(mesh, P(GROUP_AXIS))
     state_sh = device_state_shardings(mesh)
@@ -176,8 +155,8 @@ def sharded_resident_step(mesh):
     # event arrays, now_ms, leadership_timeout_ms)
     in_shardings = (state_sh,) + (repl,) * 18
     out_shardings = ResidentStep(state_sh, grp, grp, grp, grp)
-    return jax.jit(engine_step_resident, in_shardings=in_shardings,
-                   out_shardings=out_shardings, donate_argnums=(0,))
+    return jit(engine_step_resident, in_shardings=in_shardings,
+               out_shardings=out_shardings, donate_argnums=(0,))
 
 
 def sharded_ledger_pass(mesh, num_peers: int):
@@ -191,10 +170,10 @@ def sharded_ledger_pass(mesh, num_peers: int):
     single-device pass; enforced in tests/test_lag_ledger.py)."""
     import functools
 
-    import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from ratis_tpu.ops.ledger import ledger_pass
+    from ratis_tpu.util.jaxenv import jit
     grp = NamedSharding(mesh, P(GROUP_AXIS))
     grp_peer = NamedSharding(mesh, P(GROUP_AXIS, None))
     repl = NamedSharding(mesh, P())
@@ -214,8 +193,8 @@ def sharded_ledger_pass(mesh, num_peers: int):
         repl,      # lag_threshold
         repl,      # up_window_ms
     )
-    return jax.jit(functools.partial(ledger_pass, num_peers=num_peers),
-                   in_shardings=in_shardings, out_shardings=repl)
+    return jit(functools.partial(ledger_pass, num_peers=num_peers),
+               in_shardings=in_shardings, out_shardings=repl)
 
 
 def shard_device_state(mesh, state):

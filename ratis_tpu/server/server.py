@@ -1047,7 +1047,9 @@ class RaftServer:
     def health_info(self) -> dict:
         """GET /health: liveness + engine tick freshness.  The engine tick
         is the server's heartbeat-of-heartbeats — a stale tick means every
-        hosted group's election/commit math is stalled."""
+        hosted group's election/commit math is stalled, and a tick loop or
+        ledger pass that raised is reported with its cause at once rather
+        than after the freshness bound."""
         import os
         import time as _time
         last = self.engine.last_tick_monotonic
@@ -1058,7 +1060,9 @@ class RaftServer:
         # 50x tolerates load, a floor of 2s tolerates tiny intervals)
         fresh_bound = max(2.0, 50 * self.engine.tick_interval_s)
         state = self.life_cycle.get_current_state().name
-        ok = (state == "RUNNING" and age is not None and age < fresh_bound)
+        failure = self.engine.failure or self.engine.ledger.failure
+        ok = (state == "RUNNING" and age is not None and age < fresh_bound
+              and failure is None)
         return {
             "status": "ok" if ok else "degraded",
             "peer": str(self.peer_id),
@@ -1074,6 +1078,7 @@ class RaftServer:
                 "groupsLive": len(self.engine.state.active),
                 "groupsCapacity": self.engine.state.capacity,
                 "meshSlices": self.engine.state.n_slices,
+                "failure": None if failure is None else repr(failure),
             },
             "watchdogEvents": (self.watchdog.event_count()
                                if self.watchdog is not None else 0),

@@ -443,7 +443,8 @@ def test_bench_summary_line_fits_driver_window():
         mesh100k={"groups": 102400, "devices": 8,
                   "updates_per_s": 1333027867.9, "tick_ms": 99999.99,
                   "efficiency_frac": 0.999},
-        tpu_e2e={"dnf": True, "reason": "x" * 500},
+        tpu_e2e=rung(device={"platform": "tpu", "kind": "TPU v5 lite",
+                             "count": 1}),
         traced=rung(host_path_decomposition=decomp),
         filestore5=rung(streams_ok=32, stream_mb_per_s=99999.99),
         readmix=rung(reads_per_sec=123456.8, read_p99_ms=99999.99,
