@@ -28,9 +28,11 @@ merely assembles the servers):
 6. with >= 4 devices, the same on a mesh-sharded engine (5 x 1,024 groups,
    ``mesh-devices=4``, the DeviceState on 4 distinct devices).
 
-Exit 0 only if every check held; the last line of stdout is then one JSON
-object ``{"ok": true, "device": {...}, ...}`` (also written to
-``chiprun_out/chip_smoke.json``).  A failed check raises: no result line.
+Exit 0 only if every check held.  Then everything that was seen goes out as
+one ``RESULT {...}`` line (and to ``chiprun_out/chip_smoke.json``), and the
+last line of stdout is the verdict alone, one JSON object with exactly these
+keys: ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``,
+the device as JAX reports it.  A failed check raises: neither line.
 
 ``--rehearse-cpu`` rehearses the control flow on the CPU backend at a tiny
 size (5 x 64 groups; 4 virtual devices so the mesh phase runs too).  It is
@@ -505,14 +507,15 @@ def main(argv=None) -> None:
                           "jaxlib": jaxlib.__version__, "libtpu": libtpu,
                           "python": sys.version.split()[0]}
     result["compile_cache_dir"] = cache_dir
-    line = json.dumps(result, separators=(",", ":"))
     try:
         os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
         with open(args.out, "w") as f:
             json.dump(result, f, indent=1)
     except OSError as e:
         say(f"could not write {args.out}: {e}")
-    print(line, flush=True)
+    print("RESULT " + json.dumps(result, separators=(",", ":")), flush=True)
+    # the last line is the verdict and nothing else
+    print(json.dumps({"ok": True, "device": device}), flush=True)
     # every check has held and the result is out: end here, without
     # unwinding the served cluster
     os._exit(0)

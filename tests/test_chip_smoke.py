@@ -32,7 +32,8 @@ def _run_smoke(*args, cwd=REPO, timeout=600):
 
 
 def _result_lines(stdout: str) -> list:
-    return [ln for ln in stdout.splitlines() if ln.startswith("{")]
+    return [ln for ln in stdout.splitlines()
+            if ln.startswith(("{", "RESULT "))]
 
 
 def test_refuses_to_run_without_an_accelerator():
@@ -55,9 +56,17 @@ def test_explicit_cpu_rehearsal_passes_every_check(tmp_path):
     out = tmp_path / "smoke.json"
     proc = _run_smoke("--rehearse-cpu", "--out", str(out))
     assert proc.returncode == 0, (proc.stdout[-3000:], proc.stderr[-3000:])
-    last = json.loads(proc.stdout.splitlines()[-1])
+    lines = proc.stdout.splitlines()
+    # the last line is the verdict alone, to the key: the device as JAX
+    # reports it; everything that was seen is on the RESULT line before it
+    verdict = json.loads(lines[-1])
+    assert verdict == {"ok": True, "device": {
+        "platform": "cpu", "kind": verdict["device"]["kind"], "count": 4}}
+    assert isinstance(verdict["device"]["kind"], str)
+    assert lines[-2].startswith("RESULT ")
+    last = json.loads(lines[-2][len("RESULT "):])
     assert last == json.loads(out.read_text())
-    assert last["ok"] is True
+    assert last["ok"] is True and last["device"] == verdict["device"]
     assert last["rehearsal"] == "cpu" and last["device"]["platform"] == "cpu"
     for phase, mesh in (("served", 0), ("mesh", 4)):
         ph = last[phase]
