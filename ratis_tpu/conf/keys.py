@@ -950,9 +950,11 @@ class RaftServerConfigKeys:
         reference leans on JVM profilers): per-stage request->commit spans
         recorded into fixed-size ring buffers, exportable as a percentile
         decomposition table and Chrome trace-event JSON (Perfetto).  OFF by
-        default; when enabled, every ``sample-every``-th client request is
-        traced end to end and process-level stages (rpc codec, engine
-        dispatch) sample at the same rate."""
+        default; a session is open while ``enabled`` is set or while a
+        jax.profiler session is open in the process.  In a session every
+        ``sample-every``-th client request is traced end to end and
+        process-level stages (rpc codec, sweeps, log batches) sample every
+        ``sample-every``-th occurrence of their own."""
 
         ENABLED_KEY = "raft.tpu.trace.enabled"
         ENABLED_DEFAULT = False
@@ -1137,9 +1139,11 @@ class RaftServerConfigKeys:
         # rows stay masked invalid), so any max-groups value is legal.
         MESH_DEVICES_KEY = "raft.tpu.engine.mesh-devices"
         MESH_DEVICES_DEFAULT = 0
-        # When set, the engine runs inside a jax.profiler trace written to
-        # this directory (XLA device ops + one named step per tick, for
-        # TensorBoard/xprof).  Empty = no profiling.  SURVEY §5 tracing.
+        # When set, the server runs inside a jax.profiler session written
+        # to this directory from start() to close() (XLA device ops, and on
+        # the host plane the program's own ratis:* spans: a profiler session
+        # is a trace session, ratis_tpu.trace.profile_dir_session).  Empty =
+        # no profiling.  SURVEY §5 tracing.
         PROFILE_DIR_KEY = "raft.tpu.engine.profile-dir"
         PROFILE_DIR_DEFAULT = ""
 

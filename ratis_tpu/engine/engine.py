@@ -31,7 +31,9 @@ from ratis_tpu.engine.state import (GroupBatchState, NO_DEADLINE,
                                     ROLE_LEADER, ROLE_LISTENER, ROLE_UNUSED)
 from ratis_tpu.metrics.hops import hop
 from ratis_tpu.ops import reference as ref
-from ratis_tpu.trace.tracer import STAGE_ENGINE, TRACER
+from ratis_tpu.trace.tracer import (STAGE_ACK, STAGE_COLLECT, STAGE_ENGINE,
+                                    STAGE_FETCH, STAGE_LAUNCH, STAGE_PACK,
+                                    TRACER, Tiles)
 
 # keep in sync with ops.quorum.PACK_SENTINEL (not imported here: engine
 # import must not eagerly pull in jax)
@@ -211,6 +213,10 @@ class EngineListener(Protocol):
     async def on_leadership_stale(self) -> None: ...
 
 
+def _no_part(stage: int) -> None:
+    """:meth:`QuorumEngine._tick_batched_dispatch` outside a trace session."""
+
+
 class Clock:
     """Millisecond clock relative to a movable epoch (int32-friendly).
 
@@ -229,18 +235,12 @@ class Clock:
 
 
 class QuorumEngine:
-    # Which engine (if any) owns the process-wide jax profiler session:
-    # jax.profiler.start_trace is a singleton, and co-hosted servers each
-    # build an engine, so only the first profiled engine starts the trace.
-    _profiling_owner = None
-
     def __init__(self, max_groups: int = 1024, max_peers: int = 8,
                  tick_interval_s: float = 0.002,
                  scalar_fallback_threshold: int = 16,
                  leadership_timeout_ms: int = 300,
                  use_device: bool = False,
-                 mesh=None, profile_dir: Optional[str] = None,
-                 name: str = ""):
+                 mesh=None, name: str = ""):
         # Optional jax.sharding.Mesh: the PRODUCTION resident tick
         # (engine_step_resident / _fast_sliced, donated DeviceState) runs
         # sharded over the group axis — each device owns one contiguous
@@ -256,10 +256,6 @@ class QuorumEngine:
             # padded rows stay ROLE_UNUSED and cost nothing
             from ratis_tpu.parallel.mesh import pad_to_mesh
             max_groups = pad_to_mesh(max_groups, n_slices)
-        # SURVEY §5 tracing hook: when set, the engine runs inside a
-        # jax.profiler trace (XLA device ops + named tick steps) written to
-        # this directory for TensorBoard/xprof — raft.tpu.engine.profile-dir.
-        self.profile_dir = profile_dir
         self.state = GroupBatchState(max_groups, max_peers,
                                      n_slices=n_slices)
         self.clock = Clock()
@@ -402,11 +398,19 @@ class QuorumEngine:
             return
         if isinstance(rows, np.ndarray):
             rows = rows.tolist()
-        with self._lock:
-            now = self.clock.now_ms()
-            for slot, peer_slot, match_index in rows:
-                self._on_ack_locked(int(slot), int(peer_slot),
-                                    int(match_index), now)
+        # ack.intake work span: the mirror updates, the ring appends and
+        # the inline commits (with the divisions' commit callbacks) of one
+        # reply frame's rows
+        span = TRACER.begin(STAGE_ACK) if TRACER.enabled else None
+        try:
+            with self._lock:
+                now = self.clock.now_ms()
+                for slot, peer_slot, match_index in rows:
+                    self._on_ack_locked(int(slot), int(peer_slot),
+                                        int(match_index), now)
+        finally:
+            if span is not None:
+                TRACER.end(span, tag=len(rows))
 
     def _on_ack_locked(self, slot: int, peer_slot: int, match_index: int,
                        now: int) -> None:
@@ -464,9 +468,14 @@ class QuorumEngine:
         instead of N."""
         if not rows:
             return
-        with self._lock:
-            for slot, flush_index in rows:
-                self._on_flush_locked(int(slot), int(flush_index))
+        span = TRACER.begin(STAGE_ACK) if TRACER.enabled else None
+        try:
+            with self._lock:
+                for slot, flush_index in rows:
+                    self._on_flush_locked(int(slot), int(flush_index))
+        finally:
+            if span is not None:
+                TRACER.end(span, tag=len(rows))
 
     def _on_flush_locked(self, slot: int, flush_index: int) -> None:
         s = self.state
@@ -713,24 +722,10 @@ class QuorumEngine:
     async def start(self) -> None:
         self._running = True
         self._home_loop = asyncio.get_running_loop()
-        if self.profile_dir and QuorumEngine._profiling_owner is None:
-            import jax
-
-            # a server asked to profile that cannot must not start untraced
-            jax.profiler.start_trace(self.profile_dir)
-            QuorumEngine._profiling_owner = self
-            LOG.info("engine profiling -> %s", self.profile_dir)
         self._task = asyncio.create_task(self._run(), name="quorum-engine")
 
     async def close(self) -> None:
         self._running = False
-        if QuorumEngine._profiling_owner is self:
-            import jax
-            QuorumEngine._profiling_owner = None
-            try:
-                jax.profiler.stop_trace()
-            except Exception:
-                LOG.exception("could not stop jax profiler trace")
         if self._task is not None:
             self._wake.set()
             self._task.cancel()
@@ -783,18 +778,13 @@ class QuorumEngine:
                 except asyncio.TimeoutError:
                     pass
             self._wake.clear()
+            # the one place that watches for a profiler session starting or
+            # stopping in this process (ratis_tpu.trace: a trace session
+            # opens and closes with it); this loop wakes at least every
+            # tick interval
+            TRACER.poll()
             t0 = loop.time()
-            if QuorumEngine._profiling_owner is self:
-                # named step in the xprof timeline (one per dispatch).
-                # ONLY the owning engine annotates: co-hosted engines share
-                # one process-wide trace, and three interleaved step_num
-                # sequences would make xprof's per-step view meaningless.
-                import jax
-                with jax.profiler.StepTraceAnnotation(
-                        "engine_tick", step_num=self._m.ticks.count):
-                    await self.tick()
-            else:
-                await self.tick()
+            await self.tick()
             self.last_tick_monotonic = loop.time()
             cost = loop.time() - t0
             if cost > self.tick_interval_s:
@@ -987,8 +977,9 @@ class QuorumEngine:
         if check_stale:
             self._next_staleness_ms = now + max(
                 1, self.leadership_timeout_ms // 4)
-        trace_t0 = (TRACER.now() if touched
-                    and TRACER.enabled and TRACER.sample() else 0)
+        # engine.dispatch work span; the scalar pass has no device parts
+        span = (TRACER.begin(STAGE_ENGINE)
+                if touched and TRACER.enabled else None)
 
         for slot in list(s.active):
             role = s.role[slot]
@@ -1010,9 +1001,8 @@ class QuorumEngine:
             elif role == ROLE_FOLLOWER and now >= s.election_deadline_ms[slot]:
                 s.election_deadline_ms[slot] = NO_DEADLINE  # re-armed by div
                 changed.append((slot, "timeout", 0))
-        if trace_t0:
-            TRACER.record(0, STAGE_ENGINE, trace_t0, TRACER.now(),
-                          tag=len(touched))
+        if span is not None:
+            TRACER.end(span, tag=len(touched))
         return changed
 
     # -- batched path --------------------------------------------------------
@@ -1234,26 +1224,37 @@ class QuorumEngine:
         # exception path so a wedged backend shows up in the p99
         with self._m.dispatch_timer.time():
             self._m.ack_batch.update(len(acks))
-            return self._tick_batched_dispatch(acks, now)
+            if not TRACER.enabled:
+                return self._tick_batched_dispatch(acks, now)
+            # engine.dispatch host-path span (process-level; every dispatch
+            # of a session: one costs milliseconds and the sweep gate keeps
+            # them rare), tag = packed event count.  Its four parts tile
+            # it: engine.pack, engine.launch (uploads + the step call
+            # returning), engine.fetch (the outputs on the host: kernel +
+            # device->host), engine.collect.
+            tiles = Tiles(TRACER, STAGE_ENGINE)
+            try:
+                return self._tick_batched_dispatch(acks, now, tiles.part)
+            finally:
+                tiles.close(tag=len(acks))
 
-    def _tick_batched_dispatch(self, acks, now: int
+    def _tick_batched_dispatch(self, acks, now: int, part=_no_part
                                ) -> list[tuple[int, str, int]]:
+        """``part(stage)``: the dispatch enters that part of its span."""
         import jax.numpy as jnp
 
         s = self.state
         self._m.batched_dispatches.inc()
-        # engine.dispatch host-path span (process-level, sampled): the
-        # device round-trip cost per dispatch, tag = packed event count
-        trace_t0 = (TRACER.now()
-                    if TRACER.enabled and TRACER.sample() else 0)
 
         if self._dev is None or self._dev.match_index.shape != s.match_index.shape:
             # first batched tick / capacity regrow / epoch rebase: one full
             # upload, after which only dirty rows and events travel.
+            part(STAGE_LAUNCH)
             self._dev = self._upload_device_state()
             s.dirty.clear()
             self._slot_updates.clear()  # the full upload carried them
 
+        part(STAGE_PACK)
         if not s.dirty:
             # Fast path (the steady state under load): two packed uploads,
             # one packed download — profiling showed the unpacked step's 18
@@ -1268,17 +1269,16 @@ class QuorumEngine:
             ev = (self._pack_tick_sliced(acks, updates)
                   if self.mesh is not None
                   else self._pack_tick(acks, updates))
+            part(STAGE_LAUNCH)
             res = step(self._dev, jnp.asarray(ev),
                        jnp.asarray(np.array(
                            [now, self.leadership_timeout_ms], np.int32)))
             self._dev = res.state
+            part(STAGE_FETCH)
             out = np.asarray(res.out)
-            changed = self._collect_changed(out[0], out[1] != 0, out[2] != 0,
-                                            out[3] != 0)
-            if trace_t0:
-                TRACER.record(0, STAGE_ENGINE, trace_t0, TRACER.now(),
-                              tag=len(acks))
-            return changed
+            part(STAGE_COLLECT)
+            return self._collect_changed(out[0], out[1] != 0, out[2] != 0,
+                                         out[3] != 0)
 
         # dirty-row refresh: O(changed slots) host->device.  Slots with
         # queued packed updates fold in here — the mirror already holds
@@ -1306,6 +1306,7 @@ class QuorumEngine:
             evg[i], evp[i], evm[i], evt[i], evv[i] = slot, peer, match, t, True
 
         step = self._kernels()
+        part(STAGE_LAUNCH)
         res = step(
             self._dev,
             jnp.asarray(rf_idx), jnp.asarray(s.match_index[gi]),
@@ -1319,16 +1320,14 @@ class QuorumEngine:
             jnp.asarray(evt), jnp.asarray(evv),
             jnp.int32(now), jnp.int32(self.leadership_timeout_ms))
         self._dev = res.state
+        part(STAGE_FETCH)
 
         # downloads: only the [G] outputs (masks + commit values), never the
         # [G, P] state
-        changed = self._collect_changed(
-            np.asarray(res.new_commit), np.asarray(res.commit_changed),
-            np.asarray(res.timeouts), np.asarray(res.stale))
-        if trace_t0:
-            TRACER.record(0, STAGE_ENGINE, trace_t0, TRACER.now(),
-                          tag=len(acks))
-        return changed
+        outs = (np.asarray(res.new_commit), np.asarray(res.commit_changed),
+                np.asarray(res.timeouts), np.asarray(res.stale))
+        part(STAGE_COLLECT)
+        return self._collect_changed(*outs)
 
     def _collect_changed(self, new_commit_np, commit_changed_np, timeouts_np,
                          stale_np) -> list[tuple[int, str, int]]:

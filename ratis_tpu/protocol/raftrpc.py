@@ -700,20 +700,26 @@ def encode_rpc(msg) -> bytes:
     bytes): the per-commit msgpack cost of the server-to-server plane,
     measured where it is paid — fast-path encodes record through the same
     stage, so coalesced/spliced frames stay attributed."""
-    if TRACER.enabled and TRACER.sample():
-        t0 = TRACER.now()
-        b = _encode(msg)
-        TRACER.record(0, STAGE_ENCODE, t0, TRACER.now(), tag=len(b))
-        return b
+    if TRACER.enabled:
+        span = TRACER.begin(STAGE_ENCODE)
+        b = b""
+        try:
+            b = _encode(msg)
+            return b
+        finally:
+            if span is not None:
+                TRACER.end(span, tag=len(b))
     return _encode(msg)
 
 
 def decode_rpc(b: bytes):
-    if TRACER.enabled and TRACER.sample():
-        t0 = TRACER.now()
-        d = msgpack.unpackb(b, raw=False)
-        out = _MSG_TYPES[d["_"]].from_dict(d["b"])
-        TRACER.record(0, STAGE_DECODE, t0, TRACER.now(), tag=len(b))
-        return out
+    if TRACER.enabled:
+        span = TRACER.begin(STAGE_DECODE)
+        try:
+            d = msgpack.unpackb(b, raw=False)
+            return _MSG_TYPES[d["_"]].from_dict(d["b"])
+        finally:
+            if span is not None:
+                TRACER.end(span, tag=len(b))
     d = msgpack.unpackb(b, raw=False)
     return _MSG_TYPES[d["_"]].from_dict(d["b"])

@@ -57,9 +57,11 @@ from ratis_tpu.server.election import LeaderElection
 from ratis_tpu.server.leader import FollowerInfo, LeaderContext
 from ratis_tpu.server.state import ServerState
 from ratis_tpu.server.statemachine import StateMachine, TransactionContext
-from ratis_tpu.trace.tracer import (STAGE_APPEND, STAGE_APPLY, STAGE_FANOUT,
-                                    STAGE_REPLY, STAGE_REPLICATE, STAGE_TXN,
-                                    TRACER)
+from ratis_tpu.trace.tracer import (STAGE_APPEND, STAGE_APPLY,
+                                    STAGE_APPLY_QUEUE, STAGE_FANOUT,
+                                    STAGE_FLUSH_WAIT, STAGE_FOLLOWER,
+                                    STAGE_QUORUM_WAIT, STAGE_REPLICATE,
+                                    STAGE_REPLY, STAGE_TXN, TRACER)
 from ratis_tpu.util import injection
 
 LOG = logging.getLogger(__name__)
@@ -207,12 +209,14 @@ class Division:
         # per-client ordered-async reorder windows (leader only; see
         # _write_ordered)
         self._client_windows: dict = {}
-        # Host-path tracing: log index -> (trace_id, append-done ns) for
-        # sampled writes in flight between append and apply; _apply_one
-        # pops each to close the replicate span and open the apply span,
-        # then parks (trace_id, apply-done ns) in _trace_applied for the
-        # write handler to close the reply span when its future resumes.
-        self._trace_pending: dict[int, tuple[int, int]] = {}
+        # Host-path tracing: log index -> [trace_id, append-done ns,
+        # commit-covered ns, own flush seen] for sampled writes in flight
+        # between append and apply; the flush callback and the commit
+        # advance stamp the parts of server.replicate, _apply_one pops each
+        # to close the replicate span and open the apply span, then parks
+        # (trace_id, apply-done ns) in _trace_applied for the write handler
+        # to close the reply span when its future resumes.
+        self._trace_pending: dict[int, list] = {}
         self._trace_applied: dict[int, tuple[int, int]] = {}
         # Commit fan-out collapse (raft.tpu.replication.reply-fanout):
         # the apply loop resolves the batch's client waiters through ONE
@@ -549,6 +553,14 @@ class Division:
             self._apply_loop(), name=f"applier-{self.member_id}")
 
     def _on_log_flush(self, flush_index: int) -> None:
+        if self._trace_pending:
+            # server.flush_wait: append done -> this replica's own flush
+            # seen on the loop (sampled writes only; the dict is small)
+            now = TRACER.now()
+            for index, rec in self._trace_pending.items():
+                if index <= flush_index and not rec[3]:
+                    rec[3] = True
+                    TRACER.record(rec[0], STAGE_FLUSH_WAIT, rec[1], now)
         self._engine_update_flush()
 
     def _spawn_bg(self, coro) -> None:
@@ -862,20 +874,30 @@ class Division:
             return asyncio.get_running_loop().time() + 30.0
         return float("inf")
 
-    def on_commit_advance_now(self, new_commit: int) -> None:
+    def on_commit_advance_now(self, new_commit: int,
+                              by_tick: bool = False) -> None:
         """Engine advanced this group's commit (leader only).  Synchronous
         on purpose: the engine calls this INLINE from the ack intake path
         (QuorumEngine.on_ack) so a commit never waits for the tick task to
         win a turn on a loaded event loop; the body must stay await-free."""
         if not self.is_leader():
             return
+        if self._trace_pending:
+            # server.quorum_wait: append done -> the commit index covers
+            # the entry; tag 1 = inline at ack intake, 2 = by an engine tick
+            now = TRACER.now()
+            for index, rec in self._trace_pending.items():
+                if index <= new_commit and not rec[2]:
+                    rec[2] = now
+                    TRACER.record(rec[0], STAGE_QUORUM_WAIT, rec[1], now,
+                                  tag=2 if by_tick else 1)
         self.state.log.update_commit_index(new_commit,
                                            self.state.current_term, True)
         self._apply_wake.set()
         self._update_watch_frontiers()
 
     async def on_commit_advance(self, new_commit: int) -> None:
-        self.on_commit_advance_now(new_commit)
+        self.on_commit_advance_now(new_commit, by_tick=True)
 
     async def on_leadership_stale(self) -> None:
         if self._hibernating:
@@ -1153,10 +1175,18 @@ class Division:
         engine flush update as a packed ``(slot, flush_index)`` row instead
         of a scalar ``on_flush`` call — the server feeds the whole frame's
         rows to ``QuorumEngine.on_flush_batch`` in one pass."""
+        t0 = (TRACER.now() if TRACER.enabled and req.entries
+              and TRACER.sample(STAGE_FOLLOWER) else 0)
         with self.metrics.follower_append_timer.time():
             async with self._append_lock:
-                return await self._handle_append_entries_impl(req,
-                                                              flush_sink)
+                reply = await self._handle_append_entries_impl(req,
+                                                               flush_sink)
+        if t0:
+            # follower.append: entered -> the reply handed back, this
+            # replica's log flush included
+            TRACER.record(0, STAGE_FOLLOWER, t0, TRACER.now(),
+                          tag=len(req.entries))
+        return reply
 
     async def _handle_append_entries_impl(self, req: AppendEntriesRequest,
                                           flush_sink: Optional[list] = None
@@ -2056,7 +2086,11 @@ class Division:
         if tid:
             now = TRACER.now()
             TRACER.record(tid, STAGE_APPEND, t0, now)
-            self._trace_pending[index] = (tid, now)
+            # [trace id, append done, commit covered it (0: not yet),
+            #  own flush seen]: the parts of server.replicate
+            if len(self._trace_pending) > 256:
+                self._trace_pending.clear()  # writes that never applied
+            self._trace_pending[index] = [tid, now, 0, False]
         self._engine_update_flush()
         self.leader_ctx.notify_appenders()
         if on_submitted is not None:
@@ -2453,7 +2487,7 @@ class Division:
         not one wakeup chain per request (hops metric site
         ``reply_batch``; span ``server.fanout``)."""
         hop("reply_batch")
-        t0 = TRACER.now() if TRACER.enabled and TRACER.sample() else 0
+        span = TRACER.begin(STAGE_FANOUT) if TRACER.enabled else None
         for pending, exception, message, index in batch:
             try:
                 if exception is not None:
@@ -2464,9 +2498,8 @@ class Division:
                         log_index=index))
             except Exception:
                 LOG.exception("%s reply fan-out failed", self.member_id)
-        if t0:
-            TRACER.record(0, STAGE_FANOUT, t0, TRACER.now(),
-                          tag=len(batch))
+        if span is not None:
+            TRACER.end(span, tag=len(batch))
 
     async def _apply_one(self, entry: LogEntry,
                          reply_batch: Optional[list] = None) -> None:
@@ -2477,9 +2510,13 @@ class Division:
                  if self._trace_pending else None)
         if trace is not None:
             # close the replicate span (append done -> apply starts: quorum
-            # wait + apply-queue wait) and open the apply span
+            # wait + apply-queue wait) and its last part, the apply queue
+            # (commit covered the entry -> apply starts); open the apply span
             t_apply0 = TRACER.now()
             TRACER.record(trace[0], STAGE_REPLICATE, trace[1], t_apply0)
+            if trace[2]:
+                TRACER.record(trace[0], STAGE_APPLY_QUEUE, trace[2],
+                              t_apply0)
         if entry.kind == LogEntryKind.STATE_MACHINE:
             trx = self.server.transactions.pop((self.group_id, entry.index), None)
             if trx is None or trx.log_entry is None \

@@ -36,6 +36,8 @@ from ratis_tpu.protocol.exceptions import (ChecksumException,
 from ratis_tpu.protocol.logentry import LogEntry
 from ratis_tpu.protocol.termindex import INVALID_LOG_INDEX, TermIndex
 from ratis_tpu.server.log.base import RaftLog
+from ratis_tpu.trace.tracer import (STAGE_LOG_FSYNC, STAGE_LOG_QUEUE,
+                                    STAGE_LOG_WRITE, TRACER)
 
 MAGIC = b"RTPULOG\x01"
 _REC_HDR = struct.Struct("<II")
@@ -84,7 +86,8 @@ class LogWorker:
 
     def __init__(self, name: str = "default"):
         self.name = name
-        self._queue: list[tuple[object, bytes, asyncio.Future]] = []
+        # (file, bytes, future, submit ns of a log.queue sample or 0)
+        self._queue: list[tuple[object, bytes, asyncio.Future, int]] = []
         self._wake: Optional[asyncio.Event] = None
         self._task: Optional[asyncio.Task] = None
         self._refs = 0
@@ -144,7 +147,9 @@ class LogWorker:
 
     def submit(self, fileobj, data: bytes) -> asyncio.Future:
         fut = asyncio.get_running_loop().create_future()
-        self._queue.append((fileobj, data, fut))
+        t_submit = (TRACER.now() if TRACER.enabled
+                    and TRACER.sample(STAGE_LOG_QUEUE) else 0)
+        self._queue.append((fileobj, data, fut, t_submit))
         if self._wake is not None:
             self._wake.set()
         return fut
@@ -172,6 +177,12 @@ class LogWorker:
                 continue
             self._writes.inc(len(batch))
             self._batches.inc()
+            if TRACER.enabled:
+                # log.queue: submit -> the batch holding it taken
+                now = TRACER.now()
+                for _, _, _, t_submit in batch:
+                    if t_submit:
+                        TRACER.record(0, STAGE_LOG_QUEUE, t_submit, now)
             # per-flush-batch sync injection point (reference
             # RaftServerImpl.java:1620's LOG_SYNC): a registered delay
             # here is the slow-disk fault — every group sharing this
@@ -179,20 +190,33 @@ class LogWorker:
             # extra arg is the batch's distinct-file count, so a handler
             # can charge per FSYNC (per-group segments pay N, the shared
             # plane pays 1) rather than per sweep.
-            files_n = len({id(fileobj) for fileobj, _, _ in batch})
+            files_n = len({id(fileobj) for fileobj, _, _, _ in batch})
             await injection.execute(injection.LOG_SYNC, self.name, None,
                                     files_n)
 
             def _do_io():
+                # log.write / log.fsync: work spans on this worker thread
+                # (tag = distinct files: one fsync each)
+                tracing = TRACER.enabled
                 files = []
-                for fileobj, data, _ in batch:
-                    fileobj.write(data)
-                    if fileobj not in files:
-                        files.append(fileobj)
+                span = TRACER.begin(STAGE_LOG_WRITE) if tracing else None
+                try:
+                    for fileobj, data, _, _ in batch:
+                        fileobj.write(data)
+                        if fileobj not in files:
+                            files.append(fileobj)
+                finally:
+                    if span is not None:
+                        TRACER.end(span, tag=len(files))
+                span = TRACER.begin(STAGE_LOG_FSYNC) if tracing else None
                 t_sync = time.perf_counter()
-                for f in files:
-                    f.flush()
-                    os.fsync(f.fileno())
+                try:
+                    for f in files:
+                        f.flush()
+                        os.fsync(f.fileno())
+                finally:
+                    if span is not None:
+                        TRACER.end(span, tag=len(files))
                 self.registry_metrics.sync_timer.update(
                     time.perf_counter() - t_sync)
                 self.registry_metrics.sync_count.inc(len(files))
@@ -203,11 +227,11 @@ class LogWorker:
                 with self.registry_metrics.flush_timer.time():
                     await asyncio.to_thread(_do_io)
                 self.registry_metrics.flush_count.inc()
-                for _, _, fut in batch:
+                for _, _, fut, _ in batch:
                     if not fut.done():
                         fut.set_result(None)
             except Exception as e:
-                for _, _, fut in batch:
+                for _, _, fut, _ in batch:
                     if not fut.done():
                         fut.set_exception(e)
 

@@ -37,6 +37,7 @@ import asyncio
 import itertools
 import logging
 import os
+import zlib
 from typing import NamedTuple, Optional
 
 from ratis_tpu.metrics.hops import hop
@@ -44,6 +45,7 @@ from ratis_tpu.protocol.exceptions import TimeoutIOException
 from ratis_tpu.protocol.ids import RaftPeerId
 from ratis_tpu.protocol.raftrpc import (ENV_OK, AppendEntriesRequest,
                                         AppendEnvelope, AppendResult)
+from ratis_tpu.trace.tracer import STAGE_RTT, STAGE_SWEEP, TRACER
 
 LOG = logging.getLogger(__name__)
 
@@ -106,6 +108,8 @@ class PeerSender:
                  window_depth: int = 1):
         self.server = server
         self.to = to
+        # replicate.rtt's tag: the destination, the same in every process
+        self._trace_tag = zlib.crc32(str(to).encode()) & 0x7FFFFFFF
         self.coalescing = coalescing
         self.envelope_byte_limit = envelope_byte_limit
         self.inflight_cap = max(1, inflight_cap)
@@ -249,7 +253,9 @@ class PeerSender:
             if self.coalescing:
                 self._slots_free -= 1
                 lane, seq = self._next_frame()
-                t = asyncio.create_task(self._send(items, lane, seq))
+                t = asyncio.create_task(self._send(
+                    items, lane, seq,
+                    self._rtt_sample(items) if TRACER.enabled else 0))
                 self._inflight_tasks.add(t)
                 t.add_done_callback(self._inflight_tasks.discard)
             else:
@@ -307,7 +313,9 @@ class PeerSender:
             self.metrics["items"] += len(items)
             if self.coalescing:
                 lane, seq = self._next_frame()
-                t = asyncio.create_task(self._send(items, lane, seq))
+                t = asyncio.create_task(self._send(
+                    items, lane, seq,
+                    self._rtt_sample(items) if TRACER.enabled else 0))
                 self._inflight_tasks.add(t)
                 t.add_done_callback(self._inflight_tasks.discard)
             else:
@@ -345,8 +353,19 @@ class PeerSender:
             if not self.sweep:
                 self._wake.set()
 
+    @staticmethod
+    def _rtt_sample(items: list[OutItem]) -> int:
+        """The cut time of a frame whose round trip is sampled, else 0 (call
+        only while ``TRACER.enabled``).  Only frames that carry entries
+        count: the round trip then holds a follower's log flush, which a
+        bare heartbeat's does not."""
+        if any(it.request.entries for it in items) \
+                and TRACER.sample(STAGE_RTT):
+            return TRACER.now()
+        return 0
+
     async def _send(self, items: list[OutItem], lane: int = 0,
-                    seq: int = -1) -> None:
+                    seq: int = -1, t_cut: int = 0) -> None:
         server = self.server
         replies: list = []
         error: Optional[Exception] = None
@@ -404,6 +423,11 @@ class PeerSender:
                     # the frame may never have reached the receiver: later
                     # frames of this lane would stall on the hole — re-cut
                     self._reset_lane()
+            if t_cut and error is None:
+                # replicate.rtt: frame cut -> its reply taken in (decoded,
+                # not yet dispatched); the follower's flush wait is inside
+                TRACER.record(0, STAGE_RTT, t_cut, TRACER.now(),
+                              tag=self._trace_tag)
             for i, it in enumerate(items):
                 rep = error if error is not None else replies[i]
                 try:
@@ -595,12 +619,17 @@ class ReplicationScheduler:
     def _sweep_pass(self, st: _LoopSweep) -> None:
         st.armed = False
         due, st.due = st.due, {}
+        # replicate.sweep work span: one drain pass, tag = frames cut
+        span = TRACER.begin(STAGE_SWEEP) if TRACER.enabled else None
+        cut0 = self.metrics["envelopes"]
         for sender in due:
             try:
                 sender.sweep_collect()
             except Exception:
                 LOG.exception("replication sweep pass failed for %s",
                               sender.to)
+        if span is not None:
+            TRACER.end(span, tag=self.metrics["envelopes"] - cut0)
 
     def acquire(self, to: RaftPeerId, appender) -> PeerSender:
         """sender_for + register ``appender`` as a user; pair with

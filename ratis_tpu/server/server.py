@@ -463,9 +463,11 @@ class RaftServer:
                 RaftServerConfigKeys.Engine.SCALAR_FALLBACK_THRESHOLD_DEFAULT),
             leadership_timeout_ms=int(
                 RaftServerConfigKeys.Rpc.timeout_max(p).to_ms() * 2),
-            mesh=mesh,
-            profile_dir=RaftServerConfigKeys.Engine.profile_dir(p) or None,
-            name=str(peer_id))
+            mesh=mesh, name=str(peer_id))
+        # raft.tpu.engine.profile-dir: a profiler session from start() to
+        # close(), owned by ratis_tpu.trace (it is a trace session too)
+        self._profile_dir = RaftServerConfigKeys.Engine.profile_dir(p)
+        self._profiling = False
         # lag & health ledger thresholds (raft.tpu.lag.*); the ledger
         # itself is part of the engine
         self.engine.ledger.lag_threshold = RaftServerConfigKeys.Lag.threshold(p)
@@ -651,6 +653,14 @@ class RaftServer:
             # before anything that places a division: boot-scan recovery and
             # the initial group below pin divisions to shard loops
             self.shards.start()
+        from ratis_tpu.trace import instrument_loop, profile_dir_session
+        # the loop's occupancy counters (loop.select_ns, loop.iterations):
+        # this server's loop, once, however many servers share it
+        instrument_loop(asyncio.get_running_loop())
+        if self._profile_dir:
+            profile_dir_session(self._profile_dir, True)
+            self._profiling = True
+            LOG.info("%s profiling -> %s", self.peer_id, self._profile_dir)
         await self.engine.start()
         from ratis_tpu.conf.keys import RaftServerConfigKeys as _K
         if _K.Gc.discipline(self.properties):
@@ -822,6 +832,15 @@ class RaftServer:
         self.upkeep = []
         self.serving.close()
         await self.engine.close()
+        if self._profiling:
+            self._profiling = False
+            from ratis_tpu.trace import profile_dir_session
+            try:
+                # (off the loop: writing the trace out takes seconds)
+                await asyncio.to_thread(profile_dir_session,
+                                        self._profile_dir, False)
+            except Exception:
+                LOG.exception("could not stop the profiler session")
         if self.shards is not None:
             await self.shards.close()
         self.life_cycle.transition(LifeCycleState.CLOSED)
@@ -1539,11 +1558,20 @@ class RaftServer:
         from ratis_tpu.protocol.requests import RequestType
         from ratis_tpu.trace.tracer import INGRESS_NS, STAGE_ROUTE, TRACER
         trace_t0 = 0
-        if TRACER.enabled and request.trace_id:
+        route = None
+        if TRACER.enabled:
             # route starts at transport ingress when the transport stamped
             # it (captures the ingress->handler scheduling hop), else here
-            trace_t0 = INGRESS_NS.get() or TRACER.now()
-            INGRESS_NS.set(0)  # single-use: never bleed into a later call
+            ingress = INGRESS_NS.get()
+            if ingress:
+                INGRESS_NS.set(0)  # single-use: never bleed into a later call
+            # A request that arrived untraced (its client's process has no
+            # session) is traced from its arrival, under the same sampling:
+            # by the transport that stamped the ingress, or here for one
+            # that does not (the simulated transport)
+            if request.trace_id or (not ingress
+                                    and TRACER.ingress(request)):
+                trace_t0 = ingress or TRACER.now()
         t = request.type.type
         if t == RequestType.GROUP_MANAGEMENT:
             return await self._group_management(request)
@@ -1552,33 +1580,37 @@ class RaftServer:
             from ratis_tpu.protocol.message import Message
             return RaftClientReply.success_reply(
                 request, message=Message(encode_group_list(self.group_ids())))
+        if TRACER.enabled:
+            # the work span is the synchronous part: here -> division submit
+            route = TRACER.begin(STAGE_ROUTE, request.trace_id)
         try:
-            div = self.get_division(request.group_id)
-        except GroupMismatchException as e:
-            return RaftClientReply.failure_reply(request, e)
-        # Admission control (serving plane): a shard over its pending
-        # budget sheds here with a typed overload reply — the request
-        # never hops to the saturated division loop.
-        shed, ticket = self.serving.admission.try_admit(request)
-        if shed is not None:
-            return shed
-        wrapped_sink = False
-        if ticket is not None:
-            from ratis_tpu.protocol.requests import (attach_reply_sink,
-                                                     reply_sink_of)
-            sink = reply_sink_of(request)
-            if sink is not None:
-                # deferred replies bypass the handler return: the budget
-                # is held until the waterline fan-out delivers through
-                # the transport sink
-                def _release_sink(reply, _sink=sink, _t=ticket):
-                    _t.release()
-                    _sink(reply)
-                attach_reply_sink(request, _release_sink)
-                wrapped_sink = True
-        if trace_t0:
-            TRACER.record(request.trace_id, STAGE_ROUTE, trace_t0,
-                          TRACER.now())
+            try:
+                div = self.get_division(request.group_id)
+            except GroupMismatchException as e:
+                return RaftClientReply.failure_reply(request, e)
+            # Admission control (serving plane): a shard over its pending
+            # budget sheds here with a typed overload reply — the request
+            # never hops to the saturated division loop.
+            shed, ticket = self.serving.admission.try_admit(request)
+            if shed is not None:
+                return shed
+            wrapped_sink = False
+            if ticket is not None:
+                from ratis_tpu.protocol.requests import (attach_reply_sink,
+                                                         reply_sink_of)
+                sink = reply_sink_of(request)
+                if sink is not None:
+                    # deferred replies bypass the handler return: the budget
+                    # is held until the waterline fan-out delivers through
+                    # the transport sink
+                    def _release_sink(reply, _sink=sink, _t=ticket):
+                        _t.release()
+                        _sink(reply)
+                    attach_reply_sink(request, _release_sink)
+                    wrapped_sink = True
+        finally:
+            if route is not None:
+                TRACER.end(route, t0_ns=trace_t0)
         deferred = False
         try:
             try:

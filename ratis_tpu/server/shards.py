@@ -30,6 +30,8 @@ import threading
 import zlib
 from typing import Optional
 
+from ratis_tpu.trace.tracer import instrument_loop
+
 LOG = logging.getLogger(__name__)
 
 
@@ -75,6 +77,8 @@ class LoopShardPool:
                 loop = asyncio.new_event_loop()
                 holder["loop"] = loop
                 asyncio.set_event_loop(loop)
+                # this shard's occupancy counters (ratis_tpu.trace)
+                instrument_loop(loop)
                 ready.set()
                 try:
                     loop.run_forever()

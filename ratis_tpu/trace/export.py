@@ -20,7 +20,7 @@ import json
 import numpy as np
 
 from ratis_tpu.trace.tracer import (NUM_STAGES, STAGE_CLIENT, STAGE_NAMES,
-                                    TILING_STAGES)
+                                    TILING_STAGES, TRACER)
 
 # Stages whose spans OVERLAP others (client total, transport rtt, engine
 # dispatch): reported in the table, excluded from the coverage sum.
@@ -81,6 +81,43 @@ def host_path_decomposition(records) -> dict:
         "coverage": round(covered_ns / wall_ns, 3) if wall_ns else 0.0,
         "stages": stages,
     }
+
+
+# ------------------------------------------------------ the last session
+
+def session_rows(stage_name: str, tracer=TRACER):
+    """The ring rows ``[n, 5]`` (trace_id, t0_ns, dur_ns, tag, thread) of
+    ``stage_name`` that started inside the tracer's last session, or None
+    when no session has been recorded at all.  What the benchmark's
+    per-layer readers read: one session spans one measured window."""
+    sess = tracer.session()
+    if not sess["t_on"]:
+        return None
+    rows = tracer.rows(STAGE_NAMES.index(stage_name))
+    t_off = sess["t_off"] or np.iinfo(np.int64).max
+    return rows[(rows[:, 1] >= sess["t_on"]) & (rows[:, 1] <= t_off)]
+
+
+def session_durations_ms(stage_name: str, tracer=TRACER):
+    """The durations (ms) of :func:`session_rows`; None without a session."""
+    rows = session_rows(stage_name, tracer)
+    return None if rows is None else (rows[:, 2] / 1e6).tolist()
+
+
+def session_request_sums_ns(stage_names, tracer=TRACER):
+    """Per traced request of the last session, the summed duration (ns) of
+    ``stage_names`` — only requests that recorded every one of them.  None
+    when no session has been recorded."""
+    per_tid: dict[int, list[int]] = {}
+    for k, name in enumerate(stage_names):
+        rows = session_rows(name, tracer)
+        if rows is None:
+            return None
+        for tid, dur in rows[:, (0, 2)].tolist():
+            if tid:
+                got = per_tid.setdefault(tid, [0] * len(stage_names))
+                got[k] += dur
+    return [sum(v) for v in per_tid.values() if all(v)]
 
 
 def to_chrome_trace(records) -> dict:

@@ -27,6 +27,18 @@ per-stage percentile decomposition table) lives in
 The runtime is single-event-loop end to end, so one process-wide tracer
 (``TRACER`` / :func:`get_tracer`) serves every co-hosted server and the
 in-process clients; cross-process propagation rides the wire field.
+
+A *session* is open while ``raft.tpu.trace.enabled`` is set or while a
+``jax.profiler`` session is open in the process (:meth:`Tracer.poll`, called
+from the engine's tick loop, sees the profiler start and stop).  While the
+profiler is on, every *work span* (``W`` in :data:`STAGE_KINDS`: a
+synchronous stretch on one thread) is also entered as a
+``jax.profiler.TraceAnnotation("ratis:<stage>")``, so it lies in the
+xplane's host plane on the profiler's own clock beside the device ops; one
+``ratis:clock`` annotation at session open carries ``monotonic_ns`` and maps
+the ring rows (interval spans, ``I``, cannot be ``with`` blocks) onto it.
+Always-on integer counters (:meth:`Tracer.counter`) are snapshotted at the
+session's two ends; :meth:`Tracer.session` gives the delta.
 """
 
 from __future__ import annotations
@@ -70,14 +82,70 @@ STAGE_ENGINE = 11     # engine.dispatch — one quorum-engine tick dispatch
 STAGE_FANOUT = 12     # server.fanout — one waterline reply fan-out pass
                       # (batch of committed requests resolved in one unit;
                       # tag = batch size; process-level like engine.dispatch)
-NUM_STAGES = 13
+# Parts of server.replicate, per traced request on the leader; they overlap
+# each other and the tiling stage that holds them.
+STAGE_FLUSH_WAIT = 13   # server.flush_wait — append done -> the leader's own
+                        # log flush seen on the loop
+STAGE_QUORUM_WAIT = 14  # server.quorum_wait — append done -> commit index
+                        # covers the entry (tag 1 = inline at ack intake,
+                        # 2 = by an engine tick)
+STAGE_APPLY_QUEUE = 15  # server.apply_queue — commit covers it -> apply starts
+# Process-level stages (trace id 0), sampled per stage.
+STAGE_RTT = 16          # replicate.rtt — frame cut for a destination -> its
+                        # reply taken in (tag = crc32 of the destination id)
+STAGE_SWEEP = 17        # replicate.sweep — one drain pass (tag = frames cut)
+STAGE_ACK = 18          # ack.intake — one engine ack/flush intake (tag = rows)
+STAGE_FOLLOWER = 19     # follower.append — handle_append_entries entered ->
+                        # its reply returned, flush included (tag = entries)
+STAGE_LOG_QUEUE = 20    # log.queue — LogWorker.submit -> batch taken
+STAGE_LOG_WRITE = 21    # log.write — one batch's writes, on the worker
+                        # thread (tag = distinct files)
+STAGE_LOG_FSYNC = 22    # log.fsync — the batch's fsyncs (tag = distinct files)
+STAGE_PACK = 23         # engine.pack — events packed for the step
+STAGE_LAUNCH = 24       # engine.launch — uploads + the step call returning
+STAGE_FETCH = 25        # engine.fetch — outputs to the host (kernel + d2h)
+STAGE_COLLECT = 26      # engine.collect — changed rows -> listener events
+STAGE_TCP_READ = 27     # tcp.read — one read burst of a connection (tag =
+                        # frames)
+STAGE_SELECT = 28       # loop.select — one blocking selector wait
+STAGE_WIRE_FLUSH = 29   # wire.flush — one buffered socket write of a
+                        # connection's coalescer (tag = frames)
+NUM_STAGES = 30
 
 STAGE_NAMES = (
     "client.send", "codec.encode", "codec.decode", "wire.rtt",
     "server.route", "server.txn_start", "server.append",
     "server.replicate", "server.apply", "server.reply", "server.respond",
     "engine.dispatch", "server.fanout",
+    "server.flush_wait", "server.quorum_wait", "server.apply_queue",
+    "replicate.rtt", "replicate.sweep", "ack.intake", "follower.append",
+    "log.queue", "log.write", "log.fsync",
+    "engine.pack", "engine.launch", "engine.fetch", "engine.collect",
+    "tcp.read", "loop.select", "wire.flush",
 )
+
+# W = work span: a stretch that is synchronous on one thread by construction
+# (ring row + a ``ratis:<stage>`` profiler annotation); I = interval between
+# two events (ring row only).  server.txn_start, server.append and
+# server.apply await the embedder's state machine or the log: a suspension
+# there would put other callbacks inside the annotation, so they are I.
+STAGE_KINDS = (
+    "I", "W", "W", "I",
+    "W", "I", "I",
+    "I", "I", "I", "I",
+    "W", "W",
+    "I", "I", "I",
+    "I", "W", "W", "I",
+    "I", "W", "W",
+    "W", "W", "W", "W",
+    "W", "W", "W",
+)
+
+# Work spans happen once per batch, several batches per commit: their rings
+# hold this many times ``ring-size`` so a 25 s window at the default
+# sampling never wraps (server.route is one row per traced request and
+# keeps the plain size).
+WORK_RING_FACTOR = 4
 
 # Stages whose durations tile the per-request path (no mutual overlap):
 # these are the ones the decomposition's coverage fraction sums.
@@ -133,7 +201,7 @@ class SpanRing:
         return max(0, self._n - self.capacity)
 
     def rows(self) -> np.ndarray:
-        """Held records, oldest first, as an [n, 4] array copy."""
+        """Held records, oldest first, as an [n, 5] array copy."""
         if self._n <= self.capacity:
             return self._buf[:self._n].copy()
         i = self._n % self.capacity
@@ -143,45 +211,192 @@ class SpanRing:
         self._n = 0
 
 
+class Count:
+    """One always-on integer counter (:meth:`Tracer.counter`): sites add to
+    ``n`` directly, the tracer snapshots it at a session's two ends."""
+
+    __slots__ = ("n",)
+
+    def __init__(self):
+        self.n = 0
+
+
+class Tiles:
+    """An envelope work span and the parts that tile it, each begun where
+    the one before ends; every occurrence has ring rows (make one only
+    while ``tracer.enabled``).  ``close`` belongs in a ``finally``: a body
+    that raises then leaves no span open."""
+
+    __slots__ = ("_tracer", "_envelope", "_part")
+
+    def __init__(self, tracer: "Tracer", stage: int):
+        self._tracer = tracer
+        self._envelope = tracer.begin(stage, always=True)
+        self._part = None
+
+    def part(self, stage: int) -> None:
+        if self._part is not None:
+            self._tracer.end(self._part)
+        self._part = self._tracer.begin(stage, always=True)
+
+    def close(self, tag: int = 0) -> None:
+        if self._part is not None:
+            self._tracer.end(self._part)
+            self._part = None
+        self._tracer.end(self._envelope, tag)
+
+
+def _no_session() -> dict:
+    return {"t_on": 0, "t_off": 0, "counters": {}, "keyed": {}}
+
+
 class Tracer:
-    """Process-wide span recorder.  Disabled (the default) it costs one
-    attribute check per instrumentation site; enabled, each Nth request
-    (``sample_every``) gets a trace id and its stages record spans."""
+    """Process-wide span recorder.  With no session open (the default) it
+    costs one attribute check per instrumentation site; in a session each
+    Nth request (``sample_every``) gets a trace id and its stages record
+    spans, and process-level stages sample every Nth of their own."""
 
     DEFAULT_RING_SIZE = 4096
 
     def __init__(self):
-        self.enabled = False
+        self.enabled = False      # a session is open
+        self.annotate = False     # ... and the profiler is: W spans annotate
+        self.configured = False   # raft.tpu.trace.enabled holds it open
         self.sample_every = 1
         self.ring_size = self.DEFAULT_RING_SIZE
         self._rings: list[SpanRing] = [SpanRing(1) for _ in range(NUM_STAGES)]
         self._ids = itertools.count(1)
         self._req_tick = 0
-        self._proc_tick = 0
+        self._ticks = [-1] * NUM_STAGES   # per-stage sampling strides
         # trace_id -> server-handler-done ns (mark_egress/pop_egress): lets
         # the TRANSPORT close the respond span across the task boundary the
         # handler's return crosses (a ContextVar cannot flow back out of
         # the handler task — task creation copies the context one way).
         self._egress: dict[int, int] = {}
+        self._counters: dict[tuple[str, str], Count] = {}
+        self._annotation = None   # jax.profiler.TraceAnnotation, once polled
+        self._profile_refs = 0    # servers holding profile-dir's session
+        self._session = _no_session()
+        self._counters_on: dict[tuple[str, str], int] = {}
+        self._lock = threading.Lock()
 
     # -- configuration -------------------------------------------------------
 
     def configure(self, enabled: bool = True, sample_every: int = 1,
                   ring_size: int = DEFAULT_RING_SIZE) -> None:
-        """(Re)configure; allocates fresh rings (existing records drop)."""
-        self.sample_every = max(1, int(sample_every))
-        self.ring_size = max(1, int(ring_size))
-        self._rings = [SpanRing(self.ring_size) for _ in range(NUM_STAGES)]
-        self._req_tick = 0
-        self._proc_tick = 0
-        self._egress = {}
-        self.enabled = bool(enabled)
+        """(Re)configure; ``enabled`` opens a session that stays open until
+        a later ``configure(enabled=False)`` (existing records drop)."""
+        with self._lock:
+            if self.enabled:
+                self._close()
+            self.sample_every = max(1, int(sample_every))
+            self.ring_size = max(1, int(ring_size))
+            self.configured = bool(enabled)
+            self._rings = [SpanRing(1) for _ in range(NUM_STAGES)]
+            if self.configured:
+                self._open()
 
     def reset(self) -> None:
-        """Drop recorded spans; keep configuration."""
+        """Drop recorded spans (and, with no session open, what is kept of
+        the last one); keep configuration."""
         for ring in self._rings:
             ring.clear()
         self._egress.clear()
+        if not self.enabled:
+            self._session = _no_session()
+
+    # -- sessions ------------------------------------------------------------
+
+    def poll(self) -> None:
+        """Follow the profiler: open a session when a ``jax.profiler``
+        session has started in this process, close it when that has stopped
+        (unless the configuration holds it open).  Called from the engine's
+        tick loop, never per request."""
+        ann = self._annotation
+        if ann is None:
+            from jax.profiler import TraceAnnotation as ann
+            self._annotation = ann
+        on = ann.is_enabled()
+        if on == self.annotate:
+            return
+        with self._lock:
+            if on == self.annotate:
+                return
+            if on:
+                if not self.enabled:
+                    self._open()
+                self.annotate = True
+                # the ring rows' clock on the profiler's
+                with ann("ratis:clock", monotonic_ns=time.monotonic_ns()):
+                    pass
+            else:
+                self.annotate = False
+                if not self.configured:
+                    self._close()
+
+    def _open(self) -> None:
+        cap = self.ring_size
+        self._rings = [
+            SpanRing(cap * (WORK_RING_FACTOR if kind == "W" and
+                            stage != STAGE_ROUTE else 1))
+            for stage, kind in enumerate(STAGE_KINDS)]
+        self._req_tick = 0
+        self._ticks = [-1] * NUM_STAGES
+        self._egress = {}
+        self._counters_on = {k: c.n for k, c in self._counters.items()}
+        self._session = dict(_no_session(), t_on=time.monotonic_ns())
+        self.enabled = True
+
+    def _close(self) -> None:
+        self.enabled = False
+        self.annotate = False
+        self._session.update(self._deltas(), t_off=time.monotonic_ns())
+
+    def profile_dir_session(self, directory: str, start: bool) -> None:
+        """``raft.tpu.engine.profile-dir``: a ``jax.profiler`` session into
+        ``directory`` from server start to server stop, which (see
+        :meth:`poll`) is a trace session of the program too.  The profiler
+        is a process singleton, so co-hosted servers share the first one's
+        session and the last to stop closes it."""
+        import jax
+        with self._lock:
+            self._profile_refs += 1 if start else -1
+            first, last = ((start and self._profile_refs == 1),
+                           (not start and self._profile_refs == 0))
+        if first:
+            # a server asked to profile that cannot must not start untraced
+            jax.profiler.start_trace(directory)
+        elif last:
+            jax.profiler.stop_trace()
+        self.poll()
+
+    def _deltas(self) -> dict:
+        total: dict[str, int] = {}
+        keyed: dict[str, dict[str, int]] = {}
+        for (name, key), c in list(self._counters.items()):
+            d = c.n - self._counters_on.get((name, key), 0)
+            total[name] = total.get(name, 0) + d
+            keyed.setdefault(name, {})[key] = d
+        return {"counters": total, "keyed": keyed}
+
+    def session(self) -> dict:
+        """The last session: ``t_on`` / ``t_off`` (``monotonic_ns``; 0 = not
+        yet) and the counters' deltas between them — ``counters`` summed by
+        name, ``keyed`` by name and key (a loop, a log worker).  Readable
+        after the close; an open session reads its deltas up to now."""
+        if self.enabled:
+            return dict(self._session, **self._deltas())
+        return dict(self._session)
+
+    def counter(self, name: str, key: str = "") -> Count:
+        """The always-on counter ``name`` of ``key`` (one per loop, per log
+        worker, ...), made on first use.  Sites keep the object and add to
+        its ``n``; nothing else is registered anywhere."""
+        c = self._counters.get((name, key))
+        if c is None:
+            with self._lock:
+                c = self._counters.setdefault((name, key), Count())
+        return c
 
     # -- hot path ------------------------------------------------------------
 
@@ -199,13 +414,25 @@ class Tracer:
             return 0
         return next(self._ids)
 
-    def sample(self) -> bool:
+    def ingress(self, request) -> int:
+        """A client request has reached a server: one that came untraced
+        (the client's process has no session) is traced from here, under the
+        same ``sample-every`` rule.  Returns the request's trace id."""
+        tid = request.trace_id
+        if not tid and self.enabled:
+            tid = self.begin_trace()
+            if tid:
+                object.__setattr__(request, "trace_id", tid)  # frozen
+        return tid
+
+    def sample(self, stage: int) -> bool:
         """Sampling decision for PROCESS-level stages (codec on server
-        RPCs, engine dispatch) that have no request trace id."""
+        RPCs, sweeps, log batches) that have no request trace id: every
+        ``sample_every``-th occurrence of the stage, the first included."""
         if not self.enabled:
             return False
-        self._proc_tick += 1
-        return self._proc_tick % self.sample_every == 0
+        self._ticks[stage] = n = self._ticks[stage] + 1
+        return n % self.sample_every == 0
 
     def record(self, trace_id: int, stage: int, t0_ns: int, t1_ns: int,
                tag: int = 0) -> None:
@@ -213,6 +440,42 @@ class Tracer:
             return
         self._rings[stage].record(trace_id, t0_ns, t1_ns, tag,
                                   origin=threading.get_ident())
+
+    def begin(self, stage: int, trace_id: int = -1, always: bool = False):
+        """Open a work span (call only while ``enabled``).  ``trace_id`` -1
+        is a process-level span, sampled by the stage's own stride unless
+        ``always``; >= 0 a request's (0: not sampled, so no ring row).
+        While the profiler is on every span is annotated, sampled or not.
+        Returns what :meth:`end` takes, or None when there is nothing to
+        record."""
+        if trace_id < 0:
+            row = always or self.sample(stage)
+            trace_id = 0
+        else:
+            row = trace_id > 0
+        ann = None
+        if self.annotate:
+            ann = self._annotation(_ANNOTATION_NAMES[stage])
+            ann.__enter__()
+        elif not row:
+            return None
+        # (an annotation without a ring row needs no clock of ours)
+        return (stage, trace_id, row, ann, time.monotonic_ns() if row else 0)
+
+    def end(self, span, tag: int = 0, t0_ns: int = 0) -> int:
+        """Close a work span; ``t0_ns`` moves the ring row's start (a route
+        span starts at the transport's ingress stamp).  Returns the end of
+        a span that has a ring row, else 0."""
+        stage, trace_id, row, ann, t0 = span
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        if not row:
+            return 0
+        t1 = time.monotonic_ns()
+        if self.enabled:
+            self._rings[stage].record(trace_id, t0_ns or t0, t1, tag,
+                                      origin=threading.get_ident())
+        return t1
 
     def mark_egress(self, trace_id: int) -> None:
         """Server handler is done with this request NOW; the transport pops
@@ -232,6 +495,11 @@ class Tracer:
 
     # -- aggregation ---------------------------------------------------------
 
+    def rows(self, stage: int) -> np.ndarray:
+        """The stage's held rows ``[n, 5]`` (trace_id, t0_ns, dur_ns, tag,
+        origin_thread), oldest first."""
+        return self._rings[stage].rows()
+
     def snapshot(self) -> list[tuple[int, int, int, int, int, int]]:
         """Every held record as
         (trace_id, stage, t0_ns, dur_ns, tag, origin_thread)."""
@@ -246,7 +514,12 @@ class Tracer:
                 for i, r in enumerate(self._rings) if r.dropped}
 
 
+_ANNOTATION_NAMES = tuple("ratis:" + n for n in STAGE_NAMES)
+
 TRACER = Tracer()
+
+
+profile_dir_session = TRACER.profile_dir_session
 
 
 def get_tracer() -> Tracer:
@@ -254,13 +527,74 @@ def get_tracer() -> Tracer:
 
 
 def configure_from_properties(p) -> None:
-    """Enable the process tracer when ``raft.tpu.trace.enabled`` is set.
-    Never disables: co-hosted servers share ONE tracer, and a second
-    server built without the key must not silence the first's tracing."""
+    """Take the sampling and ring size from the properties, and open a
+    session when ``raft.tpu.trace.enabled`` is set.  Never closes one:
+    co-hosted servers share ONE tracer, and a second server built without
+    the key must not silence the first's tracing."""
     if p is None:
         return
     from ratis_tpu.conf.keys import RaftServerConfigKeys
     K = RaftServerConfigKeys.Trace
-    if K.enabled(p) and not TRACER.enabled:
+    if TRACER.enabled:
+        return
+    if K.enabled(p):
         TRACER.configure(enabled=True, sample_every=K.sample_every(p),
                          ring_size=K.ring_size(p))
+    else:
+        # a profiler-opened session (Tracer.poll) follows the same keys
+        TRACER.sample_every = max(1, K.sample_every(p))
+        TRACER.ring_size = max(1, K.ring_size(p))
+
+
+# ------------------------------------------------------- the loop's occupancy
+
+def instrument_loop(loop) -> bool:
+    """Count the time ``loop`` spends in its selector: ``loop.select_ns`` and
+    ``loop.iterations``, keyed by the loop, always on (two clock reads and
+    two adds per loop iteration).  Installed once per loop, where the loop's
+    owner starts on it (``RaftServer.start``, a shard's thread); a selector
+    wait that may block is also the ``loop.select`` work span.  What is not
+    selector time is the loop running callbacks: its busy share.  A loop
+    without a ``_selector`` (not a selector event loop) is left alone."""
+    selector = getattr(loop, "_selector", None)
+    inner = getattr(selector, "select", None)
+    if inner is None or getattr(inner, "ratis_timed", False):
+        return False
+    key = loop_key(loop)
+    select_ns = TRACER.counter("loop.select_ns", key)
+    iterations = TRACER.counter("loop.iterations", key)
+    clock = time.monotonic_ns
+
+    def select(timeout=None):
+        t0 = clock()
+        if timeout != 0 and TRACER.enabled:
+            span = TRACER.begin(STAGE_SELECT)
+            try:
+                events = inner(timeout)
+            finally:
+                if span is not None:
+                    TRACER.end(span)
+        else:
+            events = inner(timeout)
+        select_ns.n += clock() - t0
+        iterations.n += 1
+        return events
+
+    select.ratis_timed = True
+    try:
+        selector.select = select
+    except AttributeError:
+        return False
+    return True
+
+
+def loop_key(loop=None) -> str:
+    """The counters' key of ``loop`` (default: the running loop; "" off a
+    loop)."""
+    if loop is None:
+        import asyncio
+        try:
+            loop = asyncio.get_running_loop()
+        except RuntimeError:
+            return ""
+    return f"loop-{id(loop):x}"

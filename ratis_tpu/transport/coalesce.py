@@ -33,6 +33,8 @@ from __future__ import annotations
 import asyncio
 from typing import Optional
 
+from ratis_tpu.trace.tracer import TRACER, loop_key
+
 __all__ = ["WriteCoalescer"]
 
 
@@ -58,7 +60,13 @@ class WriteCoalescer:
         self._flusher: Optional[asyncio.Task] = None
         self._lock = asyncio.Lock()
         self._dead: Optional[Exception] = None
-        self.metrics = {"flushes": 0, "frames": 0, "coalesced_frames": 0}
+        self.metrics = {"flushes": 0, "coalesced_frames": 0}
+        # frames and bytes are the process's wire counters (ratis_tpu.trace:
+        # always on, a trace session snapshots them), one pair per loop so
+        # that every add comes from one thread
+        key = loop_key()
+        self._n_frames = TRACER.counter("wire.frames", key)
+        self._n_bytes = TRACER.counter("wire.bytes", key)
 
     @property
     def coalescing(self) -> bool:
@@ -84,7 +92,8 @@ class WriteCoalescer:
                     raise self._dead
                 await self._flush_batch([frame])
                 self.metrics["flushes"] += 1
-                self.metrics["frames"] += 1
+                self._n_frames.n += 1
+                self._n_bytes.n += nbytes
             return
         fut = asyncio.get_running_loop().create_future()
         self._pending.append(frame)
@@ -132,6 +141,7 @@ class WriteCoalescer:
                 return
             frames = self._pending
             waiters = self._waiters
+            nbytes = self._pending_bytes
             self._pending, self._waiters = [], []
             self._pending_bytes = 0
             try:
@@ -144,7 +154,8 @@ class WriteCoalescer:
                 self._poison(e, waiters)
                 return
             self.metrics["flushes"] += 1
-            self.metrics["frames"] += len(frames)
+            self._n_frames.n += len(frames)
+            self._n_bytes.n += nbytes
             if len(frames) > 1:
                 self.metrics["coalesced_frames"] += len(frames)
             for f in waiters:

@@ -750,10 +750,14 @@ class GrpcServerTransport(ServerTransport):
         async def dispatch(payload: bytes, defer_ctx=None):
             t0 = TRACER.now() if TRACER.enabled else 0
             request = RaftClientRequest.from_bytes(payload)
-            if t0 and request.trace_id:
+            if t0:
+                # (an untraced request is traced from its arrival here; the
+                # stamp tells the route site that the sampling is decided)
+                tid = TRACER.ingress(request)
                 now = TRACER.now()
-                TRACER.record(request.trace_id, STAGE_DECODE, t0,
-                              now, tag=len(payload))
+                if tid:
+                    TRACER.record(tid, STAGE_DECODE, t0, now,
+                                  tag=len(payload))
                 INGRESS_NS.set(now)  # route span starts post-decode
             if defer_ctx is not None:
                 fanout, call_id = defer_ctx

@@ -31,7 +31,8 @@ from ratis_tpu.protocol.requests import (DEFERRED_REPLY, RaftClientReply,
                                          RaftClientRequest,
                                          attach_reply_sink)
 from ratis_tpu.trace.tracer import (INGRESS_NS, STAGE_DECODE, STAGE_ENCODE,
-                                    STAGE_RESPOND, STAGE_WIRE, TRACER)
+                                    STAGE_RESPOND, STAGE_TCP_READ, STAGE_WIRE,
+                                    STAGE_WIRE_FLUSH, TRACER)
 from ratis_tpu.transport.base import (ClientRequestHandler, ClientTransport,
                                       ServerRpcHandler, ServerTransport,
                                       TransportFactory)
@@ -64,7 +65,14 @@ class _StreamFrameCoalescer(WriteCoalescer):
 
     async def _flush_batch(self, frames: list) -> None:
         w = self._writer
-        w.write(frames[0] if len(frames) == 1 else b"".join(frames))
+        # wire.flush work span: the buffered write, which is the socket's
+        # send while its buffer is empty; the drain may wait and lies outside
+        span = TRACER.begin(STAGE_WIRE_FLUSH) if TRACER.enabled else None
+        try:
+            w.write(frames[0] if len(frames) == 1 else b"".join(frames))
+        finally:
+            if span is not None:
+                TRACER.end(span, tag=len(frames))
         await w.drain()
 
 
@@ -150,6 +158,48 @@ class _DeferredReplyFanout:
                 # path (the flush itself is the coalescer's single
                 # write+drain per batch)
                 TRACER.record(tid, STAGE_RESPOND, t0, now, tag=nbody)
+
+
+def _frame_buffered(reader: asyncio.StreamReader) -> bool:
+    """Whether the next :func:`_read_frame` returns without suspending: a
+    whole frame already sits in the reader's buffer.  (CPython's
+    StreamReader keeps it in ``_buffer``; one without it reads False, and
+    every frame is then a burst of its own.)"""
+    buf = getattr(reader, "_buffer", None)
+    if buf is None or len(buf) < 4:
+        return False
+    return len(buf) >= 4 + int.from_bytes(buf[:4], "big")
+
+
+class _ReadBurst:
+    """The ``tcp.read`` work span of one connection's read loop: opened when
+    a frame arrives, kept open while whole frames are still buffered (they
+    are read without suspending), closed before the read that will wait.
+    One burst is the frames one wake-up of the connection hands on, from
+    the first one parsed to the last one's hand-off (tag = frames)."""
+
+    __slots__ = ("span", "_frames")
+
+    def __init__(self) -> None:
+        self.span = None      # open work span; None costs a read loop nothing
+        self._frames = 0
+
+    def frame(self) -> None:
+        """A frame was read (call only while ``TRACER.enabled``)."""
+        if self.span is None:
+            self.span = TRACER.begin(STAGE_TCP_READ)
+            self._frames = 0
+        self._frames += 1
+
+    def before_read(self, reader: asyncio.StreamReader) -> None:
+        """Call while ``span`` is open, before the next read."""
+        if not _frame_buffered(reader):
+            self.close()
+
+    def close(self) -> None:
+        if self.span is not None:
+            TRACER.end(self.span, tag=self._frames)
+            self.span = None
 
 
 async def _read_frame(reader: asyncio.StreamReader):
@@ -261,11 +311,16 @@ class _Connection:
 
     async def _recv_loop(self) -> None:
         cause: Exception = ConnectionError(f"{self.address} closed")
+        burst = _ReadBurst()
         try:
             while True:
+                if burst.span is not None:
+                    burst.before_read(self._reader)
                 frame = await _read_frame(self._reader)
                 if frame is None:
                     break
+                if TRACER.enabled:
+                    burst.frame()
                 call_seq, kind, body = frame
                 fut = self._pending.pop(call_seq, None)
                 if fut is not None and not fut.done():
@@ -273,6 +328,7 @@ class _Connection:
         except (ConnectionError, OSError, asyncio.CancelledError) as e:
             cause = ConnectionError(f"{self.address} lost: {e}")
         finally:
+            burst.close()
             self._dead = cause
             for fut in self._pending.values():
                 if not fut.done():
@@ -440,11 +496,16 @@ class TcpServerTransport(ServerTransport):
         fanout = (_DeferredReplyFanout(conn_out, asyncio.get_running_loop())
                   if self.defer_replies else None)
         tasks: set[asyncio.Task] = set()
+        burst = _ReadBurst()
         try:
             while True:
+                if burst.span is not None:
+                    burst.before_read(reader)
                 frame = await _read_frame(reader)
                 if frame is None:
                     break
+                if TRACER.enabled:
+                    burst.frame()
                 # handle concurrently: one slow consensus RPC must not
                 # head-of-line-block the connection (gRPC gives this for
                 # free; here we spawn per-call tasks)
@@ -455,6 +516,7 @@ class TcpServerTransport(ServerTransport):
         except (ConnectionError, OSError):
             pass
         finally:
+            burst.close()
             for t in tasks:
                 t.cancel()
             try:
@@ -479,13 +541,31 @@ class TcpServerTransport(ServerTransport):
                 reply = await self.server_handler(decode_rpc(body))
                 out_kind, out = KIND_REPLY, encode_rpc(reply)
             elif kind == KIND_CLIENT_REQUEST:
-                t0 = TRACER.now() if TRACER.enabled else 0
-                request = RaftClientRequest.from_bytes(body)
-                if t0 and request.trace_id:
+                if TRACER.enabled:
+                    # codec.decode: a work span of every request while the
+                    # profiler is on, a ring row of the sampled ones (known
+                    # only once decoded)
+                    span = TRACER.begin(STAGE_DECODE, 0)
+                    t0 = TRACER.now()
+                    try:
+                        request = RaftClientRequest.from_bytes(body)
+                    finally:
+                        if span is not None:
+                            TRACER.end(span)
+                    # a request that arrives untraced (its client's process
+                    # has no session) is traced from here: the id is minted
+                    # where the request arrives
+                    tid = TRACER.ingress(request)
                     now = TRACER.now()
-                    TRACER.record(request.trace_id, STAGE_DECODE, t0,
-                                  now, tag=len(body))
-                    INGRESS_NS.set(now)  # route span starts post-decode
+                    if tid:
+                        TRACER.record(tid, STAGE_DECODE, t0, now,
+                                      tag=len(body))
+                    # the route span starts post-decode; set for an
+                    # unsampled request too: the stamp tells the server's
+                    # route site that the sampling decision has been made
+                    INGRESS_NS.set(now)
+                else:
+                    request = RaftClientRequest.from_bytes(body)
                 if fanout is not None:
                     attach_reply_sink(
                         request, fanout.sink_for(call_seq,
