@@ -1275,32 +1275,12 @@ class GrpcConfigKeys:
 
 
 class WireConfigKeys:
-    """Wire hot-path write coalescing (no reference analog — the reference
-    pays one Netty/HTTP2 flush per message and amortizes via one stream per
-    (group, follower), GrpcLogAppender.java:343-381; this framework folds
-    RPCs instead, so the per-frame ``write()+drain()`` syscall pair became
-    the next measured wall).  A per-connection send queue batches pending
-    frames into ONE buffered flush once ``flush-bytes`` are pending or
-    ``flush-micros`` of latency budget has elapsed (0µs = flush at the next
-    event-loop pass, which batches everything enqueued in the current pass
-    at zero added latency).  Both thresholds 0 (the default) = the exact
-    per-frame write+drain path, byte-identical on the wire."""
-
-    class Tcp:
-        FLUSH_BYTES_KEY = "raft.tpu.tcp.flush-bytes"
-        FLUSH_BYTES_DEFAULT = "0B"  # 0 = per-frame (coalescing off)
-        FLUSH_MICROS_KEY = "raft.tpu.tcp.flush-micros"
-        FLUSH_MICROS_DEFAULT = 0
-
-        @staticmethod
-        def flush_bytes(p: RaftProperties) -> int:
-            return p.get_size(WireConfigKeys.Tcp.FLUSH_BYTES_KEY,
-                              WireConfigKeys.Tcp.FLUSH_BYTES_DEFAULT)
-
-        @staticmethod
-        def flush_micros(p: RaftProperties) -> int:
-            return p.get_int(WireConfigKeys.Tcp.FLUSH_MICROS_KEY,
-                             WireConfigKeys.Tcp.FLUSH_MICROS_DEFAULT)
+    """Wire hot-path write coalescing of the gRPC transport (no reference
+    analog — the reference pays one HTTP2 flush per message and amortizes
+    via one stream per (group, follower), GrpcLogAppender.java:343-381;
+    this framework folds RPCs instead).  The TCP transport has no key: it
+    writes what a loop pass queued on a connection in one socket write
+    (transport/tcp.py)."""
 
     class Grpc:
         """Stream-framing coalescing for the grpc.aio transport: one bidi

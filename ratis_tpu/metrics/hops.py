@@ -25,12 +25,13 @@ cluster-wide commit count.  Sites:
 - ``reply_window`` — one per-request ordered-window future resolution
   carrying a real reply (second wakeup of the legacy chain; absent when
   the client skips the sliding window).
-- ``reply_send``   — one per-request reply handed to the transport's
-  per-request send/drain path (the handler task suspends for the
-  flush/drain; third wakeup of the legacy chain on socket transports).
-- ``reply_flush``  — one per-connection reply-drain callback armed by
-  the transport's deferred-reply batcher (sweep mode's replacement for
-  ALL of the above: one scheduled callback per connection per burst).
+- ``reply_send``   — one per-request reply handed to the transport by
+  its own handler task (third wakeup of the legacy chain on socket
+  transports; over gRPC the task also suspends for the flush).
+- ``reply_flush``  — one per-connection callback armed by the
+  transport's deferred-reply path (sweep mode's replacement for ALL of
+  the above: one scheduled callback per connection per burst — over TCP
+  the connection's one write of the loop pass).
 - ``reply_batch``  — one waterline fan-out pass resolving a whole batch
   of committed requests.  NOT a hop (the pass is a synchronous call the
   apply loop was running anyway); counted for batch-size observability

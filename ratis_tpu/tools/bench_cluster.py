@@ -148,16 +148,11 @@ def bench_properties(batched: bool, num_groups: int = 1,
         p.set("raft.tpu.engine.scalar-fallback-threshold", "0")
         p.set(RaftServerConfigKeys.Log.Appender.COALESCING_ENABLED_KEY, "true")
         p.set(RaftServerConfigKeys.Heartbeat.COALESCING_ENABLED_KEY, "true")
-        # Wire write coalescing (raft.tpu.*, round 6): batch pending frames
-        # into one buffered flush per connection — the per-frame
-        # write()+drain() pair was the measured top host cost of the real
-        # TCP path once consensus itself left the latency path.  100µs of
-        # latency budget is noise against ~100ms commit p50; the byte
-        # threshold flushes big batches early.  Scalar mode keeps the
-        # reference's per-frame shape (these stay 0 there).
+        # gRPC stream-message coalescing (raft.tpu.grpc.*, round 6): 100µs
+        # of latency budget is noise against ~100ms commit p50.  Scalar
+        # mode keeps the reference's per-message shape (these stay 0
+        # there).  (TCP needs no key: one socket write per loop pass.)
         from ratis_tpu.conf.keys import WireConfigKeys
-        p.set(WireConfigKeys.Tcp.FLUSH_BYTES_KEY, "128KB")
-        p.set(WireConfigKeys.Tcp.FLUSH_MICROS_KEY, "100")
         p.set(WireConfigKeys.Grpc.FLUSH_MICROS_KEY, "100")
         p.set(WireConfigKeys.Grpc.FLUSH_CHUNKS_KEY, "64")
         if hibernate:

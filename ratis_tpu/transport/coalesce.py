@@ -1,25 +1,19 @@
-"""Send-side write coalescing for the real-socket transports.
+"""Send-side write coalescing for the gRPC transport's stream framing.
 
 Round-5 tracing (BENCH_r05 host_path_decomposition + docs/perf.md) put the
-north-star residual in the host wire path, not in consensus: the same host
-does 3205.8 commits/s over the sim transport but 1025 over TCP at 5-peer x
-10240 groups.  A dominant share of that gap is the per-frame
-``write() + await drain()`` pattern — every frame pays a drain await (a
-task switch + flow-control check) and, under a send lock, serializes every
-concurrent caller on the connection through it.
+north-star residual in the host wire path, not in consensus; a dominant
+share of it was one flush (a task switch + flow-control check) per frame.
+:class:`WriteCoalescer` replaces that with a per-connection send queue:
+frames accumulate while one flush is pending, and the whole batch goes out
+as ONE flush.  (The TCP transport left it in PR 26: it writes what a loop
+pass queued in one socket write from a plain callback, transport/tcp.py.)
+Policy (``raft.tpu.grpc.*`` keys, conf/keys.py):
 
-:class:`WriteCoalescer` replaces the pattern with a per-connection send
-queue: frames accumulate while one buffered flush is pending, and the whole
-batch goes to the transport as a single writev-style write + ONE drain.
-Policy (``raft.tpu.tcp.*`` / ``raft.tpu.grpc.*`` keys, conf/keys.py):
-
-- ``flush_bytes`` > 0: flush as soon as that many bytes are pending.
 - ``flush_micros`` > 0: wait at most that long for more frames before
-  flushing; 0 flushes at the *next event-loop pass*, which batches every
-  frame enqueued in the current pass at zero added latency.
-- both 0 (the default): coalescing OFF — each ``send`` performs the exact
-  write+drain of the per-frame path, serialized, byte-identical on the
-  wire (asserted in tests/test_wire_fastpath.py).
+  flushing; ``max_frames`` > 0 flushes at once when that many are pending.
+- ``flush_micros`` 0 (the default): coalescing OFF — each ``send``
+  performs exactly one flush of its one frame, serialized (asserted in
+  tests/test_wire_fastpath.py).
 
 Failure contract: a flush error fails every send awaiting that batch and
 POISONS the coalescer — some frames of the batch may be half-written, so
@@ -42,16 +36,12 @@ class WriteCoalescer:
     """Batches outbound frames into single transport flushes.
 
     Generic over the flush primitive: subclasses implement
-    :meth:`_flush_batch` (the TCP transport joins frame bytes and performs
-    one ``write+drain``; the gRPC transport packs chunks into one stream
-    message).  ``max_frames`` additionally caps frames per flush (0 =
-    unbounded) — the gRPC framing uses it so one stream message never
-    carries an unbounded chunk list.
+    :meth:`_flush_batch` (the gRPC transport packs chunks into one stream
+    message).  ``max_frames`` caps frames per flush (0 = unbounded), so
+    one stream message never carries an unbounded chunk list.
     """
 
-    def __init__(self, flush_bytes: int = 0, flush_micros: int = 0,
-                 max_frames: int = 0):
-        self.flush_bytes = int(flush_bytes)
+    def __init__(self, flush_micros: int = 0, max_frames: int = 0):
         self.flush_micros = int(flush_micros)
         self.max_frames = int(max_frames)
         self._pending: list = []
@@ -70,7 +60,7 @@ class WriteCoalescer:
 
     @property
     def coalescing(self) -> bool:
-        return self.flush_bytes > 0 or self.flush_micros > 0
+        return self.flush_micros > 0
 
     @property
     def poisoned(self) -> bool:
@@ -99,38 +89,16 @@ class WriteCoalescer:
         self._pending.append(frame)
         self._pending_bytes += nbytes
         self._waiters.append(fut)
-        if (0 < self.flush_bytes <= self._pending_bytes
-                or (self.max_frames
-                    and len(self._pending) >= self.max_frames)):
+        if self.max_frames and len(self._pending) >= self.max_frames:
             await self._flush_now()
         elif self._flusher is None:
             self._flusher = asyncio.create_task(self._flush_after_delay())
         await fut
 
-    def send_nowait(self, frame, nbytes: int) -> None:
-        """Fire-and-forget enqueue: the frame joins the pending batch and
-        the flusher (armed at most once per batch) writes it out on the
-        next pass — REGARDLESS of the flush thresholds, so deferred-reply
-        fan-out batches coalesce even on a connection configured for the
-        per-frame path.  No backpressure: callers are reply producers
-        whose volume is bounded by the connection's in-flight requests; a
-        dead coalescer drops the frame (the connection is gone and its
-        client will retry/timeout exactly as with a torn socket)."""
-        if self._dead is not None:
-            return
-        self._pending.append(frame)
-        self._pending_bytes += nbytes
-        if self._flusher is None:
-            self._flusher = asyncio.get_running_loop().create_task(
-                self._flush_after_delay())
-
     async def _flush_after_delay(self) -> None:
         try:
             while self._pending and self._dead is None:
-                if self.flush_micros:
-                    await asyncio.sleep(self.flush_micros / 1e6)
-                else:
-                    await asyncio.sleep(0)  # batch the current loop pass
+                await asyncio.sleep(self.flush_micros / 1e6)
                 await self._flush_now()
         finally:
             self._flusher = None

@@ -5,20 +5,37 @@ Capability parity with the reference Netty transport
 + NettyRpcProxy + Netty.proto:31-48): a single length-prefixed
 request/reply envelope union over all RPCs — server-to-server consensus
 traffic and client requests share one listening port, exactly like the
-reference's RaftNettyServerRequestProto union.  asyncio streams take the
-place of Netty's event loop; connections are cached per destination and
-multiplex concurrent calls by a request sequence number.
+reference's RaftNettyServerRequestProto union.  Connections are cached per
+destination and multiplex concurrent calls by a request sequence number.
 
 Frame: u32 length | u64 call_seq | u8 kind | msgpack body.
 kind: 1=server-rpc 2=client-request 3=reply 4=error-reply.
+
+A frame costs callbacks, not tasks: both ends of a connection are one
+``asyncio.Protocol`` (:class:`_FramedProtocol`; in Netty's terms the frame
+decoder and the flush on read-complete).  ``data_received`` parses every
+whole frame it holds in that one callback: a reply resolves its call's
+future there, a request's handler starts there (an eagerly started task, so
+one that never suspends never becomes a scheduled task).  ``send`` is a
+synchronous enqueue, and what a loop pass queued leaves in ONE
+``transport.write`` at the end of the pass (frames are length-prefixed: the
+joined bytes are the frames' bytes in order).  Only while the transport is
+paused does a sender wait, on one future per connection.
+
+Failure contract: a write error or a lost connection fails every pending
+call and POISONS the connection — a batch may be half-written, so later
+sends fail fast and the pool dials anew; the error never escapes into the
+event loop.  Frames still queued go out on ``close()`` and at the peer's EOF.
 """
 
 from __future__ import annotations
 
 import asyncio
+import collections
 import itertools
 import logging
 import struct
+import threading
 from typing import Callable, Dict, Optional
 
 from ratis_tpu.metrics.hops import hop
@@ -32,11 +49,10 @@ from ratis_tpu.protocol.requests import (DEFERRED_REPLY, RaftClientReply,
                                          attach_reply_sink)
 from ratis_tpu.trace.tracer import (INGRESS_NS, STAGE_DECODE, STAGE_ENCODE,
                                     STAGE_RESPOND, STAGE_TCP_READ, STAGE_WIRE,
-                                    STAGE_WIRE_FLUSH, TRACER)
+                                    STAGE_WIRE_FLUSH, TRACER, loop_key)
 from ratis_tpu.transport.base import (ClientRequestHandler, ClientTransport,
                                       ServerRpcHandler, ServerTransport,
                                       TransportFactory)
-from ratis_tpu.transport.coalesce import WriteCoalescer
 
 LOG = logging.getLogger(__name__)
 
@@ -46,6 +62,7 @@ KIND_REPLY = 3
 KIND_ERROR = 4
 
 _FRAME = struct.Struct(">IQB")
+_HEADER = _FRAME.size           # 13: the length prefix and what it counts first
 MAX_FRAME = 256 << 20
 
 
@@ -53,37 +70,174 @@ def _encode_frame(call_seq: int, kind: int, body: bytes) -> bytes:
     return _FRAME.pack(9 + len(body), call_seq, kind) + body
 
 
-class _StreamFrameCoalescer(WriteCoalescer):
-    """WriteCoalescer over an asyncio StreamWriter: the batch goes out as
-    ONE buffered write (frames are already length-prefixed, so joining is
-    byte-identical to writing them one by one) followed by ONE drain."""
+class _FramedProtocol(asyncio.Protocol):
+    """One TCP connection's framing, the same for both ends; a subclass says
+    what a frame is to it (:meth:`_frame`) and what dies with the connection
+    (:meth:`_lost`).  Everything here runs on the connection's loop."""
 
-    def __init__(self, writer: asyncio.StreamWriter,
-                 flush_bytes: int = 0, flush_micros: int = 0):
-        super().__init__(flush_bytes=flush_bytes, flush_micros=flush_micros)
-        self._writer = writer
+    def __init__(self, label: str) -> None:
+        self.label = label
+        self.loop: Optional[asyncio.AbstractEventLoop] = None  # when made
+        self._transport: Optional[asyncio.Transport] = None
+        self._rbuf = bytearray()        # the bytes of a frame not yet whole
+        self._out: list[bytes] = []     # frames queued in this loop pass
+        self.flush_armed = False
+        # set while the transport holds more than its high-water mark
+        self._writable: Optional[asyncio.Future] = None
+        self._closed: Optional[asyncio.Future] = None
+        self.dead: Optional[Exception] = None
 
-    async def _flush_batch(self, frames: list) -> None:
-        w = self._writer
-        # wire.flush work span: the buffered write, which is the socket's
-        # send while its buffer is empty; the drain may wait and lies outside
+    # -- the transport's callbacks -----------------------------------------
+
+    def connection_made(self, transport) -> None:
+        self.loop = asyncio.get_running_loop()
+        self._transport = transport
+        self._closed = self.loop.create_future()
+        # frames and bytes are the process's wire counters (ratis_tpu.trace:
+        # always on, a trace session snapshots them), one pair per loop so
+        # that every add comes from one thread
+        key = loop_key(self.loop)
+        self._n_frames = TRACER.counter("wire.frames", key)
+        self._n_bytes = TRACER.counter("wire.bytes", key)
+
+    def data_received(self, data) -> None:
+        # tcp.read work span: one read wake-up of the connection, from its
+        # first frame parsed to the last one handed on (tag = frames)
+        span = TRACER.begin(STAGE_TCP_READ) if TRACER.enabled else None
+        frames = 0
+        try:
+            buf = self._rbuf
+            if buf:             # a frame straddles reads: parse the joined bytes
+                buf += data
+                data = buf
+            pos, end = 0, len(data)
+            while end - pos >= _HEADER:
+                length, call_seq, kind = _FRAME.unpack_from(data, pos)
+                if length < 9 or length > MAX_FRAME:
+                    self._abort(ConnectionError(
+                        f"{self.label}: bad frame length {length}"))
+                    return
+                nxt = pos + 4 + length
+                if nxt > end:
+                    break
+                body = data[pos + _HEADER:nxt]
+                pos = nxt
+                frames += 1
+                self._frame(call_seq, kind,
+                            bytes(body) if data is buf else body)
+            if data is buf:
+                del buf[:pos]
+            elif pos < end:
+                buf += data[pos:]
+        finally:
+            if span is not None:
+                TRACER.end(span, tag=frames)
+
+    def eof_received(self):
+        if self._out:
+            self._flush()       # queued replies reach a half-closed peer
+        return False            # and the transport closes
+
+    def pause_writing(self) -> None:
+        if self._writable is None:
+            self._writable = self.loop.create_future()
+
+    def resume_writing(self) -> None:
+        w, self._writable = self._writable, None
+        if w is not None and not w.done():
+            w.set_result(None)
+
+    def connection_lost(self, exc) -> None:
+        self._fail(ConnectionError(f"{self.label} lost: {exc}" if exc
+                                   else f"{self.label} closed"))
+        if not self._closed.done():
+            self._closed.set_result(None)
+
+    # -- what a subclass is ------------------------------------------------
+
+    def _frame(self, call_seq: int, kind: int, body: bytes) -> None:
+        raise NotImplementedError
+
+    def _lost(self, exc: Exception) -> None:
+        """The connection is unusable: fail or cancel what waits on it."""
+
+    # -- write -------------------------------------------------------------
+
+    def send(self, frame: bytes) -> None:
+        """Queue ``frame`` for this loop pass's one write, in call order;
+        raises what killed the connection.  A sender then honours the
+        transport's flow control: ``if conn.paused: await
+        conn.wait_writable()`` (write, then drain)."""
+        if self.dead is not None:
+            raise self.dead
+        self._out.append(frame)
+        if not self.flush_armed:
+            self.flush_armed = True
+            self.loop.call_soon(self._flush)
+
+    def _flush(self) -> None:
+        self.flush_armed = False
+        frames = self._out
+        if not frames:
+            return
+        self._out = []
+        # wire.flush work span: the one buffered write of this pass, which
+        # is the socket's send while the transport's buffer is empty
         span = TRACER.begin(STAGE_WIRE_FLUSH) if TRACER.enabled else None
         try:
-            w.write(frames[0] if len(frames) == 1 else b"".join(frames))
+            data = frames[0] if len(frames) == 1 else b"".join(frames)
+            self._transport.write(data)
+        except Exception as e:
+            # some of the batch may be on the wire: poison, never raise into
+            # the loop
+            err = ConnectionError(f"{self.label} write failed: {e!r}")
+            err.__cause__ = e
+            self._abort(err)
+            return
         finally:
             if span is not None:
                 TRACER.end(span, tag=len(frames))
-        await w.drain()
+        self._n_frames.n += len(frames)
+        self._n_bytes.n += len(data)
 
+    @property
+    def paused(self) -> bool:
+        return self._writable is not None
 
-def _flush_conf(properties) -> tuple[int, int]:
-    """(flush_bytes, flush_micros) for the TCP transport; (0, 0) — the
-    per-frame path — when unconfigured."""
-    if properties is None:
-        return 0, 0
-    from ratis_tpu.conf.keys import WireConfigKeys
-    return (WireConfigKeys.Tcp.flush_bytes(properties),
-            WireConfigKeys.Tcp.flush_micros(properties))
+    async def wait_writable(self) -> None:
+        """Wait out the transport's flow control, or the connection's end.
+        (Shielded: the future is the connection's, one waiter's cancellation
+        must not cancel the others'.)"""
+        while self._writable is not None:
+            await asyncio.shield(self._writable)
+
+    # -- failure and close -------------------------------------------------
+
+    def _fail(self, exc: Exception) -> None:
+        if self.dead is None:
+            self.dead = exc
+        self._out.clear()
+        self.resume_writing()   # the waiters find ``dead``
+        self._lost(self.dead)
+
+    def _abort(self, exc: Exception) -> None:
+        self._fail(exc)
+        self._transport.abort()
+
+    def close_nowait(self) -> None:
+        """Write what is queued (flush-on-close) and close; the transport
+        sends what it has buffered, then ``connection_lost`` follows."""
+        if self._transport is None:
+            return
+        if self._out and self.dead is None:
+            self._flush()
+        self._fail(ConnectionError(f"{self.label} closed"))
+        self._transport.close()
+
+    async def close(self) -> None:
+        self.close_nowait()
+        if self._closed is not None:
+            await self._closed
 
 
 def _defer_conf(properties) -> bool:
@@ -97,22 +251,20 @@ def _defer_conf(properties) -> bool:
 
 
 class _DeferredReplyFanout:
-    """Per-connection deferred-reply batcher: the division's waterline
-    fan-out calls :meth:`submit` synchronously (possibly from a shard
-    loop); replies queue here and ONE armed callback per burst drains them
-    into the connection's write coalescer — one scheduled hop per batch
-    per connection, replacing the per-request handler-resume + send-wait
-    chain the traced decomposition measured as ``server.reply`` /
-    ``server.respond``."""
+    """Per-connection deferred replies: the division's waterline fan-out
+    calls :meth:`submit` synchronously at commit.  On the connection's own
+    loop (one loop: every deployment without loop shards) the reply frame
+    joins the connection's write of this pass at once.  From another loop
+    (``raft.tpu.server.loop-shards`` > 1) replies queue here and ONE
+    ``call_soon_threadsafe`` per burst carries them over.  Either way a
+    burst costs one scheduled callback per connection, replacing the
+    per-request handler-resume + send-wait chain the traced decomposition
+    measured as ``server.reply`` / ``server.respond``."""
 
-    __slots__ = ("_conn_out", "_loop", "_q", "_lock", "_armed")
+    __slots__ = ("_conn", "_q", "_lock", "_armed")
 
-    def __init__(self, conn_out: "_StreamFrameCoalescer",
-                 loop: asyncio.AbstractEventLoop) -> None:
-        import collections
-        import threading
-        self._conn_out = conn_out
-        self._loop = loop
+    def __init__(self, conn: "_Accepted") -> None:
+        self._conn = conn               # made: its loop is the connection's
         self._q = collections.deque()
         self._lock = threading.Lock()
         self._armed = False
@@ -130,6 +282,15 @@ class _DeferredReplyFanout:
         # the connection's loop, which only performs the buffered write
         body = reply.to_bytes()
         frame = _encode_frame(call_seq, KIND_REPLY, body)
+        try:
+            same_loop = asyncio.get_running_loop() is self._conn.loop
+        except RuntimeError:
+            same_loop = False
+        if same_loop:
+            if not self._conn.flush_armed:
+                hop("reply_flush")      # this reply arms the pass's write
+            self._hand_over(frame, tid, t0, len(body))
+            return
         with self._lock:
             self._q.append((frame, tid, t0, len(body)))
             if self._armed:
@@ -137,7 +298,7 @@ class _DeferredReplyFanout:
             self._armed = True
         hop("reply_flush")
         try:
-            self._loop.call_soon_threadsafe(self._drain)
+            self._conn.loop.call_soon_threadsafe(self._drain)
         except RuntimeError:
             pass  # connection loop closed: the client sees a torn socket
 
@@ -146,83 +307,28 @@ class _DeferredReplyFanout:
             items = list(self._q)
             self._q.clear()
             self._armed = False
-        now = TRACER.now() if TRACER.enabled else 0
-        for frame, tid, t0, nbody in items:
-            try:
-                self._conn_out.send_nowait(frame, len(frame))
-            except Exception:
-                return  # connection dead; remaining frames undeliverable
-            if tid and t0:
-                # respond span (deferred shape): reply ready at the
-                # division -> handed to this connection's batched write
-                # path (the flush itself is the coalescer's single
-                # write+drain per batch)
-                TRACER.record(tid, STAGE_RESPOND, t0, now, tag=nbody)
+        for item in items:
+            self._hand_over(*item)
 
-
-def _frame_buffered(reader: asyncio.StreamReader) -> bool:
-    """Whether the next :func:`_read_frame` returns without suspending: a
-    whole frame already sits in the reader's buffer.  (CPython's
-    StreamReader keeps it in ``_buffer``; one without it reads False, and
-    every frame is then a burst of its own.)"""
-    buf = getattr(reader, "_buffer", None)
-    if buf is None or len(buf) < 4:
-        return False
-    return len(buf) >= 4 + int.from_bytes(buf[:4], "big")
-
-
-class _ReadBurst:
-    """The ``tcp.read`` work span of one connection's read loop: opened when
-    a frame arrives, kept open while whole frames are still buffered (they
-    are read without suspending), closed before the read that will wait.
-    One burst is the frames one wake-up of the connection hands on, from
-    the first one parsed to the last one's hand-off (tag = frames)."""
-
-    __slots__ = ("span", "_frames")
-
-    def __init__(self) -> None:
-        self.span = None      # open work span; None costs a read loop nothing
-        self._frames = 0
-
-    def frame(self) -> None:
-        """A frame was read (call only while ``TRACER.enabled``)."""
-        if self.span is None:
-            self.span = TRACER.begin(STAGE_TCP_READ)
-            self._frames = 0
-        self._frames += 1
-
-    def before_read(self, reader: asyncio.StreamReader) -> None:
-        """Call while ``span`` is open, before the next read."""
-        if not _frame_buffered(reader):
-            self.close()
-
-    def close(self) -> None:
-        if self.span is not None:
-            TRACER.end(self.span, tag=self._frames)
-            self.span = None
-
-
-async def _read_frame(reader: asyncio.StreamReader):
-    """(call_seq, kind, body) or None on clean EOF."""
-    try:
-        prefix = await reader.readexactly(4)
-    except asyncio.IncompleteReadError as e:
-        if not e.partial:
-            return None
-        raise ConnectionError("truncated frame") from None
-    (length,) = struct.unpack(">I", prefix)
-    if length < 9 or length > MAX_FRAME:
-        raise ConnectionError(f"bad frame length {length}")
-    body = await reader.readexactly(length)
-    _, call_seq, kind = _FRAME.unpack(prefix + body[:9])
-    return call_seq, kind, body[9:]
+    def _hand_over(self, frame: bytes, tid: int, t0: int, nbody: int) -> None:
+        """No backpressure: replies are bounded by the connection's requests
+        in flight; a dead connection drops the frame (its client retries or
+        times out exactly as with a torn socket)."""
+        conn = self._conn
+        if conn.dead is not None:
+            return
+        conn.send(frame)
+        if tid and t0:
+            # respond span (deferred shape): reply ready at the division ->
+            # queued on this connection for the pass's one write
+            TRACER.record(tid, STAGE_RESPOND, t0, TRACER.now(), tag=nbody)
 
 
 class TcpTlsConfig:
     """TLS for the raw-TCP transport (NettyConfigKeys.Tls): same parameter
     surface as the gRPC GrpcTlsConfig — cert chain + key server-side,
     optional trust root, optional mutual auth — applied as ssl contexts on
-    asyncio start_server / open_connection."""
+    the loop's create_server / create_connection."""
 
     def __init__(self, cert_chain_path=None, private_key_path=None,
                  trust_root_path=None, mutual_auth=False):
@@ -279,104 +385,87 @@ class TcpTlsConfig:
         return ctx
 
 
-class _Connection:
+class _Connection(_FramedProtocol):
     """One outbound connection multiplexing calls by sequence number
-    (reference NettyRpcProxy channel)."""
+    (reference NettyRpcProxy channel).  A frame read is a reply: it resolves
+    its call's future in the read callback.  One deadline timer per
+    connection stands over the pending calls (not one per call)."""
 
-    def __init__(self, address: str, tls=None,
-                 flush_bytes: int = 0, flush_micros: int = 0) -> None:
-        self.address = address
+    def __init__(self, address: str, tls=None) -> None:
+        super().__init__(address)         # the label is the peer's address
         self._tls = tls
-        self._flush_bytes = flush_bytes
-        self._flush_micros = flush_micros
         self._seq = itertools.count(1)
-        self._pending: Dict[int, asyncio.Future] = {}
-        self._writer: Optional[asyncio.StreamWriter] = None
-        self._reader: Optional[asyncio.StreamReader] = None
-        self._out: Optional[_StreamFrameCoalescer] = None
-        self._recv_task: Optional[asyncio.Task] = None
-        self._dead: Optional[Exception] = None
-        self.loop: Optional[asyncio.AbstractEventLoop] = None  # at connect
+        # call_seq -> (the call's future, its deadline on the loop's clock)
+        self._pending: Dict[int, tuple[asyncio.Future, float]] = {}
+        self._timer: Optional[asyncio.TimerHandle] = None
+        self._timer_at = 0.0
 
     async def connect(self) -> None:
-        self.loop = asyncio.get_running_loop()
-        host, port = self.address.rsplit(":", 1)
+        host, port = self.label.rsplit(":", 1)
         ssl_ctx = self._tls.client_context() if self._tls is not None else None
-        self._reader, self._writer = await asyncio.open_connection(
-            host, int(port), ssl=ssl_ctx)
-        self._out = _StreamFrameCoalescer(self._writer, self._flush_bytes,
-                                          self._flush_micros)
-        self._recv_task = asyncio.create_task(
-            self._recv_loop(), name=f"tcp-rpc-recv-{self.address}")
-
-    async def _recv_loop(self) -> None:
-        cause: Exception = ConnectionError(f"{self.address} closed")
-        burst = _ReadBurst()
-        try:
-            while True:
-                if burst.span is not None:
-                    burst.before_read(self._reader)
-                frame = await _read_frame(self._reader)
-                if frame is None:
-                    break
-                if TRACER.enabled:
-                    burst.frame()
-                call_seq, kind, body = frame
-                fut = self._pending.pop(call_seq, None)
-                if fut is not None and not fut.done():
-                    fut.set_result((kind, body))
-        except (ConnectionError, OSError, asyncio.CancelledError) as e:
-            cause = ConnectionError(f"{self.address} lost: {e}")
-        finally:
-            burst.close()
-            self._dead = cause
-            for fut in self._pending.values():
-                if not fut.done():
-                    fut.set_exception(cause)
-            self._pending.clear()
+        await asyncio.get_running_loop().create_connection(
+            lambda: self, host, int(port), ssl=ssl_ctx)
 
     @property
     def alive(self) -> bool:
-        return (self._writer is not None and self._dead is None
-                and not self._out.poisoned)
+        return self._transport is not None and self.dead is None
+
+    def _frame(self, call_seq: int, kind: int, body: bytes) -> None:
+        entry = self._pending.pop(call_seq, None)
+        if entry is not None and not entry[0].done():
+            entry[0].set_result((kind, body))
+
+    def _lost(self, exc: Exception) -> None:
+        pending, self._pending = self._pending, {}
+        for fut, _deadline in pending.values():
+            if not fut.done():
+                fut.set_exception(exc)
+        if self._timer is not None:
+            self._timer.cancel()
+            self._timer = None
 
     async def call(self, kind: int, body: bytes,
                    timeout_s: float) -> tuple[int, bytes]:
-        if self._dead is not None:
-            raise self._dead
+        if self.dead is not None:
+            raise self.dead
+        loop = self.loop
         seq = next(self._seq)
-        fut = asyncio.get_running_loop().create_future()
-        self._pending[seq] = fut
-        frame = _encode_frame(seq, kind, body)
+        fut = loop.create_future()
+        deadline = loop.time() + timeout_s
+        self._pending[seq] = (fut, deadline)
+        self.send(_encode_frame(seq, kind, body))
+        if self._timer is None or deadline < self._timer_at:
+            self._arm(deadline)
         try:
-            await self._out.send(frame, len(frame))
-        except BaseException:
+            if self._writable is not None:
+                await self.wait_writable()
+            return await fut
+        except asyncio.CancelledError:
             self._pending.pop(seq, None)
             raise
-        try:
-            return await asyncio.wait_for(fut, timeout_s)
-        except asyncio.TimeoutError:
-            self._pending.pop(seq, None)
-            raise TimeoutIOException(
-                f"rpc to {self.address} timed out after {timeout_s}s") \
-                from None
 
-    async def close(self) -> None:
-        if self._recv_task is not None:
-            self._recv_task.cancel()
-            try:
-                await self._recv_task
-            except asyncio.CancelledError:
-                pass
-        if self._out is not None:
-            # flush-on-close: frames already queued must reach the wire
-            await self._out.aclose()
-        if self._writer is not None:
-            self._writer.close()
-            try:
-                await self._writer.wait_closed()
-            except (ConnectionError, OSError):
-                pass
+    def _arm(self, deadline: float) -> None:
+        if self._timer is not None:
+            self._timer.cancel()
+        self._timer_at = deadline
+        self._timer = self.loop.call_at(deadline, self._on_deadline)
+
+    def _on_deadline(self) -> None:
+        """Fail the calls whose deadline has passed and stand over the
+        earliest one left (with one timeout for all, the oldest)."""
+        self._timer = None
+        now = self.loop.time()
+        earliest = None
+        for seq, (fut, deadline) in list(self._pending.items()):
+            if deadline <= now:
+                del self._pending[seq]
+                if not fut.done():
+                    fut.set_exception(TimeoutIOException(
+                        f"rpc to {self.label} timed out"))
+            elif earliest is None or deadline < earliest:
+                earliest = deadline
+        if earliest is not None:
+            self._arm(earliest)
 
 
 class _ConnectionPool:
@@ -385,23 +474,22 @@ class _ConnectionPool:
 
     Keyed per loop on purpose: with loop sharding
     (raft.tpu.server.loop-shards) divisions pinned to worker loops send
-    through this pool from their own threads, and an asyncio connection
-    (StreamWriter, drain waiters, recv task) is loop-affine — so each
-    shard dials its own connection per destination, which also gives each
-    shard an independent send pipe instead of one shared serialized
-    writer.  Single-loop runtimes see exactly the old one-connection-per-
-    address behavior."""
+    through this pool from their own threads, and a connection (its
+    transport, futures and timer) is loop-affine — so each shard dials its
+    own connection per destination, which also gives each shard an
+    independent send pipe instead of one shared serialized writer.
+    Single-loop runtimes see exactly one connection per address."""
 
-    def __init__(self, tls=None, flush_bytes: int = 0,
-                 flush_micros: int = 0) -> None:
+    def __init__(self, tls=None) -> None:
         self._conns: Dict[tuple[int, str], _Connection] = {}
         self._locks: Dict[tuple[int, str], asyncio.Lock] = {}
         self._tls = tls
-        self._flush_bytes = flush_bytes
-        self._flush_micros = flush_micros
 
     async def get(self, address: str) -> _Connection:
         key = (id(asyncio.get_running_loop()), address)
+        conn = self._conns.get(key)
+        if conn is not None and conn.alive:
+            return conn
         lock = self._locks.setdefault(key, asyncio.Lock())
         async with lock:
             conn = self._conns.get(key)
@@ -409,9 +497,7 @@ class _ConnectionPool:
                 return conn
             if conn is not None:
                 await conn.close()
-            conn = _Connection(address, tls=self._tls,
-                               flush_bytes=self._flush_bytes,
-                               flush_micros=self._flush_micros)
+            conn = _Connection(address, tls=self._tls)
             await conn.connect()
             self._conns[key] = conn
             return conn
@@ -428,7 +514,7 @@ class _ConnectionPool:
             if conn.loop is None or conn.loop is current:
                 await conn.close()
             elif conn.loop.is_running():
-                # shard-owned connection: its recv task and writer must be
+                # shard-owned connection: its transport and futures must be
                 # unwound on the loop they live on
                 try:
                     await asyncio.wrap_future(
@@ -438,9 +524,43 @@ class _ConnectionPool:
                     pass  # connection already broken; socket dies with it
             else:
                 # owner loop gone (test teardown): close the raw transport
-                # so the fd is released; tasks on the dead loop never run
-                if conn._writer is not None:
-                    conn._writer.close()
+                # so the fd is released; callbacks on the dead loop never run
+                try:
+                    conn.close_nowait()
+                except RuntimeError:
+                    pass  # that loop is closed
+
+
+class _Accepted(_FramedProtocol):
+    """One accepted connection of a :class:`TcpServerTransport`.  A frame
+    read is a request: its handler starts at once, inside the read callback
+    (an eagerly started task), so a request that never suspends is served in
+    the loop pass that read it, and concurrent ones do not head-of-line
+    block the connection (gRPC gives that for free)."""
+
+    def __init__(self, server: "TcpServerTransport") -> None:
+        super().__init__(f"{server.peer_id} accepted")
+        self._server = server
+        self._tasks: set[asyncio.Task] = set()
+        self.fanout: Optional[_DeferredReplyFanout] = None
+
+    def connection_made(self, transport) -> None:
+        super().connection_made(transport)
+        self._server._accepted.add(self)
+        if self._server.defer_replies:
+            self.fanout = _DeferredReplyFanout(self)
+
+    def _frame(self, call_seq: int, kind: int, body: bytes) -> None:
+        t = asyncio.Task(self._server._serve_one(call_seq, kind, body, self),
+                         loop=self.loop, eager_start=True)
+        if not t.done():        # it suspended: keep it (the loop holds weakly)
+            self._tasks.add(t)
+            t.add_done_callback(self._tasks.discard)
+
+    def _lost(self, exc: Exception) -> None:
+        self._server._accepted.discard(self)
+        for t in self._tasks:
+            t.cancel()
 
 
 class TcpServerTransport(ServerTransport):
@@ -454,7 +574,6 @@ class TcpServerTransport(ServerTransport):
                                                   Optional[str]]] = None,
                  request_timeout_s: float = 3.0,
                  tls: "TcpTlsConfig | None" = None,
-                 flush_bytes: int = 0, flush_micros: int = 0,
                  defer_replies: bool = False, chaos: bool = False):
         self.peer_id = peer_id
         self._address = address
@@ -468,72 +587,23 @@ class TcpServerTransport(ServerTransport):
         # (ratis_tpu.chaos.link) — partitions/latency/drop on real sockets
         self.chaos = chaos
         self.tls = tls
-        self.flush_bytes = flush_bytes
-        self.flush_micros = flush_micros
         # commit fan-out collapse: attach a per-connection deferred-reply
         # sink to client requests (the division decides per request
         # whether to engage it; see _DeferredReplyFanout)
         self.defer_replies = defer_replies
         self._server: Optional[asyncio.AbstractServer] = None
-        self._pool = _ConnectionPool(tls=tls, flush_bytes=flush_bytes,
-                                     flush_micros=flush_micros)
-        self._accepted: set[asyncio.StreamWriter] = set()
+        self._pool = _ConnectionPool(tls=tls)
+        self._accepted: set[_Accepted] = set()
 
     async def start(self) -> None:
         host, port = self._address.rsplit(":", 1)
         ssl_ctx = self.tls.server_context() if self.tls is not None else None
-        self._server = await asyncio.start_server(self._on_connect, host,
-                                                  int(port), ssl=ssl_ctx)
+        self._server = await asyncio.get_running_loop().create_server(
+            lambda: _Accepted(self), host, int(port), ssl=ssl_ctx)
         self._bound_port = self._server.sockets[0].getsockname()[1]
 
-    async def _on_connect(self, reader: asyncio.StreamReader,
-                          writer: asyncio.StreamWriter) -> None:
-        self._accepted.add(writer)
-        # per-connection reply coalescer: concurrent _serve_one replies
-        # fold into one buffered flush + one drain per batch
-        conn_out = _StreamFrameCoalescer(writer, self.flush_bytes,
-                                         self.flush_micros)
-        fanout = (_DeferredReplyFanout(conn_out, asyncio.get_running_loop())
-                  if self.defer_replies else None)
-        tasks: set[asyncio.Task] = set()
-        burst = _ReadBurst()
-        try:
-            while True:
-                if burst.span is not None:
-                    burst.before_read(reader)
-                frame = await _read_frame(reader)
-                if frame is None:
-                    break
-                if TRACER.enabled:
-                    burst.frame()
-                # handle concurrently: one slow consensus RPC must not
-                # head-of-line-block the connection (gRPC gives this for
-                # free; here we spawn per-call tasks)
-                t = asyncio.create_task(
-                    self._serve_one(frame, conn_out, fanout))
-                tasks.add(t)
-                t.add_done_callback(tasks.discard)
-        except (ConnectionError, OSError):
-            pass
-        finally:
-            burst.close()
-            for t in tasks:
-                t.cancel()
-            try:
-                await conn_out.aclose()  # flush-on-close: queued replies
-            except (ConnectionError, OSError):
-                pass
-            self._accepted.discard(writer)
-            writer.close()
-            try:
-                await writer.wait_closed()
-            except (ConnectionError, OSError):
-                pass
-
-    async def _serve_one(self, frame, conn_out: _StreamFrameCoalescer,
-                         fanout: "Optional[_DeferredReplyFanout]" = None
-                         ) -> None:
-        call_seq, kind, body = frame
+    async def _serve_one(self, call_seq: int, kind: int, body: bytes,
+                         conn: _Accepted) -> None:
         trace_tid = trace_egress = 0
         client_reply = False
         try:
@@ -566,14 +636,14 @@ class TcpServerTransport(ServerTransport):
                     INGRESS_NS.set(now)
                 else:
                     request = RaftClientRequest.from_bytes(body)
-                if fanout is not None:
+                if conn.fanout is not None:
                     attach_reply_sink(
-                        request, fanout.sink_for(call_seq,
-                                                 request.trace_id))
+                        request, conn.fanout.sink_for(call_seq,
+                                                      request.trace_id))
                 reply = await self.client_handler(request)
                 if reply is DEFERRED_REPLY:
-                    # reply rides the per-connection fan-out batcher at
-                    # commit; this task is done at append time
+                    # reply rides the per-connection fan-out at commit;
+                    # this task is done at append time
                     return
                 trace_tid = request.trace_id
                 trace_egress = TRACER.pop_egress(trace_tid)
@@ -591,20 +661,19 @@ class TcpServerTransport(ServerTransport):
                 exception_to_wire(exc), use_bin_type=True)
         try:
             if client_reply:
-                # per-request commit->reply hop #3 (legacy path): this
-                # task suspends for the send/drain — the deferred-reply
-                # fan-out replaces it with one drain arm per connection
-                # per burst (metrics/hops.py reply_send vs reply_flush)
+                # per-request commit->reply hop #3 (legacy path): the reply
+                # is handed over per request — the deferred-reply fan-out
+                # hands a burst over in one pass (metrics/hops.py
+                # reply_send vs reply_flush)
                 hop("reply_send")
-            reply_frame = _encode_frame(call_seq, out_kind, out)
-            await conn_out.send(reply_frame, len(reply_frame))
+            conn.send(_encode_frame(call_seq, out_kind, out))
             if trace_egress:
-                # handler done -> reply serialized, framed, and drained to
-                # the socket (possibly as part of a coalesced batch): the
-                # real "reply write" cost on this transport — the respond
-                # span stays attributed across the coalesced flush
+                # handler done -> reply serialized, framed and queued for
+                # the connection's one write of this pass
                 TRACER.record(trace_tid, STAGE_RESPOND, trace_egress,
                               TRACER.now(), tag=len(out))
+            if conn.paused:
+                await conn.wait_writable()
         except (ConnectionError, OSError):
             pass
 
@@ -641,8 +710,8 @@ class TcpServerTransport(ServerTransport):
 
     async def close(self) -> None:
         await self._pool.close()
-        for writer in list(self._accepted):
-            writer.close()
+        for conn in list(self._accepted):
+            conn.close_nowait()
         if self._server is not None:
             self._server.close()
             await self._server.wait_closed()
@@ -658,10 +727,8 @@ def _decode_error(body: bytes) -> RaftException:
 
 class TcpClientTransport(ClientTransport):
     def __init__(self, request_timeout_s: float = 30.0,
-                 tls: "TcpTlsConfig | None" = None,
-                 flush_bytes: int = 0, flush_micros: int = 0):
-        self._pool = _ConnectionPool(tls=tls, flush_bytes=flush_bytes,
-                                     flush_micros=flush_micros)
+                 tls: "TcpTlsConfig | None" = None):
+        self._pool = _ConnectionPool(tls=tls)
         self.request_timeout_s = request_timeout_s
 
     async def send_request(self, peer_address: str,
@@ -703,21 +770,17 @@ class TcpTransportFactory(TransportFactory):
         if properties is not None:
             timeout_s = RaftServerConfigKeys.Rpc.request_timeout(
                 properties).seconds
-        fb, fm = _flush_conf(properties)
         chaos = (properties is not None
                  and RaftServerConfigKeys.Chaos.enabled(properties))
         return TcpServerTransport(peer_id, address, server_handler,
                                   client_handler, peer_resolver=peer_resolver,
                                   request_timeout_s=timeout_s,
                                   tls=TcpTlsConfig.from_properties(properties),
-                                  flush_bytes=fb, flush_micros=fm,
                                   defer_replies=_defer_conf(properties),
                                   chaos=chaos)
 
     def new_client_transport(self, properties=None) -> ClientTransport:
-        fb, fm = _flush_conf(properties)
-        return TcpClientTransport(tls=TcpTlsConfig.from_properties(properties),
-                                  flush_bytes=fb, flush_micros=fm)
+        return TcpClientTransport(tls=TcpTlsConfig.from_properties(properties))
 
 
 TransportFactory.register("NETTY", TcpTransportFactory())

@@ -207,7 +207,6 @@ def _by_tid(tracer, name):
 
 def _tcp_properties(sample_every=1):
     p = batched_properties()
-    p.set("raft.tpu.tcp.flush-micros", "100")
     p.set("raft.tpu.trace.sample-every", str(sample_every))
     return p
 
@@ -247,6 +246,58 @@ def test_untraced_request_over_tcp_is_traced_at_the_servers_ingress():
                  "server.reply", "server.respond"):
         assert set(_by_tid(tracer, name)) == set(appended), name
     # the client-minted id keeps working beside it (other tests here)
+
+
+def test_wire_rows_carry_their_frame_counts_and_the_counters_count_the_write():
+    """``wire.flush``: one row per socket write, tag = the frames of the pass
+    joined in it; ``tcp.read``: one row per ``data_received``, tag = the
+    whole frames parsed in it; ``wire.frames`` / ``wire.bytes`` count at the
+    write."""
+    from ratis_tpu.transport import tcp
+
+    class Sink(tcp._FramedProtocol):
+        def _frame(self, call_seq, kind, body):
+            pass
+
+    class Pipe:
+        """``write`` is the peer's read, as a loopback socket's is."""
+
+        def __init__(self, peer):
+            self.peer = peer
+
+        def write(self, data):
+            self.peer.data_received(data)
+
+    tracer = get_tracer()
+    frames = [tcp._encode_frame(i, tcp.KIND_REPLY, b"x" * i) for i in range(5)]
+
+    async def main():
+        a, b = Sink("a"), Sink("b")
+        a.connection_made(Pipe(b))
+        b.connection_made(Pipe(a))
+        tracer.configure(enabled=True, sample_every=1, ring_size=64)
+        for f in frames[:3]:
+            a.send(f)               # one pass: one write of three frames
+        await asyncio.sleep(0)
+        await asyncio.sleep(0)
+        for f in frames[3:]:
+            a.send(f)               # the next: one write of two
+        b.data_received(frames[4][:5])      # no whole frame in this read
+        b.data_received(frames[4][5:])      # its rest: one
+        await asyncio.sleep(0)
+        await asyncio.sleep(0)
+
+    asyncio.run(main())
+    assert [r[3] for r in _rows(tracer, "wire.flush")] == [3, 2]
+    assert [r[3] for r in _rows(tracer, "tcp.read")] == [3, 0, 1, 2]
+    # each read lies inside the write that caused it (the pipe is synchronous)
+    (w0, w1), (r0, _, _, r1) = _rows(tracer, "wire.flush"), \
+        _rows(tracer, "tcp.read")
+    assert w0[1] <= r0[1] and r0[1] + r0[2] <= w0[1] + w0[2]
+    assert w1[1] <= r1[1] and r1[1] + r1[2] <= w1[1] + w1[2]
+    counters = tracer.session()["counters"]
+    assert counters["wire.frames"] == 5
+    assert counters["wire.bytes"] == sum(len(f) for f in frames)
 
 
 def test_parts_of_replicate_lie_inside_it_and_the_queue_ends_at_apply(
@@ -495,11 +546,51 @@ def _raising_log_write():
     asyncio.run(main())
 
 
+class _RefusingTransport:
+    """A socket transport whose ``write`` fails."""
+
+    def write(self, data):
+        raise ConnectionResetError("peer went away mid-batch")
+
+    def abort(self):
+        pass
+
+
+def _failing_wire_write():
+    from ratis_tpu.transport import tcp
+
+    async def main():
+        conn = tcp._Connection("peer:1")
+        conn.connection_made(_RefusingTransport())
+        conn.send(b"frame")
+        conn._flush()           # the pass's write fails: swallowed, poisoned
+        conn.send(b"next")      # so this raises
+
+    asyncio.run(main())
+
+
+def _raising_frame_handler():
+    from ratis_tpu.transport import tcp
+
+    class Raising(tcp._FramedProtocol):
+        def _frame(self, call_seq, kind, body):
+            raise RuntimeError("the frame's handler raised")
+
+    async def main():
+        p = Raising("raising")
+        p.connection_made(_RefusingTransport())
+        p.data_received(tcp._encode_frame(1, tcp.KIND_REPLY, b"body"))
+
+    asyncio.run(main())
+
+
 @pytest.mark.parametrize("body, stages", [
     (_raising_dispatch, ["engine.dispatch", "engine.pack", "engine.launch"]),
     (_raising_decode, ["codec.decode"]),
     (_raising_log_write, ["log.write"]),
-], ids=["engine-step", "decode", "log-write"])
+    (_failing_wire_write, ["wire.flush"]),
+    (_raising_frame_handler, ["tcp.read"]),
+], ids=["engine-step", "decode", "log-write", "wire-write", "frame-handler"])
 def test_a_body_that_raises_leaves_no_work_span_open(annotating, body, stages):
     before = len(annotating.entered)
     with pytest.raises(Exception):

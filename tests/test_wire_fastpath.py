@@ -2,9 +2,11 @@
 
 Covers the ISSUE 2 satellite test checklist:
 
-- frame-coalescing unit tests (byte/latency threshold boundaries,
-  flush-on-close, partial-batch failure poisons the connection not the
-  loop, and the thresholds-at-0 path is bit-identical to per-frame);
+- frame-coalescing unit tests of WriteCoalescer, which the gRPC transport
+  uses (frame-count/latency threshold boundaries, flush-on-close,
+  partial-batch failure poisons the connection not the loop, and the
+  threshold-at-0 path is bit-identical to per-frame); the TCP transport's
+  own framing is tests/test_tcp_framing.py;
 - encode-once fan-out bit-identity vs the slow (generic msgpack) path;
 - trace attribution across coalesced frames (per-stage spans survive);
 - keyed-FIFO gRPC stream dispatch (same-group chunks keep arrival order);
@@ -44,9 +46,22 @@ class _FakeWriter:
         self.drains += 1
 
 
-def _tcp_coalescer(writer, **kw):
-    from ratis_tpu.transport.tcp import _StreamFrameCoalescer
-    return _StreamFrameCoalescer(writer, **kw)
+class _WriterCoalescer(WriteCoalescer):
+    """WriteCoalescer over a writer: the batch goes out as ONE buffered
+    write followed by ONE drain (the generic class as the gRPC transport
+    uses it, with bytes for chunks)."""
+
+    def __init__(self, writer, **kw):
+        super().__init__(**kw)
+        self._writer = writer
+
+    async def _flush_batch(self, frames: list) -> None:
+        self._writer.write(b"".join(frames))
+        await self._writer.drain()
+
+
+def _coalescer(writer, **kw):
+    return _WriterCoalescer(writer, **kw)
 
 
 def test_thresholds_zero_is_per_frame_bit_identical():
@@ -56,7 +71,7 @@ def test_thresholds_zero_is_per_frame_bit_identical():
 
     async def main():
         w = _FakeWriter()
-        c = _tcp_coalescer(w, flush_bytes=0, flush_micros=0)
+        c = _coalescer(w, flush_micros=0)
         frames = [b"frame-%d" % i for i in range(5)]
         for f in frames:
             await c.send(f, len(f))
@@ -76,7 +91,7 @@ def test_coalescing_batches_but_stream_is_identical():
 
     async def main():
         w = _FakeWriter()
-        c = _tcp_coalescer(w, flush_bytes=1 << 20, flush_micros=0)
+        c = _coalescer(w, flush_micros=100)
         frames = [b"frame-%d" % i for i in range(8)]
         await asyncio.gather(*(c.send(f, len(f)) for f in frames))
         await c.aclose()
@@ -87,18 +102,18 @@ def test_coalescing_batches_but_stream_is_identical():
     asyncio.run(main())
 
 
-def test_byte_threshold_boundary_flushes_immediately():
-    """Reaching flush_bytes flushes inline (no latency wait): queue two
-    frames whose sum crosses the threshold with a huge flush_micros — the
+def test_frame_threshold_boundary_flushes_immediately():
+    """Reaching max_frames flushes inline (no latency wait): queue two
+    frames, which reaches the threshold, with a huge flush_micros — the
     flush must not wait for the timer."""
 
     async def main():
         w = _FakeWriter()
-        c = _tcp_coalescer(w, flush_bytes=10, flush_micros=10_000_000)
+        c = _coalescer(w, max_frames=2, flush_micros=10_000_000)
         t0 = asyncio.get_running_loop().time()
         await asyncio.gather(c.send(b"12345", 5), c.send(b"67890", 5))
         took = asyncio.get_running_loop().time() - t0
-        assert took < 1.0, "byte-threshold flush waited on the timer"
+        assert took < 1.0, "frame-threshold flush waited on the timer"
         assert b"".join(w.chunks) == b"1234567890"
         await c.aclose()
 
@@ -110,7 +125,7 @@ def test_latency_threshold_flushes_single_frame():
 
     async def main():
         w = _FakeWriter()
-        c = _tcp_coalescer(w, flush_bytes=1 << 20, flush_micros=5_000)
+        c = _coalescer(w, flush_micros=5_000)
         await asyncio.wait_for(c.send(b"lonely", 6), 2.0)
         assert w.chunks == [b"lonely"]
         await c.aclose()
@@ -123,7 +138,7 @@ def test_flush_on_close():
 
     async def main():
         w = _FakeWriter()
-        c = _tcp_coalescer(w, flush_bytes=1 << 20, flush_micros=5_000_000)
+        c = _coalescer(w, flush_micros=5_000_000)
         t = asyncio.create_task(c.send(b"queued", 6))
         await asyncio.sleep(0)  # frame is pending, timer far away
         assert w.chunks == []
@@ -141,7 +156,7 @@ def test_partial_batch_failure_poisons_connection_not_loop():
 
     async def main():
         w = _FakeWriter(fail_after_drains=0)
-        c = _tcp_coalescer(w, flush_bytes=1 << 20, flush_micros=0)
+        c = _coalescer(w, flush_micros=100)
         results = await asyncio.gather(
             c.send(b"a", 1), c.send(b"b", 1), return_exceptions=True)
         assert all(isinstance(r, ConnectionResetError) for r in results)
@@ -316,16 +331,13 @@ def test_grpc_stream_accepts_coalesced_chunk_batches():
 
 def _coalescing_properties():
     p = fast_properties()
-    p.set(WireConfigKeys.Tcp.FLUSH_BYTES_KEY, "64KB")
-    p.set(WireConfigKeys.Tcp.FLUSH_MICROS_KEY, "100")
     p.set(WireConfigKeys.Grpc.FLUSH_MICROS_KEY, "100")
     return p
 
 
-def test_tcp_cluster_with_coalescing_on():
-    """Full consensus over real TCP sockets with write coalescing enabled:
-    writes commit, reads see them — the coalesced frames carry the same
-    protocol."""
+def test_tcp_cluster_with_the_batched_wire():
+    """Full consensus over real TCP sockets (one write per loop pass, which
+    the TCP transport always does): writes commit, reads see them."""
 
     async def t(cluster: MiniCluster):
         async with cluster.new_client() as client:
@@ -353,10 +365,9 @@ def test_grpc_cluster_with_coalescing_on():
 
 
 def test_trace_attribution_survives_coalescing():
-    """Coalesced frames still produce per-stage spans: with tracing on and
-    TCP write coalescing enabled, a traced request records decode, the
-    full server tiling, and the respond span (which now covers the
-    coalesced flush)."""
+    """Coalesced frames still produce per-stage spans: with tracing on, a
+    traced request over TCP records decode, the full server tiling, and
+    the respond span (the reply queued for its pass's one write)."""
     from ratis_tpu.trace import get_tracer
     from ratis_tpu.trace.tracer import (STAGE_APPEND, STAGE_APPLY,
                                         STAGE_CLIENT, STAGE_DECODE,
