@@ -1,27 +1,44 @@
-"""FileStore: a replicated file service exercising the DataStream path.
+"""FileStore: a replicated file service over both bulk-data paths.
 
 Capability parity with the reference filestore example
 (ratis-examples/src/main/java/org/apache/ratis/examples/filestore/
-FileStoreStateMachine.java:48 + FileStore.java): small files ride the raft
-log as WRITE transactions; large files stream peer-to-peer over the
-DataStream path (``stream``:196 opens a channel into a temp file,
-``link``:210 renames it into place when the raft entry commits).  Queries
-read file bytes / list the store.
+FileStoreStateMachine.java:48 + FileStore.java + FileInfo.java).  A WRITE
+goes round the raft log: ``start_transaction`` puts the header {path,
+offset, length, close, sync} into the entry's ``log_data`` and the bytes
+into its ``sm_data``; ``data_write`` (StateMachine.DataApi.write, called by
+the log on the leader and on every follower as the entry is appended, and
+completed before the entry's record goes to the disk) writes them into the
+file under construction at ``offset`` and forces them where the request says
+``sync``; ``apply_transaction`` commits the write and, on
+``close``, moves the file into place; ``data_read`` gives the bytes back to
+an appender whose cache let them go.  Large files can also stream peer to
+peer over the DataStream path (``stream``:196 opens a channel into a temp
+file, ``link``:210 renames it into place when the raft entry commits).
+Queries read file bytes / list the store.
 
 Commands (msgpack dicts in the Message body):
-  write  {op, path, data}     — file content through the log
+  write  {op, path, data, offset=0, close=true, sync=false}
+                              — ``data`` at ``offset`` of the file under
+                                construction; the writes of a path come in
+                                offset order, ``close`` on the last
   stream {op, path, size}     — DataStream header; bytes arrive out of band
   delete {op, path}
   read   {op, path} (query)   — file bytes
-  list   {op} (query)         — sorted file names
+  list   {op} (query)         — sorted names of the closed files
+
+Layout under the root (``<storage>/sm/files`` of a durable group):
+``<path>`` a closed file, ``.uc/<path>`` a file under construction,
+``.tmp/`` streams.
 """
 
 from __future__ import annotations
 
 import asyncio
+import concurrent.futures
 import os
 import pathlib
 import tempfile
+import threading
 from typing import Dict, Optional
 
 import msgpack
@@ -29,6 +46,21 @@ import msgpack
 from ratis_tpu.protocol.message import Message
 from ratis_tpu.server.statemachine import (BaseStateMachine, DataChannel,
                                            DataStream, TransactionContext)
+from ratis_tpu.trace.tracer import (STAGE_DATA_FSYNC, STAGE_DATA_WRITE,
+                                    TRACER)
+
+# The writers of every FileStore of the process (the reference runs a
+# writer executor per state machine; thousands of co-hosted groups share
+# one).  Each job is one pwrite and, where the request says sync, one fsync.
+IO_THREADS = 8
+_IO = concurrent.futures.ThreadPoolExecutor(   # (threads start on demand)
+    IO_THREADS, thread_name_prefix="filestore-io")
+
+
+# counters (docs/tracing.md): payload bytes the leaders put into sm_data
+# (start_transaction runs on the leader alone); forces behind data_write
+_DATA_BYTES = TRACER.counter("sm.data_bytes", "leader")
+_DATA_FSYNCS = TRACER.counter("sm.data_fsyncs")
 
 
 def _safe_relpath(path: str) -> pathlib.PurePosixPath:
@@ -70,6 +102,54 @@ class FileStoreDataStream(DataStream):
         self.channel.tmp_path.unlink(missing_ok=True)
 
 
+class _UnderConstruction:
+    """One file being written: its descriptor (opened by the first job
+    that needs it), how far writes were appended to the log and how far
+    they are committed."""
+
+    __slots__ = ("uc_path", "fd", "appended", "committed", "lock")
+
+    def __init__(self, uc_path: pathlib.Path) -> None:
+        self.uc_path = uc_path
+        self.fd: Optional[int] = None
+        self.appended = 0
+        self.committed = 0
+        self.lock = threading.Lock()
+
+    def descriptor(self) -> int:
+        with self.lock:
+            if self.fd is None:
+                self.uc_path.parent.mkdir(parents=True, exist_ok=True)
+                self.fd = os.open(self.uc_path, os.O_CREAT | os.O_RDWR, 0o644)
+            return self.fd
+
+    def close(self) -> None:
+        with self.lock:
+            if self.fd is not None:
+                os.close(self.fd)
+                self.fd = None
+
+    def write(self, offset: int, data: bytes, sync: bool) -> None:
+        """One data_write, on a writer thread."""
+        fd = self.descriptor()
+        span = TRACER.begin(STAGE_DATA_WRITE) if TRACER.enabled else None
+        try:
+            view, at = memoryview(data), offset
+            while len(view):
+                n = os.pwrite(fd, view, at)
+                view, at = view[n:], at + n
+        finally:
+            if span is not None:
+                TRACER.end(span, tag=len(data))
+        if sync:
+            span = TRACER.begin(STAGE_DATA_FSYNC) if TRACER.enabled else None
+            try:
+                os.fsync(fd)
+            finally:
+                if span is not None:
+                    TRACER.end(span, tag=1)
+
+
 class FileStoreStateMachine(BaseStateMachine):
     def __init__(self, root: Optional[str] = None) -> None:
         super().__init__()
@@ -77,6 +157,12 @@ class FileStoreStateMachine(BaseStateMachine):
         self._root: Optional[pathlib.Path] = None
         self._tmp_holder: Optional[tempfile.TemporaryDirectory] = None
         self.files: Dict[str, int] = {}  # path -> size (committed metadata)
+        self.writes_committed = 0        # WRITE transactions applied
+        self._open: Dict[str, _UnderConstruction] = {}
+        # log index -> (path, offset, the write's future) of the writes
+        # data_write has taken in this life and apply has not reached: what
+        # apply waits for and data_truncate rolls back
+        self._unapplied: Dict[int, tuple] = {}
         self._stream_seq = 0
 
     # ------------------------------------------------------------- layout
@@ -92,13 +178,21 @@ class FileStoreStateMachine(BaseStateMachine):
                 self._tmp_holder = tempfile.TemporaryDirectory(
                     prefix="filestore-")
                 self._root = pathlib.Path(self._tmp_holder.name)
-            (self._root / ".tmp").mkdir(parents=True, exist_ok=True)
         return self._root
 
     def resolve(self, path: str) -> pathlib.Path:
         return self.root / _safe_relpath(path)
 
+    def _under_construction(self, path: str) -> _UnderConstruction:
+        uc = self._open.get(path)
+        if uc is None:
+            uc = self._open[path] = _UnderConstruction(
+                self.root / ".uc" / _safe_relpath(path))
+        return uc
+
     async def close(self) -> None:
+        for uc in self._open.values():
+            uc.close()
         if self._tmp_holder is not None:
             self._tmp_holder.cleanup()
         await super().close()
@@ -106,6 +200,10 @@ class FileStoreStateMachine(BaseStateMachine):
     # ----------------------------------------------------------- pipeline
 
     async def start_transaction(self, request) -> TransactionContext:
+        """Leader: the header into ``log_data``, a WRITE's bytes into
+        ``sm_data``.  (Nothing here suspends, and the log's append follows
+        without a suspension either, so the offset is checked against what
+        data_write has taken of the write before.)"""
         trx = TransactionContext(client_request=request,
                                  log_data=request.message.content)
         try:
@@ -113,7 +211,24 @@ class FileStoreStateMachine(BaseStateMachine):
             op = cmd["op"]
             if op not in ("write", "stream", "delete"):
                 raise ValueError(f"not a transaction op: {op!r}")
-            _safe_relpath(cmd["path"])
+            path = cmd["path"]
+            _safe_relpath(path)
+            if op == "write":
+                data = cmd["data"]
+                offset = int(cmd.get("offset", 0))
+                if path in self.files:
+                    raise ValueError(f"{path!r} is closed")
+                uc = self._open.get(path)
+                expected = uc.appended if uc is not None else 0
+                if offset != expected:
+                    raise ValueError(f"{path!r}: write at offset {offset}, "
+                                     f"expected {expected}")
+                trx.log_data = msgpack.packb(
+                    {"op": "write", "path": path, "offset": offset,
+                     "length": len(data), "close": bool(cmd.get("close", True)),
+                     "sync": bool(cmd.get("sync", False))}, use_bin_type=True)
+                trx.sm_data = data
+                _DATA_BYTES.n += len(data)
         except Exception as e:
             trx.exception = e
         return trx
@@ -126,11 +241,8 @@ class FileStoreStateMachine(BaseStateMachine):
         op, path = cmd["op"], cmd.get("path", "")
         reply: dict
         if op == "write":
-            target = self.resolve(path)
-            target.parent.mkdir(parents=True, exist_ok=True)
-            await asyncio.to_thread(self._atomic_write, target, cmd["data"])
-            self.files[path] = len(cmd["data"])
-            reply = {"ok": True, "size": len(cmd["data"])}
+            reply = await self._commit_write(
+                cmd, e.index if e is not None else -1)
         elif op == "stream":
             # bytes were linked into place just before apply (data_link);
             # a peer outside the routing table simply has no local copy
@@ -142,6 +254,10 @@ class FileStoreStateMachine(BaseStateMachine):
             else:
                 reply = {"ok": False, "error": "data not streamed here"}
         elif op == "delete":
+            uc = self._open.pop(path, None)
+            if uc is not None:
+                uc.close()
+                await asyncio.to_thread(uc.uc_path.unlink, True)
             target = self.resolve(path)
             await asyncio.to_thread(target.unlink, True)
             self.files.pop(path, None)
@@ -152,11 +268,53 @@ class FileStoreStateMachine(BaseStateMachine):
             self.update_last_applied_term_index(e.term, e.index)
         return Message(msgpack.packb(reply, use_bin_type=True))
 
+    async def _commit_write(self, cmd: dict, index: int) -> dict:
+        """A committed WRITE: its bytes are in the file under construction
+        already (data_write ran when the entry was appended, and the entry
+        did not count as flushed before it completed); commit the length
+        and, on ``close``, move the file into place.  An entry that
+        data_write has not seen in this life is replayed after a restart:
+        its bytes are on the disk from the life before."""
+        path, offset, length = cmd["path"], cmd["offset"], cmd["length"]
+        taken = self._unapplied.pop(index, None)
+        replayed = taken is None
+        if not replayed and not taken[2].done():
+            # committed by the other replicas while this one's own write is
+            # still out (its failure is the log's to report, not apply's)
+            await asyncio.wait([taken[2]])
+        if path in self.files:
+            raise ValueError(f"{path!r} is closed")
+        uc = self._under_construction(path)
+        if offset != uc.committed:
+            raise ValueError(f"{path!r}: write at offset {offset}, "
+                             f"committed {uc.committed}")
+        uc.committed = offset + length
+        uc.appended = max(uc.appended, uc.committed)
+        self.writes_committed += 1
+        if cmd["close"]:
+            target = self.resolve(path)
+            del self._open[path]
+            uc.close()
+            await asyncio.to_thread(self._move_into_place, uc.uc_path,
+                                    target, replayed, uc.committed)
+            self.files[path] = uc.committed
+        return {"ok": True, "path": path, "offset": offset, "length": length,
+                "size": uc.committed}
+
     @staticmethod
-    def _atomic_write(target: pathlib.Path, data: bytes) -> None:
-        tmp = target.with_name(target.name + ".part")
-        tmp.write_bytes(data)
-        tmp.replace(target)
+    def _move_into_place(uc_path: pathlib.Path, target: pathlib.Path,
+                         replayed: bool, size: int) -> None:
+        try:
+            # (a life that ended in a crash may have written, past what is
+            # committed, the data of an entry its log never held)
+            os.truncate(uc_path, size)
+            os.replace(uc_path, target)
+        except FileNotFoundError:
+            if uc_path.exists():        # the first file of its directory
+                target.parent.mkdir(parents=True, exist_ok=True)
+                os.replace(uc_path, target)
+            elif not (replayed and target.exists()):
+                raise
 
     # -------------------------------------------------------------- query
 
@@ -179,6 +337,69 @@ class FileStoreStateMachine(BaseStateMachine):
 
     # ----------------------------------------------------------- DataApi
 
+    def data_write(self, entry):
+        """DataApi.write: a WRITE's bytes into the file under construction
+        at the header's offset, on a writer thread (forced there where the
+        header says sync); the returned future is what the log's record of
+        the entry waits for before it goes to the disk."""
+        smlog = entry.smlog
+        cmd = msgpack.unpackb(smlog.log_data, raw=False)
+        if cmd.get("op") != "write":
+            return None
+        data = smlog.sm_data
+        path, offset = cmd["path"], cmd["offset"]
+        uc = self._under_construction(path)
+        uc.appended = offset + len(data)
+        if cmd["sync"]:
+            _DATA_FSYNCS.n += 1
+        written = asyncio.get_running_loop().run_in_executor(
+            _IO, uc.write, offset, data, cmd["sync"])
+        self._unapplied[entry.index] = (path, offset, written)
+        return written
+
+    def data_read(self, entry) -> bytes:
+        """DataApi.read (blocking): the bytes of a WRITE entry, from the
+        file under construction or, once closed, from the file in place."""
+        cmd = msgpack.unpackb(entry.smlog.log_data, raw=False)
+        path, offset, length = cmd["path"], cmd["offset"], cmd["length"]
+        uc = self._open.get(path)
+        if uc is not None:
+            data = os.pread(uc.descriptor(), length, offset)
+        else:
+            with open(self.resolve(path), "rb") as f:
+                data = os.pread(f.fileno(), length, offset)
+        if len(data) != length:
+            raise IOError(f"{path!r}: {len(data)} of {length} bytes at "
+                          f"offset {offset}")
+        return data
+
+    async def data_flush(self, index: int) -> None:
+        """DataApi.flush: force every file under construction (the writes
+        that did not ask for sync)."""
+        fds = [uc.fd for uc in self._open.values() if uc.fd is not None]
+        if fds:
+            _DATA_FSYNCS.n += len(fds)
+            await asyncio.to_thread(lambda: [os.fsync(fd) for fd in fds])
+
+    async def data_truncate(self, index: int) -> None:
+        """DataApi.truncate: the log dropped its entries from ``index`` on;
+        their writes (the log has waited for them) go from the files under
+        construction, last first."""
+        for i in sorted((i for i in self._unapplied if i >= index),
+                        reverse=True):
+            path, offset, _ = self._unapplied.pop(i)
+            uc = self._open.get(path)
+            if uc is None:
+                continue
+            uc.appended = max(offset, uc.committed)
+            if uc.appended:
+                await asyncio.to_thread(os.ftruncate, uc.descriptor(),
+                                        uc.appended)
+            else:
+                del self._open[path]
+                uc.close()
+                await asyncio.to_thread(uc.uc_path.unlink, True)
+
     async def data_stream(self, request) -> DataStream:
         cmd = msgpack.unpackb(request.message.content, raw=False)
         if cmd.get("op") != "stream":
@@ -187,6 +408,9 @@ class FileStoreStateMachine(BaseStateMachine):
         self._stream_seq += 1
         tmp = self.root / ".tmp" / \
             f"stream_{request.type.stream_id}_{self._stream_seq}"
+        # (the directories are made where they are first needed, off the
+        # loop: thousands of co-hosted groups make theirs at once)
+        await asyncio.to_thread(tmp.parent.mkdir, parents=True, exist_ok=True)
         return FileStoreDataStream(FileChunkChannel(tmp), request, target)
 
     async def data_link(self, stream: Optional[DataStream], entry) -> None:

@@ -37,13 +37,25 @@ class StateMachineLogEntry:
     log_data: bytes = b""
     # State-machine data held OUTSIDE the log file when the StateMachine
     # provides a DataApi (reference SegmentedRaftLog stateMachineCachingEnabled,
-    # SegmentedRaftLog.java:203).  Not serialized into segment files.
+    # SegmentedRaftLog.java:203).  On the wire, never in a segment file: the
+    # record keeps ``data_size`` alone, and the log's cache lets the bytes go
+    # once the entry is applied and replicated.  ``sm_data is None`` with
+    # ``data_size > 0`` is such an entry: StateMachine.data_read has the bytes.
     sm_data: Optional[bytes] = None
     # True when this transaction was submitted by a DataStream CLOSE: every
     # replica must data_link the entry at apply, passing None when it holds
     # no local stream so the StateMachine can detect/repair the missing bytes
     # (reference passes a null stream for exactly this).
     is_datastream: bool = False
+    data_size: int = 0
+
+    def __post_init__(self) -> None:
+        if self.sm_data is not None:
+            object.__setattr__(self, "data_size", len(self.sm_data))
+
+    def data_let_go(self) -> bool:
+        """The entry carries state-machine data and does not hold it."""
+        return self.sm_data is None and self.data_size > 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -84,6 +96,8 @@ class LogEntry:
                        "d": self.smlog.log_data}
             if include_sm_data and self.smlog.sm_data is not None:
                 s["sd"] = self.smlog.sm_data
+            elif self.smlog.data_size:
+                s["sx"] = self.smlog.data_size
             if self.smlog.is_datastream:
                 s["ds"] = True
             d["s"] = s
@@ -106,7 +120,7 @@ class LogEntry:
             smlog = StateMachineLogEntry(
                 client_id=s.get("c", b""), call_id=s.get("id", 0),
                 log_data=s.get("d", b""), sm_data=s.get("sd"),
-                is_datastream=s.get("ds", False))
+                is_datastream=s.get("ds", False), data_size=s.get("sx", 0))
         conf = None
         if "cf" in d:
             c = d["cf"]
@@ -124,6 +138,17 @@ class LogEntry:
     @staticmethod
     def from_bytes(b: bytes) -> "LogEntry":
         return LogEntry.from_dict(msgpack.unpackb(b, raw=False))
+
+    def without_sm_data(self) -> "LogEntry":
+        """This entry as a segment file holds it: the header, and the size
+        of the state-machine data that lives outside the log."""
+        return dataclasses.replace(self, smlog=dataclasses.replace(
+            self.smlog, sm_data=None))
+
+    def with_sm_data(self, data: bytes) -> "LogEntry":
+        """The entry with the bytes StateMachine.data_read gave back."""
+        return dataclasses.replace(self, smlog=dataclasses.replace(
+            self.smlog, sm_data=data))
 
     def __str__(self) -> str:
         body = self.kind.name

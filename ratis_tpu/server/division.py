@@ -55,6 +55,7 @@ from ratis_tpu.protocol.termindex import INVALID_LOG_INDEX, TermIndex
 from ratis_tpu.server.config import RaftConfiguration
 from ratis_tpu.server.election import LeaderElection
 from ratis_tpu.server.leader import FollowerInfo, LeaderContext
+from ratis_tpu.server.log.base import DATA_CACHE_LAG
 from ratis_tpu.server.state import ServerState
 from ratis_tpu.server.statemachine import StateMachine, TransactionContext
 from ratis_tpu.trace.tracer import (STAGE_APPEND, STAGE_APPLY,
@@ -549,6 +550,12 @@ class Division:
         # flush_index -> feed the engine's commit kernel; a failed write is a
         # log failure (StateMachine.notifyLogFailed).
         log.set_flush_callbacks(self._on_log_flush, self._on_log_failed)
+        # StateMachine.DataApi: the log starts data_write as it appends an
+        # entry that carries sm_data, on the leader's path (_write_impl) and
+        # the follower's (append_entries_follower) alike, writes the entry's
+        # record (and so moves flush_index) only after it has completed, and
+        # reads the bytes back by data_read
+        log.set_data_api(self.state_machine)
         self._apply_task = asyncio.create_task(
             self._apply_loop(), name=f"applier-{self.member_id}")
 
@@ -1454,6 +1461,9 @@ class Division:
         self._taking_snapshot = True
         try:
             with self.sm_metrics.snapshot_timer.time():
+                # (the purge below takes the entries that could bring
+                # unforced state-machine data again)
+                await self.state_machine.data_flush(self._applied_index)
                 index = await self.state_machine.take_snapshot()
             if index < 0:
                 return index
@@ -2452,6 +2462,8 @@ class Division:
                 self._flush_reply_batch(batch)
             self._engine_set_applied()
             self.applied_waiters.advance(self._applied_index)
+            if log.data_held:
+                log.release_data(self._data_release_bound())
             log.evict_cache(self._applied_index)
             if self.is_leader() and self.leader_ctx is not None \
                     and not self.leader_ctx.leader_ready.done() \
@@ -2477,6 +2489,18 @@ class Division:
                 # same cadence for the write-index cache: the lazy get()
                 # path never evicts ids that stop querying
                 self.write_index_cache.sweep(now)
+
+    def _data_release_bound(self) -> int:
+        """Up to where the log's cache may let state-machine data go:
+        applied here and, on a leader, acknowledged by every follower — but
+        no further than DATA_CACHE_LAG entries behind the applied index for
+        one that lags (it is then served through data_read)."""
+        bound = self._applied_index
+        ctx = self.leader_ctx
+        if ctx is not None and ctx.followers:
+            behind = min(f.match_index for f in ctx.followers.values())
+            bound = min(bound, max(behind, bound - DATA_CACHE_LAG))
+        return bound
 
     def _flush_reply_batch(self, batch: list) -> None:
         """One waterline fan-out pass: resolve every client waiter the

@@ -43,7 +43,22 @@ class MemoryRaftLog(RaftLog):
 
     @property
     def flush_index(self) -> int:
+        if self._data_out:
+            # an entry whose data_write is out does not count as flushed
+            return min(self.next_index, self._data_out[0][0]) - 1
         return self.next_index - 1
+
+    def _data_landed(self) -> None:
+        index = self.flush_index
+        if index > self._flush_index:
+            self._flush_index = index
+            if self._flush_cb is not None:
+                self._flush_cb(index)
+
+    def _strip(self, index: int) -> None:
+        i = index - self._start
+        if 0 <= i < len(self._entries):
+            self._entries[i] = self._entries[i].without_sm_data()
 
     def get_last_entry_term_index(self) -> Optional[TermIndex]:
         if self._entries:
@@ -70,11 +85,17 @@ class MemoryRaftLog(RaftLog):
             raise ValueError(f"{self.name}: appending index {entry.index}, "
                              f"expected {expected}")
         self._entries.append(entry)
+        smlog = entry.smlog
+        if smlog is not None and smlog.sm_data is not None:
+            self._start_data_write(entry)
+        if wait_flush and self._data_out:
+            await self._wait_data()
         return entry.index
 
     async def truncate(self, index: int) -> None:
         keep = max(0, index - self._start)
         del self._entries[keep:]
+        self._flush_index = min(self._flush_index, index - 1)
 
     async def purge(self, index: int) -> int:
         if index < self._start:

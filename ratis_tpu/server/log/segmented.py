@@ -23,6 +23,7 @@ Record format (original to this implementation):
 from __future__ import annotations
 
 import asyncio
+import functools
 import os
 import pathlib
 import re
@@ -36,8 +37,8 @@ from ratis_tpu.protocol.exceptions import (ChecksumException,
 from ratis_tpu.protocol.logentry import LogEntry
 from ratis_tpu.protocol.termindex import INVALID_LOG_INDEX, TermIndex
 from ratis_tpu.server.log.base import RaftLog
-from ratis_tpu.trace.tracer import (STAGE_LOG_FSYNC, STAGE_LOG_QUEUE,
-                                    STAGE_LOG_WRITE, TRACER)
+from ratis_tpu.trace.tracer import (STAGE_DATA_WAIT, STAGE_LOG_FSYNC,
+                                    STAGE_LOG_QUEUE, STAGE_LOG_WRITE, TRACER)
 
 MAGIC = b"RTPULOG\x01"
 _REC_HDR = struct.Struct("<II")
@@ -102,6 +103,12 @@ class LogWorker:
         # decayed fsyncs-per-drain-sweep: ~1.0 on a shared log plane,
         # ~open-file-count with per-group segment files
         self._sync_ewma = 0.0
+        # record's future -> the write of its state-machine data, which the
+        # record must not reach the disk before (submit_after); files a
+        # failed one left with a hole, which take no record any more
+        self._gates: dict[asyncio.Future, asyncio.Future] = {}
+        self._in_flight: Optional[asyncio.Future] = None
+        self._dead_files: dict[object, BaseException] = {}
 
     @property
     def metrics(self) -> dict:
@@ -154,12 +161,60 @@ class LogWorker:
             self._wake.set()
         return fut
 
+    def submit_after(self, gate: asyncio.Future, fileobj,
+                     data: bytes) -> asyncio.Future:
+        """``submit`` for the record of an entry whose state-machine data is
+        written by ``gate`` (StateMachine.data_write): the record is written
+        and fsynced only once the data is, as upstream's log worker waits
+        for the stateMachineDataFuture before its flush — a crash never
+        leaves a durable record whose data is missing.  Where the data
+        write fails, neither this record nor a later one reaches the file."""
+        fut = self.submit(fileobj, data)
+        self._gates[fut] = gate
+        return fut
+
+    async def _after_gates(self, batch: list) -> list:
+        """Hold the batch until the data writes of its records have
+        completed; the batch without the records a failed one took."""
+        gates = self._gates
+        mine = [(item, gates.pop(item[2])) for item in batch
+                if item[2] in gates]
+        out = [g for _, g in mine if not g.done()]
+        t0 = TRACER.now() if TRACER.enabled else 0
+        if out:
+            await asyncio.wait(out)
+        if t0:
+            # server.data_wait: what a record's data held its batch back
+            # (nothing, where the data came first)
+            t1 = TRACER.now() if out else t0
+            for _ in mine:
+                if TRACER.sample(STAGE_DATA_WAIT):
+                    TRACER.record(0, STAGE_DATA_WAIT, t0, t1)
+        dead = self._dead_files
+        for item, gate in mine:
+            exc = (asyncio.CancelledError() if gate.cancelled()
+                   else gate.exception())
+            if exc is not None:
+                dead.setdefault(item[0], exc)
+        if not dead:
+            return batch
+        kept = []
+        for item in batch:
+            exc = dead.get(item[0])
+            if exc is None:
+                kept.append(item)
+            elif not item[2].done():
+                err = RaftLogIOException(
+                    "state-machine data write failed: record not written")
+                err.__cause__ = exc
+                item[2].set_exception(err)
+        return kept
+
     async def drain(self) -> None:
         """Wait until previously submitted writes are flushed."""
-        if not self._queue:
-            return
-        fut = self._queue[-1][2]
-        await asyncio.shield(fut)
+        fut = self._queue[-1][2] if self._queue else self._in_flight
+        if fut is not None and not fut.done():
+            await asyncio.shield(fut)
 
     async def _run(self) -> None:
         from ratis_tpu.util import injection
@@ -175,6 +230,13 @@ class LogWorker:
             batch, self._queue = self._queue, []
             if not batch:
                 continue
+            if self._gates or self._dead_files:
+                self._in_flight = batch[-1][2]
+                batch = await self._after_gates(batch)
+                if not batch:
+                    continue
+            # (what drain() waits for once the queue is empty)
+            self._in_flight = batch[-1][2]
             self._writes.inc(len(batch))
             self._batches.inc()
             if TRACER.enabled:
@@ -323,19 +385,9 @@ class SegmentedRaftLog(RaftLog):
         import threading
         self._rt_lock = threading.Lock()
         self._open_file = None
-        self._flush_index = INVALID_LOG_INDEX
         self._below_start: Optional[TermIndex] = None
-        # Latched on the first failed write: flush_index must never advance
-        # past a hole (a later successful fsync does NOT make earlier failed
-        # bytes durable), and further appends are refused — the reference's
-        # log worker terminates on IO failure the same way.
-        self._failed: Optional[Exception] = None
         from ratis_tpu.metrics import SegmentedRaftLogMetrics
         self.metrics = SegmentedRaftLogMetrics(name)
-
-    @property
-    def failed(self) -> bool:
-        return self._failed is not None
 
     # ------------------------------------------------------------- recovery
 
@@ -385,6 +437,8 @@ class SegmentedRaftLog(RaftLog):
         # follow open() with set_snapshot_boundary(snapshot.term_index) — the
         # term is not recoverable from the index argument alone.
         self._flush_index = self.next_index - 1
+        # whatever a segment file gave back holds no state-machine data
+        self._data_released = self._flush_index
 
     async def close(self) -> None:
         if self._open_file is not None:
@@ -466,20 +520,32 @@ class SegmentedRaftLog(RaftLog):
 
     def is_resident(self, index: int) -> bool:
         seg = self._covering_segment(index)
-        if seg is None or seg.cached:
+        if seg is None:
             return True
-        # _rt_cache is mutated from prefault worker threads; the lock is
-        # uncontended and keeps this membership check from racing an LRU
-        # eviction into a synchronous whole-segment load on the event loop
-        with self._rt_lock:
-            return seg.start in self._rt_cache
+        if not seg.cached:
+            # _rt_cache is mutated from prefault worker threads; the lock is
+            # uncontended and keeps this membership check from racing an LRU
+            # eviction into a synchronous whole-segment load on the event
+            # loop
+            with self._rt_lock:
+                if seg.start not in self._rt_cache:
+                    return False
+        return super().is_resident(index)
 
     def prefault(self, index: int) -> None:
         """Blocking load of the segment covering ``index`` into the
-        read-through cache; call via asyncio.to_thread from async paths."""
+        read-through cache, and of the state-machine data its entries let
+        go; call via asyncio.to_thread from async paths."""
         seg = self._covering_segment(index)
         if seg is not None and not seg.cached:
             self._fault_in(seg)
+        super().prefault(index)
+
+    def _strip(self, index: int) -> None:
+        seg = self._covering_segment(index)
+        if seg is not None and seg.cached:
+            i = index - seg.start
+            seg.entries[i] = seg.entries[i].without_sm_data()
 
     def get(self, index: int) -> Optional[LogEntry]:
         for seg in reversed(self._segments):
@@ -569,32 +635,21 @@ class SegmentedRaftLog(RaftLog):
         payload = entry.to_bytes(include_sm_data=False)
         record = encode_record(payload)
         seg.append(entry, seg.size, len(record))
-        fut = self.worker.submit(self._open_file, record)
-        index = entry.index
-
-        # flush_index advances from the worker's completion, in submit order
-        # (the worker resolves a batch's futures in order, and done-callbacks
-        # run before any awaiter resumes), so it stays contiguous whether or
-        # not the caller awaits (SegmentedRaftLogWorker flushIfNecessary:368).
-        def _on_flush(f: "asyncio.Future") -> None:
-            if f.cancelled():
-                return
-            exc = f.exception()
-            if exc is not None:
-                first = self._failed is None
-                self._failed = self._failed or exc
-                if first and self._flush_err_cb is not None:
-                    self._flush_err_cb(exc)
-                return
-            if self._failed is None and index > self._flush_index:
-                self._flush_index = index
-                if self._flush_cb is not None:
-                    self._flush_cb(self._flush_index)
-
-        fut.add_done_callback(_on_flush)
+        smlog = entry.smlog
+        if smlog is None or smlog.sm_data is None:
+            fut = self.worker.submit(self._open_file, record)
+        else:
+            # StateMachine.DataApi.write starts here; the record follows
+            # it to the disk
+            gate = self._start_data_write(entry)
+            fut = (self.worker.submit_after(gate, self._open_file, record)
+                   if gate is not None
+                   else self.worker.submit(self._open_file, record))
+        fut.add_done_callback(
+            functools.partial(self._on_record_flushed, entry.index))
         if wait_flush:
             await fut
-        return index
+        return entry.index
 
     # ------------------------------------------------------------ truncate
 

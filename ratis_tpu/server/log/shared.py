@@ -58,6 +58,7 @@ retires entirely.
 from __future__ import annotations
 
 import asyncio
+import functools
 import logging
 import os
 import pathlib
@@ -403,19 +404,24 @@ class SharedLogStore:
         # the fd cache keyed the inode, which rename preserves — keep it
 
     def submit_record(self, gid: bytes, index: int, term: int, rtype: int,
-                      body: bytes = b"") -> tuple[asyncio.Future, int, int, int]:
+                      body: bytes = b"", gate: Optional[asyncio.Future] = None
+                      ) -> tuple[asyncio.Future, int, int, int]:
         """Queue one record on the open segment WITHOUT rolling — the
         synchronous path for control records from non-async callers; size
-        overshoot is corrected by the next append_record."""
+        overshoot is corrected by the next append_record.  ``gate``: the
+        write of the entry's state-machine data, which the record follows
+        to the disk."""
         self._ensure_open()
         rec = encode_shared(gid, index, term, rtype, body)
         off = self._open_size
-        fut = self.worker.submit(self._open_file, rec)
+        fut = (self.worker.submit(self._open_file, rec) if gate is None
+               else self.worker.submit_after(gate, self._open_file, rec))
         self._open_size += len(rec)
         return fut, self._open_seg, off, len(rec)
 
     async def append_record(self, gid: bytes, index: int, term: int,
-                            rtype: int, body: bytes = b"") \
+                            rtype: int, body: bytes = b"",
+                            gate: Optional[asyncio.Future] = None) \
             -> tuple[asyncio.Future, int, int, int]:
         if self._open_file is not None \
                 and self._open_size > self.segment_size_max:
@@ -428,7 +434,7 @@ class SharedLogStore:
                 if self._open_file is not None \
                         and self._open_size > self.segment_size_max:
                     await self._seal_open_segment()
-        return self.submit_record(gid, index, term, rtype, body)
+        return self.submit_record(gid, index, term, rtype, body, gate)
 
     # ---------------------------------------------------------------- reads
 
@@ -581,14 +587,8 @@ class SharedGroupLog(RaftLog):
         self.gid = gid
         self._st = _GroupState()
         self._entries: dict[int, LogEntry] = {}
-        self._flush_index = INVALID_LOG_INDEX
-        self._failed: Optional[Exception] = None
         from ratis_tpu.metrics import SegmentedRaftLogMetrics
         self.metrics = SegmentedRaftLogMetrics(name)
-
-    @property
-    def failed(self) -> bool:
-        return self._failed is not None
 
     # ------------------------------------------------------------ open/close
 
@@ -597,6 +597,8 @@ class SharedGroupLog(RaftLog):
         self.store.acquire(self)
         self._st = self.store.take_recovered(self.gid)
         self._flush_index = self.next_index - 1
+        # whatever the shard file gives back holds no state-machine data
+        self._data_released = self._flush_index
 
     async def close(self) -> None:
         await self.store.release(self)
@@ -675,25 +677,29 @@ class SharedGroupLog(RaftLog):
         return e
 
     # Record-sized preads make cold reads cheap enough to serve inline —
-    # no whole-segment faulting, so the resident/prefault machinery the
-    # segmented store needs (multi-MB synchronous loads) does not apply.
-    def is_resident(self, index: int) -> bool:
-        return True
-
-    def prefault(self, index: int) -> None:
-        pass
+    # no whole-segment faulting, so of the resident/prefault machinery the
+    # segmented store needs (multi-MB synchronous loads) only the base's
+    # part applies: state-machine data the cache let go is read back.
 
     def evict_cache(self, applied_index: int) -> int:
         """Drop payload cache at or below the applied frontier (the applier
         reads each entry once); only flushed entries are evictable — until
         the fsync their bytes may not be readable from the file."""
         limit = min(applied_index, self._flush_index)
+        if self._data_held:
+            # (what still holds state-machine data goes by release_data)
+            limit = min(limit, self._data_held[0] - 1)
         victims = [i for i in self._entries if i <= limit]
         for i in victims:
             del self._entries[i]
         if victims:
             self.metrics.cache_evict_count.inc(len(victims))
         return len(victims)
+
+    def _strip(self, index: int) -> None:
+        e = self._entries.get(index)
+        if e is not None:
+            self._entries[index] = e.without_sm_data()
 
     # ---------------------------------------------------------------- append
 
@@ -704,10 +710,7 @@ class SharedGroupLog(RaftLog):
                 return
             exc = f.exception()
             if exc is not None:
-                first = self._failed is None
-                self._failed = self._failed or exc
-                if first and self._flush_err_cb is not None:
-                    self._flush_err_cb(exc)
+                self._failure(exc)
         fut.add_done_callback(_done)
 
     async def append_entry(self, entry: LogEntry, wait_flush: bool = True) -> int:
@@ -723,9 +726,14 @@ class SharedGroupLog(RaftLog):
         if entry.index != expected:
             raise ValueError(f"{self.name}: appending index {entry.index}, "
                              f"expected {expected}")
+        smlog = entry.smlog
+        # StateMachine.DataApi.write starts here; the record follows it to
+        # the disk
+        gate = (self._start_data_write(entry)
+                if smlog is not None and smlog.sm_data is not None else None)
         fut, seg_n, off, rec_len = await self.store.append_record(
             self.gid, entry.index, entry.term, REC_ENTRY,
-            entry.to_bytes(include_sm_data=False))
+            entry.to_bytes(include_sm_data=False), gate)
         st = self._st
         if not st.count:
             st.first = entry.index
@@ -734,25 +742,9 @@ class SharedGroupLog(RaftLog):
         self._entries[entry.index] = entry
         index = entry.index
 
-        # identical advance discipline to the per-group store: the worker
-        # resolves a batch's futures in submit order, so flush_index stays
-        # contiguous whether or not the caller awaits
-        def _on_flush(f: asyncio.Future) -> None:
-            if f.cancelled():
-                return
-            exc = f.exception()
-            if exc is not None:
-                first = self._failed is None
-                self._failed = self._failed or exc
-                if first and self._flush_err_cb is not None:
-                    self._flush_err_cb(exc)
-                return
-            if self._failed is None and index > self._flush_index:
-                self._flush_index = index
-                if self._flush_cb is not None:
-                    self._flush_cb(self._flush_index)
-
-        fut.add_done_callback(_on_flush)
+        # identical advance discipline to the per-group store
+        fut.add_done_callback(
+            functools.partial(self._on_record_flushed, index))
         if wait_flush:
             await fut
         return index

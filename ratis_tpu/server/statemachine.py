@@ -307,12 +307,43 @@ class StateMachine:
         raise NotImplementedError(
             f"{type(self).__name__} does not support DataStream")
 
-    async def data_write(self, entry) -> None:
-        """Persist SM data carried by a log entry outside the log
-        (DataApi.write); default no-op."""
+    # The log-path half (DataApi.write / read / flush / truncate): an entry's
+    # ``sm_data`` never reaches a segment file.  The log calls ``data_write``
+    # when the entry is appended, on the leader and on every follower, and its
+    # worker writes and fsyncs the entry's record only once the returned
+    # awaitable has completed (upstream's
+    # raft.server.log.statemachine.data.sync, default true: the log worker
+    # waits for the data before its flush), so no crash leaves a durable
+    # record without its data; once the entry is applied and replicated the
+    # log's cache lets the bytes go, and whoever needs them again (an
+    # appender, after a restart) calls ``data_read``.
+
+    def data_write(self, entry):
+        """Persist the state-machine data a log entry carries, outside the
+        log (DataApi.write).  Called on the loop, in index order, as the
+        entry is appended; returns an awaitable that completes when the
+        bytes are written (and forced, where the state machine's request
+        says so), or None when there is nothing to wait for.  The entry may
+        be committed by the other replicas, and so applied here, before this
+        replica's own write has completed: an apply that needs the bytes
+        written waits for its own awaitable.  Default: no-op (the data is
+        then held by nothing but the log's cache, which keeps it)."""
+        return None
+
+    def data_read(self, entry) -> bytes:
+        """The state-machine data of ``entry`` (``entry.smlog.data_size``
+        bytes), which the log no longer holds (DataApi.read).  Blocking:
+        called off the loop."""
+        raise NotImplementedError(
+            f"{type(self).__name__} keeps no state-machine data to read back")
 
     async def data_flush(self, index: int) -> None:
-        """Flush SM data up to a log index (DataApi.flush); default no-op."""
+        """Force state-machine data up to a log index (DataApi.flush);
+        default no-op."""
+
+    async def data_truncate(self, index: int) -> None:
+        """The log dropped its entries from ``index`` on: drop their data
+        (DataApi.truncate); default no-op."""
 
     def __str__(self) -> str:
         return f"{type(self).__name__}@{self.member_id}"

@@ -1,12 +1,15 @@
 """FileStore load generator (reference ratis-examples filestore cli
 LoadGen.java + ratis-examples/README.md:56-66): drives N clients writing
-numFiles files of a given size — over the DataStream path or as plain log
-writes — and reports aggregate throughput + latency percentiles.
+numFiles files of a given size — over the DataStream path, or round the log
+as chunked WRITEs (header in the log, bytes through StateMachine.DataApi) —
+and reports aggregate throughput + latency percentiles.
 
 Usage:
   python -m ratis_tpu.tools.loadgen -peers s0=h:p,s1=h:p,s2=h:p \
       [-groupid UUID] [-numFiles 64] [-size 1048576] [-numClients 4]
-      [--log-path]   # bypass DataStream, send file bytes through the log
+      [--log-path [-bufferSize 65536] [--sync]]
+                     # bypass DataStream: each file as WRITEs of bufferSize
+                     # bytes at their offsets, close on the last
 """
 
 from __future__ import annotations
@@ -24,7 +27,8 @@ from ratis_tpu.shell.cli import _new_client, parse_peers
 
 async def _run_client(client_no: int, peers, group_id, num_files: int,
                       size: int, use_log_path: bool,
-                      latencies: List[float]) -> int:
+                      latencies: List[float], buffer_size: int = 65536,
+                      sync: bool = False) -> int:
     payload = bytes((client_no + i) % 256 for i in range(size))
     errors = 0
     async with _new_client(peers, group_id) as client:
@@ -33,9 +37,18 @@ async def _run_client(client_no: int, peers, group_id, num_files: int,
             t0 = time.perf_counter()
             try:
                 if use_log_path:
-                    reply = await client.io().send(msgpack.packb(
-                        {"op": "write", "path": path, "data": payload},
-                        use_bin_type=True))
+                    # as upstream's LoadGen: every write of the file goes
+                    # out by the async API and all are waited for together
+                    replies = await asyncio.gather(*(
+                        client.async_api().send(msgpack.packb(
+                            {"op": "write", "path": path, "offset": off,
+                             "close": off + buffer_size >= size,
+                             "sync": sync,
+                             "data": payload[off:off + buffer_size]},
+                            use_bin_type=True))
+                        for off in range(0, max(size, 1), buffer_size)))
+                    reply = next((r for r in replies if not r.success),
+                                 replies[-1])
                 else:
                     out = await client.data_stream().stream(msgpack.packb(
                         {"op": "stream", "path": path}, use_bin_type=True))
@@ -66,7 +79,7 @@ async def run(args) -> int:
     t0 = time.perf_counter()
     errors = sum(await asyncio.gather(*(
         _run_client(c, peers, group_id, args.numFiles, args.size,
-                    args.log_path, latencies)
+                    args.log_path, latencies, args.bufferSize, args.sync)
         for c in range(args.numClients))))
     elapsed = time.perf_counter() - t0
 
@@ -97,8 +110,13 @@ def main(argv=None) -> int:
     p.add_argument("-size", type=int, default=1 << 20)
     p.add_argument("-numClients", type=int, default=4)
     p.add_argument("--log-path", action="store_true",
-                   help="send bytes through the raft log instead of "
-                        "the DataStream path")
+                   help="send the bytes round the raft log (chunked WRITEs) "
+                        "instead of the DataStream path")
+    p.add_argument("-bufferSize", type=int, default=65536,
+                   help="bytes of one WRITE with --log-path (upstream: "
+                        "should be less than 4MB)")
+    p.add_argument("--sync", action="store_true",
+                   help="with --log-path: force every WRITE")
     return asyncio.run(run(p.parse_args(argv)))
 
 

@@ -110,7 +110,15 @@ STAGE_TCP_READ = 27     # tcp.read — one read burst of a connection (tag =
 STAGE_SELECT = 28       # loop.select — one blocking selector wait
 STAGE_WIRE_FLUSH = 29   # wire.flush — one buffered socket write of a
                         # connection's coalescer (tag = frames)
-NUM_STAGES = 30
+# State-machine data beside the log (StateMachine.DataApi).
+STAGE_DATA_WAIT = 30    # server.data_wait — what the log worker's batch was
+                        # held back for a record's data_write before its
+                        # write and fsync (0 where the data came first)
+STAGE_DATA_WRITE = 31   # sm.data_write — one entry's bytes written by the
+                        # state machine, on the writing thread (tag = bytes)
+STAGE_DATA_FSYNC = 32   # sm.data_fsync — the force behind such writes
+                        # (tag = files)
+NUM_STAGES = 33
 
 STAGE_NAMES = (
     "client.send", "codec.encode", "codec.decode", "wire.rtt",
@@ -122,6 +130,7 @@ STAGE_NAMES = (
     "log.queue", "log.write", "log.fsync",
     "engine.pack", "engine.launch", "engine.fetch", "engine.collect",
     "tcp.read", "loop.select", "wire.flush",
+    "server.data_wait", "sm.data_write", "sm.data_fsync",
 )
 
 # W = work span: a stretch that is synchronous on one thread by construction
@@ -139,6 +148,7 @@ STAGE_KINDS = (
     "I", "W", "W",
     "W", "W", "W", "W",
     "W", "W", "W",
+    "I", "W", "W",
 )
 
 # Work spans happen once per batch, several batches per commit: their rings
