@@ -187,19 +187,22 @@ async def serve_phase(name: str, *, groups: int, capacity: int,
     # elections feed on themselves (the shape is metastable, see PERF.md).
     # This run proves the path, it does not time elections.
     rpc = RaftServerConfigKeys.Rpc
-    scaled = bench_properties(True, groups, num_servers=PEERS,
-                              transport="tcp")
-    cluster = BenchCluster(
-        groups, num_servers=PEERS, batched=True, transport="tcp",
-        mesh_devices=mesh_devices, extra_props={
-            rpc.TIMEOUT_MIN_KEY:
-                f"{int(2 * rpc.timeout_min(scaled).to_ms())}ms",
-            rpc.TIMEOUT_MAX_KEY:
-                f"{int(2 * rpc.timeout_max(scaled).to_ms())}ms",
-            RaftServerConfigKeys.Read.OPTION_KEY:
-                RaftServerConfigKeys.Read.Option.LINEARIZABLE,
-            RaftServerConfigKeys.Engine.MAX_GROUPS_KEY: capacity,
-            RaftServerConfigKeys.Engine.MAX_PEERS_KEY: ENGINE_MAX_PEERS})
+    scaled = bench_properties(True, groups, num_servers=PEERS)
+    extra_props = {
+        rpc.TIMEOUT_MIN_KEY: f"{int(2 * rpc.timeout_min(scaled).to_ms())}ms",
+        rpc.TIMEOUT_MAX_KEY: f"{int(2 * rpc.timeout_max(scaled).to_ms())}ms",
+        RaftServerConfigKeys.Read.OPTION_KEY:
+            RaftServerConfigKeys.Read.Option.LINEARIZABLE,
+        RaftServerConfigKeys.Engine.MAX_GROUPS_KEY: capacity,
+        RaftServerConfigKeys.Engine.MAX_PEERS_KEY: ENGINE_MAX_PEERS}
+    if mesh_devices:
+        # shard the resident engine state over the group axis of an
+        # n-device mesh (parallel/mesh.py): each device owns one contiguous
+        # slice of the group batch; capacity is auto-padded to the mesh
+        extra_props[RaftServerConfigKeys.Engine.MESH_DEVICES_KEY] = \
+            mesh_devices
+    cluster = BenchCluster(groups, num_servers=PEERS, batched=True,
+                           transport="tcp", extra_props=extra_props)
     out["properties"] = dict(sorted(cluster.properties.items()))
     engines = [s.engine for s in cluster.servers]
     out["engine_state_shape"] = [engines[0].state.capacity,
@@ -223,7 +226,7 @@ async def serve_phase(name: str, *, groups: int, capacity: int,
     base = [dict(e.metrics.items()) for e in engines]
 
     # ---- bring-up: first wave by election, the rest appointed
-    gc.disable()  # nothing built here is garbage (see _started_cluster)
+    gc.disable()  # nothing built here is garbage (see run_bench)
     try:
         await cluster.start(elect_first=elected)
         cluster.servers[0].seal_heap()

@@ -152,8 +152,7 @@ class PlacementActuator:
     async def _transfer(self, div, target: str):
         """Submit the admin TransferLeadership request in-process on the
         division's owning loop (the same request the shell/client path
-        builds — bench_cluster.run_churn_bench drives it over a real
-        transport)."""
+        builds)."""
         from ratis_tpu.protocol.admin import TransferLeadershipArguments
         from ratis_tpu.protocol.message import Message
         from ratis_tpu.protocol.requests import (RaftClientRequest,

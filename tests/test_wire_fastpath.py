@@ -9,12 +9,10 @@ Covers the ISSUE 2 satellite test checklist:
   own framing is tests/test_tcp_framing.py;
 - encode-once fan-out bit-identity vs the slow (generic msgpack) path;
 - trace attribution across coalesced frames (per-stage spans survive);
-- keyed-FIFO gRPC stream dispatch (same-group chunks keep arrival order);
-- the bench's one-line JSON stays inside the driver's 2000-char window.
+- keyed-FIFO gRPC stream dispatch (same-group chunks keep arrival order).
 """
 
 import asyncio
-import json
 
 import msgpack
 import pytest
@@ -398,138 +396,3 @@ def test_trace_attribution_survives_coalescing():
                       f"{ {k: len(v) for k, v in by_stage.items()} }")
     finally:
         tracer.configure(enabled=False)
-
-
-# ------------------------------------------------- bench line stays small
-
-def test_bench_summary_line_fits_driver_window():
-    """The one-line bench JSON must parse from the driver's 2000-char tail
-    capture (BENCH_r05.json overflowed it: parsed null).  Fill every rung
-    with worst-case-width synthetic numbers and assert the line fits."""
-    import bench
-
-    def rung(**extra):
-        out = {"commits_per_sec": 123456.8, "p50_ms": 99999.99,
-               "p99_ms": 99999.99, "election_convergence_s": 9999.99,
-               "write_failures": 0, "engine_occupancy": 0.9999,
-               "watchdog_events": 99999, "reply_hops_per_commit": 99.999,
-               "window_occupancy": 0.9999}
-        out.update(extra)
-        return out
-
-    decomp = {"coverage": 0.975, "stages": {
-        name: {"p50_us": 123456.7}
-        for name in ("server.route", "server.txn_start", "server.append",
-                     "server.replicate", "server.apply", "server.reply",
-                     "server.respond")}}
-    trials = [rung() for _ in range(5)]
-    summary = bench._summarize(
-        headline=trials, scalar=trials,
-        ladder={1: trials[:2], 64: trials[:2], 1024: trials[:3],
-                10_240: trials[:2]},
-        mesh_trials=trials[:2],
-        peer5=rung(host_path_decomposition=decomp,
-                   mp={"server_procs": 5, "client_procs": 4,
-                       "loop_shards": 3}),
-        peer5_sp=rung(), peer5_mp=rung(),
-        peer5_scalar=rung(),
-        peer5_grpc=rung(), peer5_grpc_scalar=rung(),
-        peer7=rung(host_path_decomposition=decomp),
-        sparse_hib=rung(hibernated_groups=10240), sparse_plain=rung(),
-        churn=rung(transfers_ok=64, transfers_failed=64),
-        mixed=rung(streams_ok=32, stream_mb_per_s=99999.99),
-        mixed_fs={"pergroup": rung(stream_mb_per_s=99999.99,
-                                   fsyncs_per_commit=99.9999),
-                  "shared": rung(stream_mb_per_s=99999.99,
-                                 fsyncs_per_commit=99.9999),
-                  "pergroup_5ms": rung(stream_mb_per_s=99999.99,
-                                       fsyncs_per_commit=99.9999),
-                  "shared_5ms": rung(stream_mb_per_s=99999.99,
-                                     fsyncs_per_commit=99.9999)},
-        stream=rung(stream_mb_per_s=99999.99),
-        grpc_b=trials[:3], grpc_s_1024=rung(), grpc_s_256=rung(),
-        kernel={"group_updates_per_sec": 1330708656.5,
-                "vs_scalar_loop": 99126.85, "platform": "TPU v5 lite0"},
-        kernel_100k={"group_updates_per_sec_100k": 1333027867.0},
-        mesh100k={"groups": 102400, "devices": 8,
-                  "updates_per_s": 1333027867.9, "tick_ms": 99999.99,
-                  "efficiency_frac": 0.999},
-        tpu_e2e=rung(device={"platform": "tpu", "kind": "TPU v5 lite",
-                             "count": 1}),
-        traced=rung(host_path_decomposition=decomp),
-        filestore5=rung(streams_ok=32, stream_mb_per_s=99999.99),
-        readmix=rung(reads_per_sec=123456.8, read_p99_ms=99999.99,
-                     reads_lease_leader=99999,
-                     reads_follower_linearizable=99999,
-                     reads_stale=99999),
-        snapcatch=rung(catchup_s=9999.99, installs=10240,
-                       cps_before=123456.8),
-        zipf=rung(writes_per_sec=123456.8, reads_per_sec=123456.8,
-                  shed_frac=0.9999),
-        placement={"hotspot_p99_before_ms": 99999.99,
-                   "hotspot_p99_after_ms": 99999.99,
-                   "transfers": 99999, "grey_steer_frac": 0.9999},
-        win_sweep={str(d): [123456.8, 99999.99, 0.9999]
-                   for d in (1, 4, 16)},
-        chaos={"passed": 9, "total": 9, "worst_reelect_s": 9999.999,
-               "recovery_frac": 99.999, "fault_events": 99999},
-        tel_on=rung(telemetry={"samples": 99999,
-                               "sample_cost_p99_ms": 9999.999,
-                               "hot_share": 0.9999,
-                               "hot_group": "group-aabbccdd",
-                               "sampler_pass_ms": 9999.999,
-                               "ledger_fetch_ms": 9999.999,
-                               "walk_pass_ms": 9999.999}),
-        tel_off=rung(),
-        # realistic-worst width: the idle scan measures in MICROseconds
-        # (tests/test_upkeep.py); 9.999ms is already a 1000x degradation
-        upkeep=[9.999, 9.999, 0.99])
-    line = json.dumps(summary, separators=(",", ":"))
-    assert len(line) < 2000, f"bench line would overflow: {len(line)} chars"
-    parsed = json.loads(line)
-    assert parsed["value"] == 123456.8
-    assert parsed["vs_baseline"] == 1.0
-    assert parsed["secondary"]["p5_10240"]["vs_scalar"] == 1.0
-    assert parsed["secondary"]["p5_10240"]["mp"] == [5, 3, 4]
-    assert parsed["secondary"]["p5_fs"][2] == 32
-    # durable mixed rung: [pg c/s, pg f/c, shared c/s, shared MB/s,
-    # shared f/c, speedup] + the modeled-disk pair [pg, shared, speedup]
-    assert parsed["secondary"]["mix_fs"][5] == 1.0
-    assert parsed["secondary"]["mix_5ms"][2] == 1.0
-    assert parsed["secondary"]["readmix"][1] == 123456.8
-    assert parsed["secondary"]["snap_1024"][1] == 10240
-    # round-12 zipf fleet rung: [writes/s, reads/s, shed frac, p99 ms]
-    assert parsed["secondary"]["zipf"] == [
-        123456.8, 123456.8, 0.9999, 99999.99]
-    # round-16 placement closed loop: [hot p99 OFF, ON, transfers,
-    # grey steer fraction]
-    assert parsed["secondary"]["placement"] == [
-        99999.99, 99999.99, 99999, 0.9999]
-    # observability keys: [engine occupancy, watchdog event count,
-    # reply-plane scheduling hops per commit (round-8 fan-out collapse),
-    # append-window occupancy (round-9 pipelined windows), the round-11
-    # telemetry-on/off overhead pair, the headline hot-group skew, and
-    # the round-14 lag-ledger cost pair [sampler pass p50 ms, device
-    # ledger fetch p50 ms]]
-    assert parsed["secondary"]["obs"] == [
-        0.9999, 99999 * 6, 99.999, 0.9999,
-        [123457, 123457, 0.0], 0.9999, [9999.999, 9999.999]]
-    assert parsed["secondary"]["win_sweep"]["16"] == [123456.8, 99999.99,
-                                                      0.9999]
-    # chaos campaign rung: [passed, total, worst reelect s,
-    # recovery-throughput fraction, injected-fault event records]
-    assert parsed["secondary"]["chaos"] == [9, 9, 9999.999, 99.999,
-                                                 99999]
-    # round-15 upkeep plane: [sweep ms @64 slots, @1024, sim dip frac]
-    assert parsed["secondary"]["upkeep"] == [9.999, 9.999, 0.99]
-    # kernel throughputs are COUNTS: emitted rounded to the integer
-    assert parsed["secondary"]["kernel"][0] == 1330708656
-    assert parsed["secondary"]["kernel_100k"] == 1333027867
-    # PR-18 flagship mesh rung: [groups, devices, updates/s, tick ms,
-    # efficiency vs the mesh-devices=0 control]
-    assert parsed["secondary"]["mesh100k"] == [
-        102400, 8, 1333027868, 99999.99, 0.999]
-    # compact list forms: grpc_1024 = [cps, p99, scalar cps, s256 cps],
-    # mesh_10240 = [cps, spread, sim cps, sim spread]
-    assert parsed["secondary"]["grpc_1024"][0] == 123456.8
-    assert len(parsed["secondary"]["mesh_10240"]) == 4
