@@ -281,7 +281,9 @@ class FileStoreStateMachine(BaseStateMachine):
         if not replayed and not taken[2].done():
             # committed by the other replicas while this one's own write is
             # still out (its failure is the log's to report, not apply's)
-            await asyncio.wait([taken[2]])
+            done, _ = await asyncio.wait([asyncio.wrap_future(taken[2])])
+            for f in done:
+                f.cancelled() or f.exception()   # (seen: no warning of it)
         if path in self.files:
             raise ValueError(f"{path!r} is closed")
         uc = self._under_construction(path)
@@ -340,8 +342,10 @@ class FileStoreStateMachine(BaseStateMachine):
     def data_write(self, entry):
         """DataApi.write: a WRITE's bytes into the file under construction
         at the header's offset, on a writer thread (forced there where the
-        header says sync); the returned future is what the log's record of
-        the entry waits for before it goes to the disk."""
+        header says sync); the returned future (the writer thread's own:
+        the log worker's thread sees it complete without the loop) is what
+        the log's record of the entry waits for before it goes to the
+        disk."""
         smlog = entry.smlog
         cmd = msgpack.unpackb(smlog.log_data, raw=False)
         if cmd.get("op") != "write":
@@ -352,8 +356,7 @@ class FileStoreStateMachine(BaseStateMachine):
         uc.appended = offset + len(data)
         if cmd["sync"]:
             _DATA_FSYNCS.n += 1
-        written = asyncio.get_running_loop().run_in_executor(
-            _IO, uc.write, offset, data, cmd["sync"])
+        written = _IO.submit(uc.write, offset, data, cmd["sync"])
         self._unapplied[entry.index] = (path, offset, written)
         return written
 

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import concurrent.futures
 from typing import Iterable, Optional, Sequence
 
 from ratis_tpu.protocol.exceptions import (LogCorruptedException,
@@ -33,6 +34,9 @@ DATA_CACHE_LAG = 8
 DATA_READ_AHEAD = 8
 
 _DATA_READS = TRACER.counter("sm.data_reads")
+# log.loop_calls of the data writes: each completion is seen on the loop once
+# (_on_data_written), whatever thread wrote; the log workers add their own
+_DATA_LOOP_CALLS = TRACER.counter("log.loop_calls", "data")
 
 
 class RaftLog:
@@ -58,7 +62,7 @@ class RaftLog:
         # StateMachine.DataApi (set by the division).  ``_data_out``:
         # (index, the data_write) of entries whose data write has not
         # completed, in index order: a durable log's worker holds each one's
-        # record back until it has (LogWorker.submit_after), truncation and
+        # record back until it has (LogWorker.submit's gate), truncation and
         # close wait for them.  ``_data_held``: indexes whose entry in the
         # cache still holds its sm_data; ``_data_released``: the highest
         # index that let it go.
@@ -265,46 +269,47 @@ class RaftLog:
         if first and self._flush_err_cb is not None:
             self._flush_err_cb(exc)
 
-    def _on_record_flushed(self, index: int, f: "asyncio.Future") -> None:
-        """Done-callback of an appended record's write.  flush_index
-        advances from the worker's completions, in submit order (the worker
-        resolves a batch's futures in order, and done-callbacks run before
-        any awaiter resumes), so it stays contiguous whether or not the
-        caller awaits (SegmentedRaftLogWorker flushIfNecessary:368).  The
-        record of an entry that carries state-machine data is written after
-        that data, so a flushed record's data is written too."""
-        if f.cancelled():
-            return
-        exc = f.exception()
-        if exc is not None:
-            self._failure(exc)
-            return
+    def _on_record_flushed(self, index: int) -> None:
+        """The worker's batch call-back (LogWorker._completed): this log's
+        records up to ``index`` are fsynced.  It runs on the loop, batch
+        after batch in submit order, whether or not the appender awaits, so
+        flush_index stays contiguous (SegmentedRaftLogWorker
+        flushIfNecessary:368); a failed write reaches ``_failure`` instead
+        and nothing moves past it.  The record of an entry that carries
+        state-machine data is written after that data, so a flushed
+        record's data is written too."""
         if self._failed is None and index > self._flush_index:
             self._flush_index = index
             if self._flush_cb is not None:
                 self._flush_cb(index)
 
-    def _start_data_write(self, entry: LogEntry) -> "Optional[asyncio.Future]":
+    def _start_data_write(self, entry: LogEntry):
         """DataApi.write for an entry that carries ``sm_data``, started as
-        the entry is appended.  Returns the write, which the entry's record
-        waits for before it goes to the disk, or None where the state
-        machine writes nothing."""
+        the entry is appended.  Returns the write as the log worker's thread
+        takes it (the gate the entry's record waits for before it goes to
+        the disk: the writer thread's own future where the state machine
+        gives one, which then wakes the worker without the loop), or None
+        where the state machine writes nothing."""
         if self._data_api is None:
             return None
         try:
             pending = self._data_api.data_write(entry)
             if pending is None:
                 return None  # nobody else has the bytes: the cache keeps them
-            fut = asyncio.ensure_future(pending)
+            if isinstance(pending, concurrent.futures.Future):
+                gate, fut = pending, asyncio.wrap_future(pending)
+            else:
+                gate = fut = asyncio.ensure_future(pending)
             self._data_held.append(entry.index)
         except Exception as e:
-            fut = asyncio.get_running_loop().create_future()
+            gate = fut = asyncio.get_running_loop().create_future()
             fut.set_exception(e)
         self._data_out.append((entry.index, fut))
         fut.add_done_callback(self._on_data_written)
-        return fut
+        return gate
 
     def _on_data_written(self, fut: "asyncio.Future") -> None:
+        _DATA_LOOP_CALLS.n += 1
         if not fut.cancelled() and fut.exception() is not None:
             # a failed data write is a failed log write (and stays in
             # _data_out: nothing counts as flushed past it)

@@ -14,6 +14,16 @@ SegmentedRaftLogWorker.java, LogSegment.java, SegmentedRaftLogFormat):
 - flush_index advances only after fsync and feeds the leader's own slot in
   the batched commit kernel.
 
+Who owns what.  Segments, indexes, caches and flush_index belong to the event
+loop of the log's division.  The way to the disk belongs to the worker's
+thread (``LogWorker``): a record goes from ``submit`` to ``os.fsync`` without
+the loop, and a state-machine data write that gates it is seen done by that
+thread.  They meet in one place: the thread's one ``call_soon_threadsafe`` a
+batch, which runs ``LogWorker._completed`` -> ``RaftLog._on_record_flushed``
+on the loop.  Between a ``drain()`` that has returned and the next ``submit``
+the thread touches no file of that loop's logs, which is when the loop closes,
+renames and truncates them.
+
 Record format (original to this implementation):
     file   := MAGIC record*
     record := u32_le payload_len | u32_le crc32(payload) | payload
@@ -23,11 +33,11 @@ Record format (original to this implementation):
 from __future__ import annotations
 
 import asyncio
-import functools
 import os
 import pathlib
 import re
 import struct
+import threading
 import time
 import zlib
 from typing import Optional
@@ -39,12 +49,18 @@ from ratis_tpu.protocol.termindex import INVALID_LOG_INDEX, TermIndex
 from ratis_tpu.server.log.base import RaftLog
 from ratis_tpu.trace.tracer import (STAGE_DATA_WAIT, STAGE_LOG_FSYNC,
                                     STAGE_LOG_QUEUE, STAGE_LOG_WRITE, TRACER)
+from ratis_tpu.util import injection
 
 MAGIC = b"RTPULOG\x01"
 _REC_HDR = struct.Struct("<II")
 
 _CLOSED_RE = re.compile(r"^log_(\d+)-(\d+)$")
 _OPEN_RE = re.compile(r"^log_inprogress_(\d+)$")
+
+# log.loop_calls of a data write that completes on a loop (an awaitable from
+# a state machine that has no writer thread): its done-callback there wakes
+# the worker's thread.  Added to on the loop; the workers' own are theirs.
+_LOOP_GATE_CALLS = TRACER.counter("log.loop_calls", "gate")
 
 
 def encode_record(payload: bytes) -> bytes:
@@ -74,24 +90,77 @@ def read_records(path: pathlib.Path) -> tuple[list[bytes], int]:
     return payloads, off
 
 
-class LogWorker:
-    """One fsync-batching writer per storage device.
+class _Record:
+    """One queued record: what ``LogWorker.submit`` hands back.  The worker's
+    thread reads ``fileobj``, ``data``, ``gate`` and ``loop``, and sets
+    ``t_held`` and (for a record it may not write) ``exc`` before it calls
+    back; everything else belongs to the loop it was submitted from.
+    Awaitable: ``await record`` returns once the record is on the disk, or
+    raises what kept it off."""
 
-    Tasks are (file, bytes, future) appends; each drain writes every queued
-    task then issues ONE fsync per distinct file, resolving all futures —
-    group commit like the reference's flushIfNecessary/forceSyncNum
-    (SegmentedRaftLogWorker.java:368) but across divisions.
+    __slots__ = ("fileobj", "data", "log", "index", "gate", "loop",
+                 "t_submit", "t_held", "done", "exc", "_waiters")
+
+    def __init__(self, fileobj, data, log, index, gate, loop, t_submit):
+        self.fileobj = fileobj
+        self.data = data
+        self.log = log          # the RaftLog whose flush_index it moves
+        self.index = index
+        self.gate = gate        # the write of its state-machine data
+        self.loop = loop
+        self.t_submit = t_submit  # of a log.queue sample, or 0
+        self.t_held = 0         # when the thread first found the gate shut
+        self.done = False
+        self.exc: Optional[BaseException] = None
+        self._waiters: Optional[list] = None
+
+    def __await__(self):
+        if not self.done:
+            # a future only for a record somebody awaits (one each: an
+            # awaiter that is cancelled takes no other's completion away)
+            fut = self.loop.create_future()
+            if self._waiters is None:
+                self._waiters = [fut]
+            else:
+                self._waiters.append(fut)
+            yield from fut
+        if self.exc is not None:
+            raise self.exc
+
+
+class LogWorker:
+    """One fsync-batching writer thread per storage device.
+
+    The thread owns the way to the disk: it takes everything queued as one
+    batch, writes each file's records with one ``write``, then flushes and
+    fsyncs each distinct file ONCE -- group commit like the reference's
+    flushIfNecessary/forceSyncNum (SegmentedRaftLogWorker.java:368) but
+    across divisions.  A record whose state-machine data is still being
+    written (``gate``) stays queued, and with it everything behind it: a
+    batch is the longest prefix of the queue whose gates are done.
+
+    The loops own the logs: ``submit`` runs on a division's loop and only
+    appends to the queue under the condition; ``_completed`` runs on that
+    loop again, once a batch, and moves every log's flush_index there.  The
+    one place the two meet is the ``call_soon_threadsafe`` of ``_call_back``
+    (counted: ``log.loop_calls``).
     """
 
     _instances: dict[str, "LogWorker"] = {}
 
     def __init__(self, name: str = "default"):
         self.name = name
-        # (file, bytes, future, submit ns of a log.queue sample or 0)
-        self._queue: list[tuple[object, bytes, asyncio.Future, int]] = []
-        self._wake: Optional[asyncio.Event] = None
-        self._task: Optional[asyncio.Task] = None
+        self._queue: list[_Record] = []
+        self._cond = threading.Condition(threading.Lock())
+        self._thread: Optional[threading.Thread] = None
+        # release()'s way to hear of the thread's end (set: the thread is
+        # to stop once nothing ready is queued); whether it has ended
+        self._stopping = None
+        self._ended = False
+        self._home: Optional[asyncio.AbstractEventLoop] = None
         self._refs = 0
+        # the newest record of each loop: what drain() on that loop waits for
+        self._last: dict[asyncio.AbstractEventLoop, _Record] = {}
         # single metric source (reference log_worker catalog: flushTime/
         # flushCount/syncTime over the shared per-device worker)
         from ratis_tpu.metrics import LogWorkerMetrics
@@ -100,14 +169,14 @@ class LogWorker:
         self.registry_metrics.add_sweep_gauge(lambda: self._sync_ewma)
         self._writes = self.registry_metrics.registry.counter("writeCount")
         self._batches = self.registry_metrics.registry.counter("batchCount")
+        # calls this worker's thread scheduled onto a loop (one a batch and
+        # loop); only the thread adds to it
+        self._loop_calls = TRACER.counter("log.loop_calls", name)
         # decayed fsyncs-per-drain-sweep: ~1.0 on a shared log plane,
         # ~open-file-count with per-group segment files
         self._sync_ewma = 0.0
-        # record's future -> the write of its state-machine data, which the
-        # record must not reach the disk before (submit_after); files a
-        # failed one left with a hole, which take no record any more
-        self._gates: dict[asyncio.Future, asyncio.Future] = {}
-        self._in_flight: Optional[asyncio.Future] = None
+        # files a failed data write left with a hole, which take no record
+        # any more (the thread's)
         self._dead_files: dict[object, BaseException] = {}
 
     @property
@@ -132,170 +201,266 @@ class LogWorker:
 
     def acquire(self) -> None:
         self._refs += 1
-        if self._task is None:
-            self._wake = asyncio.Event()
-            self._task = asyncio.create_task(self._run(),
-                                             name=f"log-worker-{self.name}")
+        if self._thread is None:
+            self._stopping, self._ended = None, False
+            self._home = asyncio.get_running_loop()
+            self._thread = threading.Thread(
+                target=self._run, name=f"log-worker-{self.name}", daemon=True)
+            self._thread.start()
 
     async def release(self) -> None:
         if self._refs <= 0:
             return  # tolerate close-without-open (failed startup cleanup)
         self._refs -= 1
-        if self._refs <= 0 and self._task is not None:
-            task, self._task = self._task, None
-            self._wake.set()
-            task.cancel()
-            try:
-                await task
-            except asyncio.CancelledError:
-                pass
+        if self._refs <= 0 and self._thread is not None:
+            thread, self._thread = self._thread, None
+            loop = asyncio.get_running_loop()
+            ended = loop.create_future()
+
+            def tell() -> None:
+                loop.call_soon_threadsafe(
+                    lambda: ended.done() or ended.set_result(None))
+
+            with self._cond:
+                if self._ended:
+                    ended.set_result(None)
+                self._stopping = tell
+                self._cond.notify()
+            # the thread writes what is queued, fails what a gate still
+            # holds, calls both back and ends; it may need this loop on the
+            # way (an injected handler), so the loop runs on meanwhile
+            await ended
+            thread.join()
+            self._last.clear()
             self._instances.pop(self.name, None)
             self.registry_metrics.unregister()
 
-    def submit(self, fileobj, data: bytes) -> asyncio.Future:
-        fut = asyncio.get_running_loop().create_future()
-        t_submit = (TRACER.now() if TRACER.enabled
-                    and TRACER.sample(STAGE_LOG_QUEUE) else 0)
-        self._queue.append((fileobj, data, fut, t_submit))
-        if self._wake is not None:
-            self._wake.set()
-        return fut
+    def submit(self, fileobj, data: bytes, log: Optional[RaftLog] = None,
+               index: int = INVALID_LOG_INDEX, gate=None) -> _Record:
+        """Queue ``data`` for ``fileobj``.  Once it is fsynced the loop this
+        was called on runs ``log._on_record_flushed`` with the highest
+        ``index`` the batch holds of ``log`` (or ``log._failure``); the
+        record handed back is awaitable for a caller that wants to wait.
 
-    def submit_after(self, gate: asyncio.Future, fileobj,
-                     data: bytes) -> asyncio.Future:
-        """``submit`` for the record of an entry whose state-machine data is
-        written by ``gate`` (StateMachine.data_write): the record is written
-        and fsynced only once the data is, as upstream's log worker waits
-        for the stateMachineDataFuture before its flush — a crash never
-        leaves a durable record whose data is missing.  Where the data
-        write fails, neither this record nor a later one reaches the file."""
-        fut = self.submit(fileobj, data)
-        self._gates[fut] = gate
-        return fut
+        ``gate``: the write of the entry's state-machine data
+        (StateMachine.data_write; a ``concurrent.futures.Future`` or an
+        asyncio one of this loop).  The record is written and fsynced only
+        once the data is, as upstream's log worker waits for the
+        stateMachineDataFuture before its flush -- a crash never leaves a
+        durable record whose data is missing.  Where the data write fails or
+        is cancelled, neither this record nor a later one reaches the file."""
+        loop = asyncio.get_running_loop()
+        rec = _Record(fileobj, data, log, index, gate, loop,
+                      TRACER.now() if TRACER.enabled
+                      and TRACER.sample(STAGE_LOG_QUEUE) else 0)
+        self._last[loop] = rec
+        queue = self._queue
+        with self._cond:
+            queue.append(rec)
+            # the thread waits only with nothing ready at the head of the
+            # queue, and a record behind that head cannot help it
+            if len(queue) == 1:
+                self._cond.notify()
+        if gate is not None:
+            gate.add_done_callback(self._gate_done)
+        return rec
 
-    async def _after_gates(self, batch: list) -> list:
-        """Hold the batch until the data writes of its records have
-        completed; the batch without the records a failed one took."""
-        gates = self._gates
-        mine = [(item, gates.pop(item[2])) for item in batch
-                if item[2] in gates]
-        out = [g for _, g in mine if not g.done()]
-        t0 = TRACER.now() if TRACER.enabled else 0
-        if out:
-            await asyncio.wait(out)
-        if t0:
-            # server.data_wait: what a record's data held its batch back
-            # (nothing, where the data came first)
-            t1 = TRACER.now() if out else t0
-            for _ in mine:
-                if TRACER.sample(STAGE_DATA_WAIT):
-                    TRACER.record(0, STAGE_DATA_WAIT, t0, t1)
-        dead = self._dead_files
-        for item, gate in mine:
-            exc = (asyncio.CancelledError() if gate.cancelled()
-                   else gate.exception())
-            if exc is not None:
-                dead.setdefault(item[0], exc)
-        if not dead:
-            return batch
-        kept = []
-        for item in batch:
-            exc = dead.get(item[0])
-            if exc is None:
-                kept.append(item)
-            elif not item[2].done():
-                err = RaftLogIOException(
-                    "state-machine data write failed: record not written")
-                err.__cause__ = exc
-                item[2].set_exception(err)
-        return kept
+    def _gate_done(self, gate) -> None:
+        """A data write has completed: on its writer's thread for a
+        ``concurrent.futures.Future``, which crosses no loop.  The thread is
+        woken only for the gate it waits for, the head's."""
+        if isinstance(gate, asyncio.Future):
+            _LOOP_GATE_CALLS.n += 1
+        queue = self._queue
+        with self._cond:
+            if queue and queue[0].gate is gate:
+                self._cond.notify()
 
     async def drain(self) -> None:
-        """Wait until previously submitted writes are flushed."""
-        fut = self._queue[-1][2] if self._queue else self._in_flight
-        if fut is not None and not fut.done():
-            await asyncio.shield(fut)
+        """Wait until the writes submitted from this loop are flushed, their
+        logs have been told, and the thread has let go of their files."""
+        rec = self._last.get(asyncio.get_running_loop())
+        if rec is not None and not rec.done:
+            await rec
 
-    async def _run(self) -> None:
-        from ratis_tpu.util import injection
-        # worker-start injection point (reference
-        # SegmentedRaftLogWorker.java:70 runs CodeInjectionForTesting at
-        # the top of its run loop): lets the chaos suite stall a device's
-        # whole log worker before it drains anything
-        await injection.execute(injection.RUN_LOG_WORKER, self.name)
-        while True:
-            if not self._queue:
-                self._wake.clear()
-                await self._wake.wait()
-            batch, self._queue = self._queue, []
-            if not batch:
-                continue
-            if self._gates or self._dead_files:
-                self._in_flight = batch[-1][2]
-                batch = await self._after_gates(batch)
-                if not batch:
-                    continue
-            # (what drain() waits for once the queue is empty)
-            self._in_flight = batch[-1][2]
-            self._writes.inc(len(batch))
-            self._batches.inc()
-            if TRACER.enabled:
+    # ------------------------------------------------- the worker's thread
+
+    def _run(self) -> None:
+        cond, queue = self._cond, self._queue
+        try:
+            # worker-start injection point (reference
+            # SegmentedRaftLogWorker.java:70 runs CodeInjectionForTesting at
+            # the top of its run loop): lets the chaos suite stall a
+            # device's whole log worker before it drains anything
+            if injection.is_registered(injection.RUN_LOG_WORKER):
+                injection.execute_from_thread(
+                    self._home, injection.RUN_LOG_WORKER, self.name)
+            while True:
+                with cond:
+                    while True:
+                        n = self._ready(queue)
+                        if n or self._stopping is not None:
+                            break
+                        cond.wait()
+                    stopped = not n
+                    batch = queue[:n or len(queue)]
+                    del queue[:len(batch)]
+                if stopped:
+                    # (what a gate still holds at the end is not written)
+                    self._call_back(batch, RaftLogIOException(
+                        f"log worker {self.name} stopped"))
+                    return
+                self._write_batch(batch)
+        finally:
+            with cond:
+                self._ended = True
+                tell = self._stopping
+            if tell is not None:
+                tell()
+
+    @staticmethod
+    def _ready(queue: list) -> int:
+        """How many records from the head of the queue can go: up to the
+        first whose data write is still out (global submit order is kept,
+        which drain() and flush_index rely on)."""
+        n = 0
+        for rec in queue:
+            gate = rec.gate
+            if gate is not None and not gate.done():
+                if not rec.t_held and TRACER.enabled:
+                    rec.t_held = TRACER.now()
+                break
+            n += 1
+        return n
+
+    def _write_batch(self, batch: list) -> None:
+        try:
+            exc = self._write(batch)
+        except Exception as e:  # a failed write or fsync; a raising handler
+            exc = e
+        self._call_back(batch, exc)
+
+    def _write(self, batch: list) -> None:
+        """Write and fsync the batch; raises what kept it off the disk."""
+        tracing = TRACER.enabled
+        now = TRACER.now() if tracing else 0
+        dead = self._dead_files
+        by_file: dict[object, list[bytes]] = {}
+        for rec in batch:
+            if rec.t_submit:
                 # log.queue: submit -> the batch holding it taken
-                now = TRACER.now()
-                for _, _, _, t_submit in batch:
-                    if t_submit:
-                        TRACER.record(0, STAGE_LOG_QUEUE, t_submit, now)
-            # per-flush-batch sync injection point (reference
-            # RaftServerImpl.java:1620's LOG_SYNC): a registered delay
-            # here is the slow-disk fault — every group sharing this
-            # device pays it, exactly like a real degraded disk.  The
-            # extra arg is the batch's distinct-file count, so a handler
-            # can charge per FSYNC (per-group segments pay N, the shared
-            # plane pays 1) rather than per sweep.
-            files_n = len({id(fileobj) for fileobj, _, _, _ in batch})
-            await injection.execute(injection.LOG_SYNC, self.name, None,
-                                    files_n)
+                TRACER.record(0, STAGE_LOG_QUEUE, rec.t_submit, now)
+            gate = rec.gate
+            if gate is not None:
+                if tracing and TRACER.sample(STAGE_DATA_WAIT):
+                    # server.data_wait: from when the thread could have
+                    # taken the record to its data written (nothing, where
+                    # the data came first)
+                    TRACER.record(0, STAGE_DATA_WAIT, rec.t_held or now, now)
+                exc = (asyncio.CancelledError() if gate.cancelled()
+                       else gate.exception())
+                if exc is not None:
+                    dead.setdefault(rec.fileobj, exc)
+            if dead and rec.fileobj in dead:
+                rec.exc = RaftLogIOException(
+                    "state-machine data write failed: record not written")
+                rec.exc.__cause__ = dead[rec.fileobj]
+                continue
+            chunks = by_file.get(rec.fileobj)
+            if chunks is None:
+                by_file[rec.fileobj] = [rec.data]
+            else:
+                chunks.append(rec.data)
+        if not by_file:
+            return
+        self._writes.inc(sum(map(len, by_file.values())))
+        self._batches.inc()
+        # per-flush-batch sync injection point (reference
+        # RaftServerImpl.java:1620's LOG_SYNC): a registered delay here is
+        # the slow-disk fault -- every group sharing this device pays it,
+        # exactly like a real degraded disk.  The extra arg is the batch's
+        # distinct-file count, so a handler can charge per FSYNC (per-group
+        # segments pay N, the shared plane pays 1) rather than per sweep.
+        if injection.is_registered(injection.LOG_SYNC):
+            injection.execute_from_thread(
+                batch[0].loop, injection.LOG_SYNC, self.name, None,
+                len(by_file))
+        with self.registry_metrics.flush_timer.time():
+            self._do_io(by_file, tracing)
+        self.registry_metrics.flush_count.inc()
 
-            def _do_io():
-                # log.write / log.fsync: work spans on this worker thread
-                # (tag = distinct files: one fsync each)
-                tracing = TRACER.enabled
-                files = []
-                span = TRACER.begin(STAGE_LOG_WRITE) if tracing else None
-                try:
-                    for fileobj, data, _, _ in batch:
-                        fileobj.write(data)
-                        if fileobj not in files:
-                            files.append(fileobj)
-                finally:
-                    if span is not None:
-                        TRACER.end(span, tag=len(files))
-                span = TRACER.begin(STAGE_LOG_FSYNC) if tracing else None
-                t_sync = time.perf_counter()
-                try:
-                    for f in files:
-                        f.flush()
-                        os.fsync(f.fileno())
-                finally:
-                    if span is not None:
-                        TRACER.end(span, tag=len(files))
-                self.registry_metrics.sync_timer.update(
-                    time.perf_counter() - t_sync)
-                self.registry_metrics.sync_count.inc(len(files))
-                self._sync_ewma = (0.9 * self._sync_ewma + 0.1 * len(files)
-                                   if self._sync_ewma else float(len(files)))
+    def _do_io(self, by_file: dict, tracing: bool) -> None:
+        # log.write / log.fsync: work spans on this thread (tag = distinct
+        # files: one fsync each)
+        n = len(by_file)
+        span = TRACER.begin(STAGE_LOG_WRITE) if tracing else None
+        try:
+            for fileobj, chunks in by_file.items():
+                fileobj.write(chunks[0] if len(chunks) == 1
+                              else b"".join(chunks))
+        finally:
+            if span is not None:
+                TRACER.end(span, tag=n)
+        span = TRACER.begin(STAGE_LOG_FSYNC) if tracing else None
+        t_sync = time.perf_counter()
+        try:
+            for f in by_file:
+                f.flush()
+                os.fsync(f.fileno())
+        finally:
+            if span is not None:
+                TRACER.end(span, tag=n)
+        self.registry_metrics.sync_timer.update(time.perf_counter() - t_sync)
+        self.registry_metrics.sync_count.inc(n)
+        self._sync_ewma = (0.9 * self._sync_ewma + 0.1 * n
+                           if self._sync_ewma else float(n))
 
+    def _call_back(self, batch: list, exc: Optional[BaseException]) -> None:
+        """One call a batch to the loop its records came from (with loop
+        shards a batch may hold records of several: one call to each)."""
+        by_loop: dict[object, list[_Record]] = {}
+        for rec in batch:
+            recs = by_loop.get(rec.loop)
+            if recs is None:
+                by_loop[rec.loop] = [rec]
+            else:
+                recs.append(rec)
+        for loop, recs in by_loop.items():
+            self._loop_calls.n += 1
             try:
-                with self.registry_metrics.flush_timer.time():
-                    await asyncio.to_thread(_do_io)
-                self.registry_metrics.flush_count.inc()
-                for _, _, fut, _ in batch:
-                    if not fut.done():
-                        fut.set_result(None)
-            except Exception as e:
-                for _, _, fut, _ in batch:
-                    if not fut.done():
-                        fut.set_exception(e)
+                loop.call_soon_threadsafe(self._completed, recs, exc)
+            except RuntimeError:
+                pass    # that loop has closed: nobody is left to tell
+
+    # ------------------------------------------------------ back on a loop
+
+    @staticmethod
+    def _completed(recs: list, exc: Optional[BaseException]) -> None:
+        """A batch's records of this loop are on the disk (or ``exc`` kept
+        them off): tell each log once, with its highest index of the batch
+        -- within a file the batch is a contiguous run, so that implies the
+        rest, in submit order -- then wake whoever awaits a record."""
+        tops: dict[RaftLog, int] = {}
+        try:
+            for rec in recs:
+                rec.data = None
+                if rec.exc is None:
+                    rec.exc = exc
+                if rec.log is not None:
+                    if rec.exc is not None:
+                        rec.log._failure(rec.exc)
+                    elif rec.index > tops.get(rec.log, INVALID_LOG_INDEX):
+                        tops[rec.log] = rec.index
+            for log, index in tops.items():
+                log._on_record_flushed(index)
+        finally:
+            # (a log's observer that raises takes no awaiter's wake-up away)
+            for rec in recs:
+                rec.done = True
+                if rec._waiters is not None:
+                    for fut in rec._waiters:
+                        if not fut.done():
+                            fut.set_result(None)
 
 
 class _Segment:
@@ -382,7 +547,6 @@ class SegmentedRaftLog(RaftLog):
         self._rt_cache: "dict[int, list[LogEntry]]" = {}
         self._rt_cache_max = 3
         self._rt_version = 0  # bumped on truncate/purge/snapshot invalidation
-        import threading
         self._rt_lock = threading.Lock()
         self._open_file = None
         self._below_start: Optional[TermIndex] = None
@@ -636,19 +800,14 @@ class SegmentedRaftLog(RaftLog):
         record = encode_record(payload)
         seg.append(entry, seg.size, len(record))
         smlog = entry.smlog
-        if smlog is None or smlog.sm_data is None:
-            fut = self.worker.submit(self._open_file, record)
-        else:
-            # StateMachine.DataApi.write starts here; the record follows
-            # it to the disk
-            gate = self._start_data_write(entry)
-            fut = (self.worker.submit_after(gate, self._open_file, record)
-                   if gate is not None
-                   else self.worker.submit(self._open_file, record))
-        fut.add_done_callback(
-            functools.partial(self._on_record_flushed, entry.index))
+        # StateMachine.DataApi.write starts here; the record follows it to
+        # the disk
+        gate = (self._start_data_write(entry)
+                if smlog is not None and smlog.sm_data is not None else None)
+        rec = self.worker.submit(self._open_file, record, self, entry.index,
+                                 gate)
         if wait_flush:
-            await fut
+            await rec
         return entry.index
 
     # ------------------------------------------------------------ truncate

@@ -58,7 +58,6 @@ retires entirely.
 from __future__ import annotations
 
 import asyncio
-import functools
 import logging
 import os
 import pathlib
@@ -404,25 +403,26 @@ class SharedLogStore:
         # the fd cache keyed the inode, which rename preserves — keep it
 
     def submit_record(self, gid: bytes, index: int, term: int, rtype: int,
-                      body: bytes = b"", gate: Optional[asyncio.Future] = None
-                      ) -> tuple[asyncio.Future, int, int, int]:
+                      body: bytes = b"", gate=None, glog=None):
         """Queue one record on the open segment WITHOUT rolling — the
         synchronous path for control records from non-async callers; size
         overshoot is corrected by the next append_record.  ``gate``: the
         write of the entry's state-machine data, which the record follows
-        to the disk."""
+        to the disk.  ``glog``: the group's log, told of the flush (an
+        entry moves its flush_index) or of the failed write.  Returns (the
+        worker's awaitable record, segment, offset, length)."""
         self._ensure_open()
         rec = encode_shared(gid, index, term, rtype, body)
         off = self._open_size
-        fut = (self.worker.submit(self._open_file, rec) if gate is None
-               else self.worker.submit_after(gate, self._open_file, rec))
+        queued = self.worker.submit(
+            self._open_file, rec, glog,
+            index if rtype == REC_ENTRY else INVALID_LOG_INDEX, gate)
         self._open_size += len(rec)
-        return fut, self._open_seg, off, len(rec)
+        return queued, self._open_seg, off, len(rec)
 
     async def append_record(self, gid: bytes, index: int, term: int,
-                            rtype: int, body: bytes = b"",
-                            gate: Optional[asyncio.Future] = None) \
-            -> tuple[asyncio.Future, int, int, int]:
+                            rtype: int, body: bytes = b"", gate=None,
+                            glog=None):
         if self._open_file is not None \
                 and self._open_size > self.segment_size_max:
             async with self._roll_lock:
@@ -434,7 +434,7 @@ class SharedLogStore:
                 if self._open_file is not None \
                         and self._open_size > self.segment_size_max:
                     await self._seal_open_segment()
-        return self.submit_record(gid, index, term, rtype, body, gate)
+        return self.submit_record(gid, index, term, rtype, body, gate, glog)
 
     # ---------------------------------------------------------------- reads
 
@@ -703,16 +703,6 @@ class SharedGroupLog(RaftLog):
 
     # ---------------------------------------------------------------- append
 
-    def _watch_control(self, fut: asyncio.Future) -> None:
-        """Latch the failure latch if a control record's write fails."""
-        def _done(f: asyncio.Future) -> None:
-            if f.cancelled():
-                return
-            exc = f.exception()
-            if exc is not None:
-                self._failure(exc)
-        fut.add_done_callback(_done)
-
     async def append_entry(self, entry: LogEntry, wait_flush: bool = True) -> int:
         with self.metrics.append_timer.time():
             return await self._append_entry_impl(entry, wait_flush)
@@ -733,21 +723,18 @@ class SharedGroupLog(RaftLog):
                 if smlog is not None and smlog.sm_data is not None else None)
         fut, seg_n, off, rec_len = await self.store.append_record(
             self.gid, entry.index, entry.term, REC_ENTRY,
-            entry.to_bytes(include_sm_data=False), gate)
+            entry.to_bytes(include_sm_data=False), gate, self)
         st = self._st
         if not st.count:
             st.first = entry.index
         st.terms.append(entry.term)
         st.locs.append((seg_n, off, rec_len))
         self._entries[entry.index] = entry
-        index = entry.index
-
-        # identical advance discipline to the per-group store
-        fut.add_done_callback(
-            functools.partial(self._on_record_flushed, index))
+        # (the worker's call-back advances flush_index, as in the per-group
+        # store)
         if wait_flush:
             await fut
-        return index
+        return entry.index
 
     # -------------------------------------------------------------- truncate
 
@@ -763,9 +750,9 @@ class SharedGroupLog(RaftLog):
         # settle in-flight appends first: a late-resolving future for a
         # truncated index must not advance flush_index past the new tail
         await self.store.worker.drain()
+        # (a control record's failed write latches the log, as an entry's)
         fut, *_ = await self.store.append_record(
-            self.gid, index, 0, REC_TOMBSTONE)
-        self._watch_control(fut)
+            self.gid, index, 0, REC_TOMBSTONE, glog=self)
         i = index - st.first
         for j in range(i, st.count):
             self._entries.pop(st.first + j, None)
@@ -783,9 +770,8 @@ class SharedGroupLog(RaftLog):
         st = self._st
         if ti is None or not st.count or index < st.first:
             return self.start_index - 1
-        fut, *_ = await self.store.append_record(
-            self.gid, index, ti.term, REC_PURGE)
-        self._watch_control(fut)
+        await self.store.append_record(
+            self.gid, index, ti.term, REC_PURGE, glog=self)
         limit = min(index, st.last)
         for j in range(st.first, limit + 1):
             self._entries.pop(j, None)
@@ -803,9 +789,8 @@ class SharedGroupLog(RaftLog):
         st = self._st
         if not st.count and st.below_start == ti:
             return  # boot-time re-assert of an already-recovered boundary
-        fut, *_ = self.store.submit_record(
-            self.gid, ti.index, ti.term, REC_PURGE)
-        self._watch_control(fut)
+        self.store.submit_record(
+            self.gid, ti.index, ti.term, REC_PURGE, glog=self)
         self._entries.clear()
         self.store._kill_tail(st, st.first)  # charge everything dead
         st.first = ti.index + 1

@@ -1006,10 +1006,10 @@ class RaftServer:
 
     def _shared_log_store(self, root: str, shard: int):
         """Get-or-create the shard's interleaved log store.  Each shard
-        gets its OWN LogWorker: worker futures are created on the
-        submitter's loop, and a shard's divisions all live on one loop, so
-        per-shard workers keep every future loop-affine (the per-group
-        store's single per-device worker would cross loops here)."""
+        gets its OWN LogWorker: one file, one writer thread and one batch
+        call-back a shard (a worker calls every record back on the loop it
+        came from, so the per-group store's single per-device worker serves
+        all the shards' loops)."""
         store = self._shared_log_stores.get(shard)
         if store is None:
             from ratis_tpu.server.log.segmented import LogWorker

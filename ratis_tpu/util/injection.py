@@ -53,6 +53,22 @@ async def execute(point: str, local_id: Any = None, remote_id: Any = None,
     return True
 
 
+def execute_from_thread(loop, point: str, local_id: Any = None,
+                        remote_id: Any = None, *args: Any) -> bool:
+    """``execute`` for code on a thread of its own (the log worker): a sync
+    callback runs on that thread, an async one on ``loop``, and the thread
+    waits for it."""
+    code = _injections.get(point)
+    if code is None:
+        return False
+    result = code(local_id, remote_id, *args)
+    if inspect.isawaitable(result):
+        async def waited():
+            await result
+        asyncio.run_coroutine_threadsafe(waited(), loop).result()
+    return True
+
+
 def execute_sync(point: str, local_id: Any = None, remote_id: Any = None,
                  *args: Any) -> bool:
     code = _injections.get(point)
