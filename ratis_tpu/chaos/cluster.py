@@ -143,8 +143,6 @@ class ChaosCluster:
                                                  str(storage_root))
         if transport in ("tcp", "grpc"):
             from ratis_tpu.transport.base import TransportFactory
-            import ratis_tpu.transport.grpc  # noqa: F401 (registers GRPC)
-            import ratis_tpu.transport.tcp  # noqa: F401 (registers TCP)
             self.network = None
             self.factory = TransportFactory.get(
                 "GRPC" if transport == "grpc" else "TCP")

@@ -86,7 +86,7 @@ def _shared_fast_step():
 # Why the sweep gate let a batched dispatch through — the dispatch count at
 # scale is THE batched-mode cost driver, so its composition is a first-class
 # labeled counter set instead of a guess.
-DISPATCH_REASONS = ("upload", "commit", "dirty", "votes", "sweep", "backlog")
+DISPATCH_REASONS = ("upload", "commit", "dirty", "sweep", "backlog")
 
 
 class EngineMetrics:
@@ -118,6 +118,9 @@ class EngineMetrics:
         self.fast_ticks = r.counter("fast_ticks")
         self.refresh_ticks = r.counter("refresh_ticks")
         self.idle_skips = r.counter("idle_skips")
+        # tally dispatches of the open vote rounds (_vote_pass): one a tick
+        # that found a reply queued or a round's deadline passed
+        self.vote_tallies = r.counter("vote_tallies")
         self.reasons = {reason: r.counter(labeled("dispatches",
                                                   reason=reason))
                         for reason in DISPATCH_REASONS}
@@ -154,7 +157,8 @@ class _EngineMetricsView:
     the dict they replace."""
 
     _PLAIN = ("ticks", "acks", "commit_advances", "batched_dispatches",
-              "refresh_rows", "fast_ticks", "refresh_ticks", "idle_skips")
+              "refresh_rows", "fast_ticks", "refresh_ticks", "idle_skips",
+              "vote_tallies")
 
     def __init__(self, em: EngineMetrics) -> None:
         self._em = em
@@ -624,15 +628,20 @@ class QuorumEngine:
                 self._cancel_future(old)
             fut = asyncio.get_running_loop().create_future()
             self._vote_rounds[slot] = fut
-        self._wake_set()
         return fut
 
     def on_vote_reply(self, slot: int, peer_slot: int, granted: bool) -> None:
+        """Queue a reply for the next tick's tally.  No wake: the tick loop
+        runs every tick interval anyway, and one tally a tick for every
+        reply that came in since the last is the batch the kernel wants.
+        Waking for each reply made an election storm feed itself: thousands
+        of open rounds kept every engine dispatching at the front of every
+        loop pass, the votes' own RPCs starved behind them, and the rounds
+        timed out and were asked again."""
         with self._lock:
             if slot not in self._vote_rounds:
                 return
             self._vote_ring.append((slot, peer_slot, granted))
-        self._wake_set()
 
     def end_vote_round(self, slot: int) -> None:
         """Abandon a round (candidate stopped / stepped down / special
@@ -656,7 +665,6 @@ class QuorumEngine:
             now = np.int32(self.clock.now_ms())
             if s.vote_deadline_ms[slot] > now:
                 s.vote_deadline_ms[slot] = now
-        self._wake_set()
 
     def _vote_pass(self, now: int) -> list[tuple[asyncio.Future, str]]:
         """Apply queued vote replies and tally EVERY open round in one
@@ -675,6 +683,7 @@ class QuorumEngine:
         if not self._vote_rounds:
             return []
         import jax.numpy as jnp
+        self._m.vote_tallies.inc()
         res = _shared_tally()(
             jnp.asarray(s.vote_grants), jnp.asarray(s.vote_rejects),
             jnp.asarray(s.conf_cur), jnp.asarray(s.conf_old),
@@ -686,6 +695,7 @@ class QuorumEngine:
         for slot, fut in list(self._vote_rounds.items()):
             if fut.done():
                 self._vote_rounds.pop(slot)
+                s.vote_deadline_ms[slot] = NO_DEADLINE
                 continue
             if rejected[slot]:
                 result = "REJECTED"
@@ -881,6 +891,7 @@ class QuorumEngine:
         if not active:
             self._ack_ring.clear()
             s.dirty.clear()
+            s.lazy.clear()
             self._slot_updates.clear()
             self._dev = None
             return [], []
@@ -889,8 +900,7 @@ class QuorumEngine:
                        or len(active) >= self.scalar_fallback_threshold)
         if use_batched and self._dev is not None \
                 and not self._tick_commit_pending \
-                and not s.dirty and not self._vote_rounds \
-                and not self._vote_ring and now < self._next_sweep_ms \
+                and not s.dirty and now < self._next_sweep_ms \
                 and (len(self._ack_ring) + len(self._slot_updates)
                      < self._EVENT_BACKLOG_MAX):
             # Nothing the device could DECIDE right now: commits already
@@ -898,8 +908,10 @@ class QuorumEngine:
             # due.  Let events accumulate — the next dispatch carries a
             # bigger packed batch (the shape the kernel wants) and the
             # engine's dispatch rate drops from per-tick to per-sweep.
+            # Open vote rounds do not open this gate: the quorum step
+            # decides nothing about them (the tally below does).
             self._m.idle_skips.inc()
-            return [], []
+            return [], self._votes_if_due(now)
         if use_batched:
             # why did the gate let this dispatch through? (the labeled
             # dispatches{reason=...} counters; see EngineMetrics)
@@ -910,8 +922,6 @@ class QuorumEngine:
                 reasons["commit"].inc()
             elif s.dirty:
                 reasons["dirty"].inc()
-            elif self._vote_rounds or self._vote_ring:
-                reasons["votes"].inc()
             elif now >= self._next_sweep_ms:
                 reasons["sweep"].inc()
             else:
@@ -942,13 +952,23 @@ class QuorumEngine:
             # host-only mutations make any retained device copy stale; drop
             # it so a later crossing back over the threshold re-uploads
             s.dirty.clear()
+            s.lazy.clear()
             self._dev = None
             self._tick_commit_pending = False
             changed = self._tick_scalar(touched, now)
 
-        votes = (self._vote_pass(now)
-                 if (self._vote_rounds or self._vote_ring) else [])
-        return changed, votes
+        return changed, self._votes_if_due(now)
+
+    def _votes_if_due(self, now: int) -> list[tuple[asyncio.Future, str]]:
+        """The tally, only where it can decide something: a reply came in
+        since the last one, or an open round's deadline has passed (a
+        closed round's reads NO_DEADLINE).  An open round with neither is
+        left alone, however many ticks pass over it."""
+        if self._vote_ring or (
+                self._vote_rounds
+                and now >= int(self.state.vote_deadline_ms.min())):
+            return self._vote_pass(now)
+        return []
 
     def _compute_next_sweep(self, now: int) -> int:
         """Earliest time the device must be consulted again with no new
@@ -1054,7 +1074,8 @@ class QuorumEngine:
         are filtered by the active set."""
         s = self.state
         now = self.clock.now_ms()
-        saved_dirty = set(s.dirty)
+        saved_dirty, saved_lazy = set(s.dirty), set(s.lazy)
+        s.lazy = set()
         # backlog chunking must stay inside what this call compiles — a
         # bigger batch mid-run would be a fresh shape = a synchronous
         # multi-second compile on the event loop
@@ -1082,7 +1103,7 @@ class QuorumEngine:
             jnp.asarray(s.vote_grants), jnp.asarray(s.vote_rejects),
             jnp.asarray(s.conf_cur), jnp.asarray(s.conf_old),
             jnp.asarray(s.priority), jnp.asarray(s.self_priority))
-        s.dirty = saved_dirty
+        s.dirty, s.lazy = saved_dirty, saved_lazy
         self._dev = None  # drop the prewarm device copy; re-upload on use
 
     def _upload_device_state(self):
@@ -1252,10 +1273,11 @@ class QuorumEngine:
             part(STAGE_LAUNCH)
             self._dev = self._upload_device_state()
             s.dirty.clear()
+            s.lazy.clear()
             self._slot_updates.clear()  # the full upload carried them
 
         part(STAGE_PACK)
-        if not s.dirty:
+        if not s.dirty and not s.lazy:
             # Fast path (the steady state under load): two packed uploads,
             # one packed download — profiling showed the unpacked step's 18
             # small transfers costing more than the quorum math itself.
@@ -1284,9 +1306,10 @@ class QuorumEngine:
         # queued packed updates fold in here — the mirror already holds
         # their values, so the row refresh carries them.
         self._m.refresh_ticks.inc()
-        dirty = sorted(s.dirty | set(self._slot_updates))
+        dirty = sorted(s.dirty | s.lazy | set(self._slot_updates))
         self._slot_updates.clear()
         s.dirty.clear()
+        s.lazy.clear()
         self._m.refresh_rows.inc(len(dirty))
         dcap = self._bucket(len(dirty))
         # padded entries point one past the end -> dropped by the scatter

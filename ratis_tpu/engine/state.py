@@ -85,10 +85,20 @@ class GroupBatchState:
         # The device-resident tick uploads ONLY these rows (plus packed ack
         # events); the scalar tick re-runs commit math only for these.
         self.dirty: set[int] = set()
+        # Slots whose change the device need not hear of until it is asked
+        # something anyway (a candidacy begun or given up: the kernel decides
+        # nothing for a candidate, and a follower's next deadline opens the
+        # sweep gate by itself).  They travel with the next dispatch, which
+        # refreshes them before it decides anything, and never cause one.
+        self.lazy: set[int] = set()
 
     def mark_dirty(self, slot: int) -> None:
         if slot >= 0:
             self.dirty.add(slot)
+
+    def mark_lazy(self, slot: int) -> None:
+        if slot >= 0:
+            self.lazy.add(slot)
 
     def slice_of_slot(self, slot: int) -> int:
         return slot // self.slice_rows

@@ -447,10 +447,18 @@ class Division:
         # update, not a dirty-row refresh
         engine.on_deadline(self.engine_slot, deadline)
 
-    def _engine_set_role(self, role_code: int) -> None:
+    def _engine_set_role(self, role_code: int, lazy: bool = False) -> None:
+        """``lazy``: a candidacy begun or given up.  The device decides
+        nothing for such a row until its next deadline, so the change rides
+        the next dispatch instead of causing one (an election storm's role
+        flips kept every engine dispatching on every tick)."""
         if self.engine_slot >= 0:
-            self.server.engine.state.role[self.engine_slot] = role_code
-            self.server.engine.state.mark_dirty(self.engine_slot)
+            st = self.server.engine.state
+            st.role[self.engine_slot] = role_code
+            if lazy:
+                st.mark_lazy(self.engine_slot)
+            else:
+                st.mark_dirty(self.engine_slot)
 
     def _engine_update_flush(self, sink: Optional[list] = None) -> None:
         if self.engine_slot >= 0:
@@ -506,10 +514,13 @@ class Division:
             # RECOVER path (reference ServerState.initialize:134): reload
             # (term, votedFor), init the SM (restores its latest snapshot),
             # then open the segmented log above the snapshot.
-            term, voted_for = self.storage.load_metadata()
+            # (file reads, off the loop as the directories' making is)
+            term, voted_for = await asyncio.to_thread(
+                self.storage.load_metadata)
             self.state.current_term = term
             self.state.voted_for = voted_for
-            conf_entry = self.storage.load_conf_entry()
+            conf_entry = await asyncio.to_thread(
+                self.storage.load_conf_entry)
             if conf_entry is not None:
                 self.state.apply_log_entry_configuration(conf_entry)
             else:
@@ -923,7 +934,7 @@ class Division:
     async def change_to_candidate(self, force: bool = False) -> None:
         assert self.is_follower()
         self.role = RaftPeerRole.CANDIDATE
-        self._engine_set_role(ROLE_CANDIDATE)
+        self._engine_set_role(ROLE_CANDIDATE, lazy=True)
         self.election = LeaderElection(self, force=force)
         if force:
             # Leadership-transfer target (dissertation §3.10 TimeoutNow):
@@ -946,7 +957,7 @@ class Division:
                 if self.is_candidate():
                     # election did not conclude in leadership: back to follower
                     self.role = RaftPeerRole.FOLLOWER
-                    self._engine_set_role(ROLE_FOLLOWER)
+                    self._engine_set_role(ROLE_FOLLOWER, lazy=True)
                     self.reset_election_deadline()
 
         self._election_task = asyncio.create_task(
@@ -1076,7 +1087,8 @@ class Division:
                 self.state.set_leader(leader_id)
             return
         self.role = RaftPeerRole.FOLLOWER
-        self._engine_set_role(ROLE_FOLLOWER)
+        self._engine_set_role(ROLE_FOLLOWER,
+                              lazy=old_role != RaftPeerRole.LEADER)
         await self.state.update_current_term(term)
         if leader_id is not None:
             changed = self.state.set_leader(leader_id)

@@ -519,7 +519,7 @@ class LogAppender:
         # wake sets it: "next sweep heartbeats immediately").
         hb = self.heartbeat_interval_s
         if self._last_send_s:
-            if now - f.last_rpc_response_s < hb * 0.9:
+            if self._contact_fresh(now):
                 return None  # follower demonstrably fresh (recent reply)
             if now - self._last_send_s < hb * 0.45:
                 return None  # give the in-flight contact a chance to land
@@ -553,8 +553,24 @@ class LogAppender:
         if not self._last_send_s:
             return now
         hb = self.heartbeat_interval_s
-        return max(self.follower.last_rpc_response_s + hb * 0.9,
+        return max(min(self.follower.last_rpc_response_s, self._last_send_s)
+                   + hb * 0.9,
                    self._last_send_s + hb * 0.45)
+
+    def _contact_fresh(self, now: float) -> bool:
+        """The follower has heard from this leader within 0.9 x the
+        heartbeat interval, for all the leader can tell: a reply came in
+        that lately AND it answered something sent that lately.  The reply
+        alone is no proof: it says when the follower's answer got here, not
+        when the follower last heard.  A heartbeat whose reply came 0.3 s
+        late (a stalled loop) read as fresh at the next sweep, one interval
+        after it was sent, the sweep skipped the follower, and it heard
+        nothing for two intervals, which is the shortest election timeout:
+        every stall of the loop over 0.1 x the interval cost healthy
+        leaders elections (PERF.md §7)."""
+        fresh_for = self.heartbeat_interval_s * 0.9
+        return (now - self.follower.last_rpc_response_s < fresh_for
+                and now - self._last_send_s < fresh_for)
 
     async def on_bulk_reply(self, code: int, term: int, next_index: int,
                             follower_commit: int, flush_index: int,
@@ -690,7 +706,7 @@ class LogAppender:
             f = self.follower
             interval = self.heartbeat_interval_s
             if self._last_send_s:
-                if now - f.last_rpc_response_s < interval * 0.9:
+                if self._contact_fresh(now):
                     return  # follower demonstrably fresh (recent reply)
                 if now - self._last_send_s < interval * 0.45:
                     return

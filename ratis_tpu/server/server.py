@@ -430,6 +430,11 @@ class RaftServer:
         self._transport_factory = transport_factory
         self.life_cycle = LifeCycle(f"server-{peer_id}")
         self.divisions: dict[RaftGroupId, Division] = {}
+        # groups whose _add_division is under way: taken before its first
+        # await, so that a second group_add for the same id (a retry) is
+        # refused as the first one will be, not left to race it to the
+        # directory's lock
+        self._adding: set[RaftGroupId] = set()
         # Shared log plane (raft.tpu.log.shared): one interleaved store per
         # loop shard, created on first use, refcounted by its divisions.
         self._shared_log_stores: dict[int, object] = {}
@@ -902,8 +907,15 @@ class RaftServer:
                                                    me.datastream_address)
 
     async def _add_division(self, group: RaftGroup) -> Division:
-        if group.group_id in self.divisions:
+        if group.group_id in self.divisions or group.group_id in self._adding:
             raise AlreadyExistsException(f"{self.peer_id} already hosts {group.group_id}")
+        self._adding.add(group.group_id)
+        try:
+            return await self._add_reserved_division(group)
+        finally:
+            self._adding.discard(group.group_id)
+
+    async def _add_reserved_division(self, group: RaftGroup) -> Division:
         # a group arriving after startup (group_add) may be the first to
         # advertise a datastream address for this peer
         self._maybe_create_datastream(group)
@@ -928,8 +940,15 @@ class RaftServer:
             from ratis_tpu.server.log.segmented import LogWorker, SegmentedRaftLog
             from ratis_tpu.server.storage import RaftStorageDirectory
             storage = RaftStorageDirectory(root, group.group_id)
-            storage.format()
-            storage.lock()
+
+            def format_and_lock() -> None:
+                storage.format()
+                storage.lock()
+
+            # off the loop: a wave of groups added at once made its
+            # directories as one stall of seconds, which costs every group
+            # this server already hosts its heartbeats (PERF.md §7)
+            await asyncio.to_thread(format_and_lock)
             if RaftServerConfigKeys.TpuLog.shared(self.properties):
                 from ratis_tpu.server.log.shared import SharedGroupLog
                 store = self._shared_log_store(root,

@@ -47,7 +47,6 @@ def parse_peers(spec: str) -> List[RaftPeer]:
 
 def _new_client(peers: List[RaftPeer], group_id: Optional[RaftGroupId]):
     from ratis_tpu.client import RaftClient
-    from ratis_tpu.transport import grpc as _grpc  # noqa: F401 (registers)
     from ratis_tpu.transport.base import TransportFactory
     factory = TransportFactory.get("GRPC")
     group = RaftGroup.value_of(group_id or RaftGroupId.empty_id(), peers)

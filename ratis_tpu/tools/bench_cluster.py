@@ -60,7 +60,6 @@ def _ephemeral_port() -> int:
 def _tcp_factory():
     """The registered factory of the framed TCP transport."""
     from ratis_tpu.transport.base import TransportFactory
-    import ratis_tpu.transport.tcp  # noqa: F401  (registers TCP)
     return TransportFactory.get("TCP")
 
 

@@ -41,7 +41,6 @@ class MembershipCluster:
     """In-process counter cluster keyed by port (reference RaftCluster)."""
 
     def __init__(self):
-        from ratis_tpu.transport import tcp  # registers the factory
         from ratis_tpu.transport.base import TransportFactory
         self.factory = TransportFactory.get("TCP")
         self.properties = RaftProperties()

@@ -47,6 +47,7 @@ import contextvars
 import itertools
 import threading
 import time
+import types
 
 import numpy as np
 
@@ -118,7 +119,13 @@ STAGE_DATA_WRITE = 31   # sm.data_write — one entry's bytes written by the
                         # state machine, on the writing thread (tag = bytes)
 STAGE_DATA_FSYNC = 32   # sm.data_fsync — the force behind such writes
                         # (tag = files)
-NUM_STAGES = 33
+# The gRPC transport's two (what tcp.read / wire.flush are to TCP).
+STAGE_GRPC_READ = 33    # grpc.read — one inbound stream message: unpacked,
+                        # its chunks handed to their handlers (tag = chunks)
+STAGE_GRPC_WRITE = 34   # grpc.write — one outbound stream message: packed
+                        # and given to grpc.aio, up to where that call
+                        # suspends (tag = chunks)
+NUM_STAGES = 35
 
 STAGE_NAMES = (
     "client.send", "codec.encode", "codec.decode", "wire.rtt",
@@ -131,6 +138,7 @@ STAGE_NAMES = (
     "engine.pack", "engine.launch", "engine.fetch", "engine.collect",
     "tcp.read", "loop.select", "wire.flush",
     "server.data_wait", "sm.data_write", "sm.data_fsync",
+    "grpc.read", "grpc.write",
 )
 
 # W = work span: a stretch that is synchronous on one thread by construction
@@ -149,6 +157,7 @@ STAGE_KINDS = (
     "W", "W", "W", "W",
     "W", "W", "W",
     "I", "W", "W",
+    "W", "W",
 )
 
 # Work spans happen once per batch, several batches per commit: their rings
@@ -486,6 +495,42 @@ class Tracer:
             self._rings[stage].record(trace_id, t0_ns or t0, t1, tag,
                                       origin=threading.get_ident())
         return t1
+
+    @types.coroutine
+    def head(self, stage: int, awaitable, tag: int = 0):
+        """``await awaitable`` under a work span that covers its
+        synchronous head alone: from here to where the awaitable first
+        suspends (or ends, if it never does).  What it awaits after that is
+        other callbacks' time and lies outside the span.  Call only while
+        ``enabled``; sampled as any process-level span."""
+        it = awaitable.__await__()
+        span = self.begin(stage)
+        if span is None:
+            return (yield from it)
+        try:
+            try:
+                waits_for = next(it)
+            finally:
+                self.end(span, tag)
+        except StopIteration as e:
+            return e.value
+        # the rest of the delegation, as ``yield from`` would do it
+        while True:
+            try:
+                sent = yield waits_for
+            except GeneratorExit:
+                it.close()
+                raise
+            except BaseException as e:
+                try:
+                    waits_for = it.throw(e)
+                except StopIteration as s:
+                    return s.value
+            else:
+                try:
+                    waits_for = it.send(sent)
+                except StopIteration as s:
+                    return s.value
 
     def mark_egress(self, trace_id: int) -> None:
         """Server handler is done with this request NOW; the transport pops

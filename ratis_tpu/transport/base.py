@@ -5,12 +5,14 @@ SPI (ratis-common/.../rpc/SupportedRpcType.java:24-48, RpcFactory): a server
 binds one endpoint serving all its groups; clients and peer servers reach it
 by peer address.  Implementations: SIMULATED (in-memory, deterministic,
 fault-injectable — the test transport, cf. the reference's
-SimulatedRequestReply) and GRPC (real network).
+SimulatedRequestReply), GRPC and TCP (real network; NETTY is TCP's other
+name).
 """
 
 from __future__ import annotations
 
 import abc
+import importlib
 from typing import Awaitable, Callable, Optional
 
 from ratis_tpu.protocol.ids import RaftPeerId
@@ -52,7 +54,10 @@ class ClientTransport(abc.ABC):
 
 
 class TransportFactory:
-    """Registry keyed by rpc type string (SIMULATED / GRPC)."""
+    """Registry keyed by rpc type string (GRPC / TCP / NETTY).  A factory
+    registers itself when its module is imported, and a type nobody has
+    imported yet is found by its name: ``ratis_tpu.transport.<type>``, as
+    the reference's SupportedRpcType.valueOf finds its factory class."""
 
     _factories: dict[str, "TransportFactory"] = {}
 
@@ -62,8 +67,14 @@ class TransportFactory:
 
     @classmethod
     def get(cls, rpc_type: str) -> "TransportFactory":
+        key = rpc_type.upper()
+        if key not in cls._factories:
+            try:
+                importlib.import_module("ratis_tpu.transport." + key.lower())
+            except ModuleNotFoundError:
+                pass
         try:
-            return cls._factories[rpc_type.upper()]
+            return cls._factories[key]
         except KeyError:
             raise ValueError(f"unsupported rpc type {rpc_type!r}; "
                              f"known: {sorted(cls._factories)}") from None

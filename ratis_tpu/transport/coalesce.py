@@ -27,8 +27,6 @@ from __future__ import annotations
 import asyncio
 from typing import Optional
 
-from ratis_tpu.trace.tracer import TRACER, loop_key
-
 __all__ = ["WriteCoalescer"]
 
 
@@ -38,25 +36,20 @@ class WriteCoalescer:
     Generic over the flush primitive: subclasses implement
     :meth:`_flush_batch` (the gRPC transport packs chunks into one stream
     message).  ``max_frames`` caps frames per flush (0 = unbounded), so
-    one stream message never carries an unbounded chunk list.
+    one stream message never carries an unbounded chunk list.  What goes
+    out is counted where it is handed to the transport (``wire.*`` in
+    transport/grpc.py, as in transport/tcp.py), not here.
     """
 
     def __init__(self, flush_micros: int = 0, max_frames: int = 0):
         self.flush_micros = int(flush_micros)
         self.max_frames = int(max_frames)
         self._pending: list = []
-        self._pending_bytes = 0
         self._waiters: list[asyncio.Future] = []
         self._flusher: Optional[asyncio.Task] = None
         self._lock = asyncio.Lock()
         self._dead: Optional[Exception] = None
         self.metrics = {"flushes": 0, "coalesced_frames": 0}
-        # frames and bytes are the process's wire counters (ratis_tpu.trace:
-        # always on, a trace session snapshots them), one pair per loop so
-        # that every add comes from one thread
-        key = loop_key()
-        self._n_frames = TRACER.counter("wire.frames", key)
-        self._n_bytes = TRACER.counter("wire.bytes", key)
 
     @property
     def coalescing(self) -> bool:
@@ -69,7 +62,7 @@ class WriteCoalescer:
     async def _flush_batch(self, frames: list) -> None:
         raise NotImplementedError
 
-    async def send(self, frame, nbytes: int) -> None:
+    async def send(self, frame) -> None:
         """Queue ``frame`` and return once the flush carrying it drained
         (backpressure: callers wait out the transport's flow control
         exactly as the per-frame path did)."""
@@ -82,12 +75,9 @@ class WriteCoalescer:
                     raise self._dead
                 await self._flush_batch([frame])
                 self.metrics["flushes"] += 1
-                self._n_frames.n += 1
-                self._n_bytes.n += nbytes
             return
         fut = asyncio.get_running_loop().create_future()
         self._pending.append(frame)
-        self._pending_bytes += nbytes
         self._waiters.append(fut)
         if self.max_frames and len(self._pending) >= self.max_frames:
             await self._flush_now()
@@ -109,9 +99,7 @@ class WriteCoalescer:
                 return
             frames = self._pending
             waiters = self._waiters
-            nbytes = self._pending_bytes
             self._pending, self._waiters = [], []
-            self._pending_bytes = 0
             try:
                 await self._flush_batch(frames)
             except asyncio.CancelledError:
@@ -122,8 +110,6 @@ class WriteCoalescer:
                 self._poison(e, waiters)
                 return
             self.metrics["flushes"] += 1
-            self._n_frames.n += len(frames)
-            self._n_bytes.n += nbytes
             if len(frames) > 1:
                 self.metrics["coalesced_frames"] += len(frames)
             for f in waiters:
@@ -139,7 +125,6 @@ class WriteCoalescer:
                 f.set_exception(exc)
         self._waiters.clear()
         self._pending.clear()
-        self._pending_bytes = 0
 
     async def aclose(self) -> None:
         """Flush anything still pending (flush-on-close), then retire the

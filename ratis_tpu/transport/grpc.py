@@ -38,7 +38,9 @@ from ratis_tpu.protocol.requests import (DEFERRED_REPLY, RaftClientReply,
                                          RaftClientRequest,
                                          attach_reply_sink)
 from ratis_tpu.trace.tracer import (INGRESS_NS, STAGE_DECODE, STAGE_ENCODE,
-                                    STAGE_RESPOND, STAGE_WIRE, TRACER)
+                                    STAGE_GRPC_READ, STAGE_GRPC_WRITE,
+                                    STAGE_RESPOND, STAGE_WIRE, TRACER,
+                                    loop_key)
 from ratis_tpu.transport.base import (ClientRequestHandler, ClientTransport,
                                       ServerRpcHandler, ServerTransport,
                                       TransportFactory)
@@ -227,6 +229,56 @@ class _StreamDialGate:
         return True
 
 
+class _WireCount:
+    """The running loop's share of the process's wire counters
+    (ratis_tpu.trace: always on, a trace session snapshots them; one set a
+    loop, so that every add comes from one thread).  ``wire.frames`` and
+    ``wire.bytes`` mean what they mean over TCP, what this process hands to
+    the socket layer: an rpc frame is a ``[call_id, payload]`` chunk, a
+    reply triple or a unary body, the bytes are those of the gRPC messages
+    that carry them.  ``grpc.messages_out`` / ``grpc.messages_in`` count the
+    messages themselves, stream and unary, written and read (each is one
+    call into grpc.aio), and ``grpc.chunks_out`` what the written ones
+    carried: chunks over messages is 1.0 while nothing batches."""
+
+    __slots__ = ("frames", "nbytes", "messages_out", "messages_in",
+                 "chunks_out")
+
+    def __init__(self) -> None:
+        key = loop_key()
+        self.frames = TRACER.counter("wire.frames", key)
+        self.nbytes = TRACER.counter("wire.bytes", key)
+        self.messages_out = TRACER.counter("grpc.messages_out", key)
+        self.messages_in = TRACER.counter("grpc.messages_in", key)
+        self.chunks_out = TRACER.counter("grpc.chunks_out", key)
+
+    def wrote(self, nbytes: int, chunks: int = 1) -> None:
+        self.messages_out.n += 1
+        self.chunks_out.n += chunks
+        self.frames.n += chunks
+        self.nbytes.n += nbytes
+
+
+async def _write_message(write, item, chunks: int, wire: _WireCount) -> None:
+    data = msgpack.packb(item)
+    await write(data)
+    wire.wrote(len(data), chunks)
+
+
+def _stream_write(write, item, chunks: int, wire: _WireCount):
+    """One outbound stream message, at either end of a stream: ``item`` (a
+    chunk or a reply, or a list of ``chunks`` of them) packed and given to
+    grpc.aio's ``write``; counted once that has taken it.  In a trace
+    session the ``grpc.write`` work span covers the pack and the call into
+    grpc.aio up to where it suspends (the message serialized and started in
+    the core); the wake-up when the core has sent it is a callback of its
+    own.  Writes of one call never overlap: the caller serializes them."""
+    message = _write_message(write, item, chunks, wire)
+    if TRACER.enabled:
+        return TRACER.head(STAGE_GRPC_WRITE, message, chunks)
+    return message
+
+
 class _StreamChunkCoalescer(WriteCoalescer):
     """Stream-framing coalescing (VERDICT r5 item 6): one bidi stream
     message carries a BATCH of ``[call_id, payload]`` chunks, so grpc.aio's
@@ -234,16 +286,27 @@ class _StreamChunkCoalescer(WriteCoalescer):
     per append.  A single-chunk flush keeps the legacy wire shape (a bare
     pair), so with thresholds at 0 the stream framing is unchanged."""
 
-    def __init__(self, call, flush_micros: int = 0, max_frames: int = 64):
+    def __init__(self, call, wire: _WireCount, flush_micros: int = 0,
+                 max_frames: int = 64):
         super().__init__(flush_micros=flush_micros, max_frames=max_frames)
         self._call = call
+        self._wire = wire
 
     async def _flush_batch(self, frames: list) -> None:
         # the coalescer's internal lock serializes flushes, which is the
         # overlapping-write serialization grpc core requires
         # (GRPC_CALL_ERROR_TOO_MANY_OPERATIONS)
-        await self._call.write(msgpack.packb(
-            frames[0] if len(frames) == 1 else frames))
+        try:
+            await _stream_write(self._call.write,
+                                frames[0] if len(frames) == 1 else frames,
+                                len(frames), self._wire)
+        except BaseException as e:
+            # failed or cancelled MID-write: the call may hold an abandoned
+            # core write op and takes no other write; senders still queued
+            # behind this one fail fast instead of writing into it
+            self._poison(e if isinstance(e, Exception) else ConnectionError(
+                "stream write cancelled mid-flight"))
+            raise
 
 
 class _DeferredStreamFanout:
@@ -256,7 +319,7 @@ class _DeferredStreamFanout:
     ships them — one scheduled hop per burst per stream instead of one
     handler-resume + reply-write chain per request."""
 
-    __slots__ = ("_loop", "_replies", "_q", "_lock", "_armed")
+    __slots__ = ("_loop", "_replies", "_q", "_lock", "_armed", "traced")
 
     def __init__(self, loop: asyncio.AbstractEventLoop,
                  replies: asyncio.Queue) -> None:
@@ -267,6 +330,12 @@ class _DeferredStreamFanout:
         self._q = collections.deque()
         self._lock = threading.Lock()
         self._armed = False
+        # call id -> (trace id, ns the reply was ready): the traced replies
+        # now in the reply queue.  The stream's writer closes each one's
+        # server.respond span as it hands the reply to grpc.aio, so the
+        # span covers this hop and the queue's wait, the stretch that ends
+        # at the socket layer over TCP
+        self.traced: dict[int, tuple[int, int]] = {}
 
     def sink_for(self, call_id: int, trace_id: int = 0):
         def sink(reply: RaftClientReply) -> None:
@@ -296,9 +365,10 @@ class _DeferredStreamFanout:
             items = list(self._q)
             self._q.clear()
             self._armed = False
-        now = TRACER.now() if TRACER.enabled else 0
         backlog: list = []
         for out, tid, t0 in items:
+            if tid and t0:
+                self.traced[out[0]] = (tid, t0)
             if backlog:
                 backlog.append(out)
             else:
@@ -308,10 +378,6 @@ class _DeferredStreamFanout:
                     # reply order across call ids is irrelevant (replies
                     # are id-matched); overflow rides one catch-up task
                     backlog.append(out)
-            if tid and t0:
-                # respond span (deferred shape): reply ready at the
-                # division -> handed to this stream's reply fold
-                TRACER.record(tid, STAGE_RESPOND, t0, now, tag=len(out[2]))
         if backlog:
             self._loop.create_task(self._put_backlog(backlog))
 
@@ -334,10 +400,11 @@ class _AppendStreamClient:
         self._pending: dict[int, asyncio.Future] = {}
         self._next_id = 0
         self.closed = False
+        self._wire = _WireCount()
         # serializes writes (grpc core rejects overlapping write() ops on
         # one call) and, when flush_micros > 0, batches chunks into one
         # stream message per flush
-        self._out = _StreamChunkCoalescer(self._call,
+        self._out = _StreamChunkCoalescer(self._call, self._wire,
                                           flush_micros=flush_micros,
                                           max_frames=flush_chunks)
         self._reader = asyncio.create_task(self._read_loop())
@@ -349,12 +416,9 @@ class _AppendStreamClient:
         self._next_id += 1
         fut = asyncio.get_running_loop().create_future()
         self._pending[call_id] = fut
-        wrote = False
 
         async def _write_then_wait() -> bytes:
-            nonlocal wrote
-            await self._out.send([call_id, payload], len(payload) + 16)
-            wrote = True
+            await self._out.send([call_id, payload])
             return await fut
 
         try:
@@ -363,15 +427,16 @@ class _AppendStreamClient:
             # appender's send slot frees and its window resets
             return await asyncio.wait_for(_write_then_wait(), timeout_s)
         except asyncio.TimeoutError:
-            if not wrote and not self._out.coalescing:
+            if self._out.poisoned:
                 # the deadline cancelled the writer MID self._call.write():
                 # the call may hold an abandoned core write op, and reusing
                 # it breaks the overlapping-write serialization — this
-                # stream is done (callers see .closed and re-dial); only
-                # the reply-is-late case is safe to ride out.  With
-                # coalescing on, the chunk was merely QUEUED and the
-                # flusher task owns the core write — the stream stays
-                # healthy and the late reply is dropped by the reader.
+                # stream is done (callers see .closed and re-dial).  A
+                # chunk that was still QUEUED (behind the stream's one
+                # write at a time, or in the coalescer's batch, whose
+                # flusher task owns the core write) never reached the call:
+                # the stream stays healthy for everybody else's appends, and
+                # a reply that is merely late is dropped by the reader.
                 self._fail(TimeoutIOException(
                     "append stream write timed out (flow-blocked peer)"))
             raise
@@ -392,15 +457,24 @@ class _AppendStreamClient:
     async def _read_loop(self) -> None:
         try:
             async for chunk in self._call:
-                decoded = msgpack.unpackb(chunk)
-                if decoded and isinstance(decoded[0], (list, tuple)):
-                    # coalesced reply batch: several [id, status, payload]
-                    # triples in one stream message
-                    for call_id, status, payload in decoded:
+                self._wire.messages_in.n += 1
+                # grpc.read work span: one stream message of replies, from
+                # its unpacking to the last of them resolving its call
+                span = (TRACER.begin(STAGE_GRPC_READ) if TRACER.enabled
+                        else None)
+                replies = ()
+                try:
+                    decoded = msgpack.unpackb(chunk)
+                    # one [id, status, payload] triple, or a coalesced
+                    # batch of them in one stream message
+                    replies = (decoded if decoded
+                               and isinstance(decoded[0], (list, tuple))
+                               else (decoded,))
+                    for call_id, status, payload in replies:
                         self._dispatch_reply(call_id, status, payload)
-                else:
-                    call_id, status, payload = decoded
-                    self._dispatch_reply(call_id, status, payload)
+                finally:
+                    if span is not None:
+                        TRACER.end(span, tag=len(replies))
         except asyncio.CancelledError:
             self._fail(ConnectionError("append stream closed"))
             raise
@@ -493,13 +567,26 @@ class GrpcServerTransport(ServerTransport):
         self.request_timeout_s = request_timeout_s
         self.tls = tls
         self._server: Optional[grpc.aio.Server] = None
+        self._wire: Optional[_WireCount] = None
         self._pool = _ChannelPool(tls)
         self._append_streams: dict[str, _AppendStreamClient] = {}
         self._dial_gate = _StreamDialGate()
 
     # ---------------------------------------------------------- service side
 
+    def _counts(self) -> _WireCount:
+        """The home loop's counters: every handler and every send runs on
+        that loop (made on first use: a send may come before start())."""
+        if self._wire is None:
+            self._wire = _WireCount()
+        return self._wire
+
+    def _unary_reply(self, reply: bytes) -> bytes:
+        self._counts().wrote(len(reply))
+        return reply
+
     async def _handle_rpc(self, request_bytes: bytes, context) -> bytes:
+        self._counts().messages_in.n += 1
         try:
             msg = decode_rpc(request_bytes)
         except Exception as e:
@@ -512,16 +599,17 @@ class GrpcServerTransport(ServerTransport):
         except Exception as e:
             LOG.exception("%s: server rpc failed", self.peer_id)
             await context.abort(grpc.StatusCode.INTERNAL, str(e))
-        return encode_rpc(reply)
+        return self._unary_reply(encode_rpc(reply))
 
     async def _handle_client(self, request_bytes: bytes, context) -> bytes:
+        self._counts().messages_in.n += 1
         try:
             request = RaftClientRequest.from_bytes(request_bytes)
         except Exception as e:
             await context.abort(grpc.StatusCode.INVALID_ARGUMENT,
                                 f"undecodable client request: {e}")
         reply = await self.client_handler(request)
-        return reply.to_bytes()
+        return self._unary_reply(reply.to_bytes())
 
     # bound on concurrently-processing chunks per inbound stream: enough to
     # keep every co-hosted group's append pipeline full, finite so a peer
@@ -529,8 +617,8 @@ class GrpcServerTransport(ServerTransport):
     # handler tasks)
     _STREAM_CONCURRENCY = 256
 
-    async def _serve_stream(self, request_iterator, dispatch, classify=None,
-                            defer: bool = False):
+    async def _serve_stream(self, request_iterator, write, dispatch,
+                            classify=None, defer: bool = False) -> None:
         """Shared server scaffold for the multiplexed bidi streams (append
         plane and client plane): chunks are handled CONCURRENTLY (a slow
         division flush must not head-of-line-block every co-hosted group
@@ -550,7 +638,13 @@ class GrpcServerTransport(ServerTransport):
         (``raft.tpu.grpc.*``); replies batch the same way — everything
         ready in the reply queue folds into one stream message, zero added
         latency.  ``dispatch(work) -> reply bytes``; a RaftException maps
-        to _ST_RAFT_ERROR, anything else to _ST_INTERNAL."""
+        to _ST_RAFT_ERROR, anything else to _ST_INTERNAL.
+
+        Replies go out through ``write`` (the call's ``context.write``:
+        grpc.aio's reader-writer style; one writer, this coroutine, so
+        writes never overlap), each stream message a ``grpc.write`` work
+        span; each inbound message is a ``grpc.read`` work span in the
+        pump."""
         # BOUNDED reply queue: run_one blocks on put when the consumer (the
         # HTTP/2 send side) stalls, which keeps the gate held, which stops
         # the pump from accepting more chunks — end-to-end backpressure.
@@ -563,6 +657,7 @@ class GrpcServerTransport(ServerTransport):
         tasks: set[asyncio.Task] = set()
         last_by_key: dict[object, asyncio.Future] = {}
         metrics = self.dispatch_metrics
+        wire = self._counts()
         # deferred-reply fan-out (commit fan-out collapse): dispatch gets
         # (fanout, call_id) and may return None — the reply arrives later
         # through the fanout's thread-safe drain into this reply queue
@@ -636,26 +731,47 @@ class GrpcServerTransport(ServerTransport):
         async def pump() -> None:
             try:
                 async for chunk in request_iterator:
+                    wire.messages_in.n += 1
+                    # grpc.read work span: one stream message, from its
+                    # unpacking to its last chunk classified, keyed and
+                    # given a task (tag = chunks handed on)
+                    span = (TRACER.begin(STAGE_GRPC_READ) if TRACER.enabled
+                            else None)
+                    handed = 0
                     try:
-                        decoded = msgpack.unpackb(chunk)
-                        if decoded and isinstance(decoded[0], (list, tuple)):
-                            # coalesced batch of [call_id, payload] pairs
-                            pairs = [(c, p) for c, p in decoded]
-                        else:
-                            c, p = decoded
-                            pairs = [(c, p)]
-                    except Exception as e:
-                        # peer is garbling the FRAMING: stop reading — the
-                        # stream ends and the sender re-dials.  Say WHY on
-                        # this side (a bare break would leave both ends
-                        # diagnosing a generic 'stream closed').
-                        LOG.error("%s: undecodable stream chunk (%s); "
-                                  "closing stream", self.peer_id, e)
-                        break
-                    if len(pairs) > 1:
-                        metrics["batched_messages"] += 1
-                    for call_id, payload in pairs:
-                        await enqueue(call_id, payload)
+                        try:
+                            decoded = msgpack.unpackb(chunk)
+                            if decoded and isinstance(decoded[0],
+                                                      (list, tuple)):
+                                # coalesced batch of [call_id, payload] pairs
+                                pairs = [(c, p) for c, p in decoded]
+                            else:
+                                c, p = decoded
+                                pairs = [(c, p)]
+                        except Exception as e:
+                            # peer is garbling the FRAMING: stop reading —
+                            # the stream ends and the sender re-dials.  Say
+                            # WHY on this side (a bare break would leave
+                            # both ends diagnosing a generic 'stream
+                            # closed').
+                            LOG.error("%s: undecodable stream chunk (%s); "
+                                      "closing stream", self.peer_id, e)
+                            break
+                        if len(pairs) > 1:
+                            metrics["batched_messages"] += 1
+                        for call_id, payload in pairs:
+                            if span is not None and (gate.locked()
+                                                     or replies.full()):
+                                # enqueue may have to wait (a slot, room for
+                                # an error reply): other handlers' time, so
+                                # the span ends with what it handed on
+                                TRACER.end(span, tag=handed)
+                                span = None
+                            await enqueue(call_id, payload)
+                            handed += 1
+                    finally:
+                        if span is not None:
+                            TRACER.end(span, tag=handed)
             finally:
                 # all accepted work must flush before the end marker
                 for t in list(tasks):
@@ -679,13 +795,11 @@ class GrpcServerTransport(ServerTransport):
                 item = await replies.get()
                 if item is None:
                     break
-                if not coalesce_replies:
-                    yield msgpack.packb(item)
-                    continue
-                # batch-what's-ready: fold every already-queued reply into
-                # this stream message (no timed wait — zero added latency)
+                # batch-what's-ready (coalescing only): fold every
+                # already-queued reply into this stream message (no timed
+                # wait — zero added latency)
                 batch = [item]
-                while len(batch) < self.flush_chunks:
+                while coalesce_replies and len(batch) < self.flush_chunks:
                     try:
                         nxt = replies.get_nowait()
                     except asyncio.QueueEmpty:
@@ -696,7 +810,18 @@ class GrpcServerTransport(ServerTransport):
                     batch.append(nxt)
                 if len(batch) > 1:
                     metrics["reply_batches"] += 1
-                yield msgpack.packb(batch if len(batch) > 1 else batch[0])
+                if fanout is not None and fanout.traced:
+                    # server.respond ends here, at the hand-over to
+                    # grpc.aio: reply ready -> its stream message's write
+                    now = TRACER.now()
+                    for out in batch:
+                        since = fanout.traced.pop(out[0], None)
+                        if since is not None:
+                            TRACER.record(since[0], STAGE_RESPOND, since[1],
+                                          now, tag=len(out[2]))
+                await _stream_write(write,
+                                    batch if len(batch) > 1 else batch[0],
+                                    len(batch), wire)
         finally:
             pump_task.cancel()
             for t in list(tasks):
@@ -729,9 +854,8 @@ class GrpcServerTransport(ServerTransport):
         async def dispatch(msg) -> bytes:
             return encode_rpc(await self.server_handler(msg))
 
-        async for item in self._serve_stream(request_iterator, dispatch,
-                                             classify=classify):
-            yield item
+        await self._serve_stream(request_iterator, context.write, dispatch,
+                                 classify=classify)
 
     async def _handle_client_stream(self, request_iterator, context):
         """Server side of the multiplexed client-request stream (reference
@@ -770,14 +894,18 @@ class GrpcServerTransport(ServerTransport):
                 return None
             reply_bytes = reply.to_bytes()
             egress = TRACER.pop_egress(request.trace_id)
-            if egress:
+            if egress and defer_ctx is not None:
+                # the stream's writer ends the span (see _serve_stream)
+                fanout.traced[call_id] = (request.trace_id, egress)
+            elif egress:
+                # no fan-out on this stream (reply-fanout off): the span
+                # stops at the reply queue
                 TRACER.record(request.trace_id, STAGE_RESPOND, egress,
                               TRACER.now(), tag=len(reply_bytes))
             return reply_bytes
 
-        async for item in self._serve_stream(request_iterator, dispatch,
-                                             defer=self.defer_replies):
-            yield item
+        await self._serve_stream(request_iterator, context.write, dispatch,
+                                 defer=self.defer_replies)
 
     def _client_handlers(self):
         return grpc.method_handlers_generic_handler(
@@ -795,6 +923,7 @@ class GrpcServerTransport(ServerTransport):
         plane (firewallable separately, like the reference's admin
         server)."""
         from ratis_tpu.protocol.requests import RequestType
+        self._counts().messages_in.n += 1
         try:
             request = RaftClientRequest.from_bytes(request_bytes)
         except Exception as e:
@@ -806,7 +935,7 @@ class GrpcServerTransport(ServerTransport):
                 grpc.StatusCode.PERMISSION_DENIED,
                 f"{request.type.type.name} is not an admin operation")
         reply = await self.client_handler(request)
-        return reply.to_bytes()
+        return self._unary_reply(reply.to_bytes())
 
     def _admin_handlers(self):
         return grpc.method_handlers_generic_handler(
@@ -980,8 +1109,10 @@ class GrpcServerTransport(ServerTransport):
                 or (isinstance(msg, AppendEntriesRequest) and msg.entries)):
             return await self._send_via_stream(to, address, msg)
         call = self._pool.unary(address, _RPC_METHOD)
+        request_bytes = encode_rpc(msg)
+        self._counts().wrote(len(request_bytes))
         try:
-            reply_bytes = await call(encode_rpc(msg),
+            reply_bytes = await call(request_bytes,
                                      timeout=self.request_timeout_s)
         except grpc.aio.AioRpcError as e:
             if e.code() in _TRANSIENT_CODES:
@@ -994,6 +1125,7 @@ class GrpcServerTransport(ServerTransport):
             raise RaftException(
                 f"{self.peer_id}->{to} rpc failed {e.code().name}: "
                 f"{e.details()}") from None
+        self._counts().messages_in.n += 1
         return decode_rpc(reply_bytes)
 
     async def _send_via_stream(self, to: RaftPeerId, address: str, msg):
@@ -1065,6 +1197,7 @@ class GrpcClientTransport(ClientTransport):
         # address -> shared bidi request stream (one per server)
         self._streams: dict[str, _AppendStreamClient] = {}
         self._dial_gate = _StreamDialGate()
+        self._wire: Optional[_WireCount] = None  # the unary calls' counters
 
     async def send_request(self, peer_address: str,
                            request: RaftClientRequest) -> RaftClientReply:
@@ -1129,8 +1262,13 @@ class GrpcClientTransport(ClientTransport):
                           request: RaftClientRequest,
                           timeout: float) -> RaftClientReply:
         call = self._pool.unary(peer_address, _REQUEST_METHOD)
+        if self._wire is None:
+            self._wire = _WireCount()
+        wire = self._wire
+        request_bytes = request.to_bytes()
+        wire.wrote(len(request_bytes))
         try:
-            reply_bytes = await call(request.to_bytes(), timeout=timeout)
+            reply_bytes = await call(request_bytes, timeout=timeout)
         except grpc.aio.AioRpcError as e:
             if e.code() in _TRANSIENT_CODES:
                 raise TimeoutIOException(
@@ -1139,6 +1277,7 @@ class GrpcClientTransport(ClientTransport):
             raise RaftException(
                 f"client->{peer_address} rpc failed {e.code().name}: "
                 f"{e.details()}") from None
+        wire.messages_in.n += 1
         return RaftClientReply.from_bytes(reply_bytes)
 
     async def close(self) -> None:

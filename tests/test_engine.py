@@ -412,6 +412,83 @@ def test_vote_round_timeout_without_majority():
     asyncio.run(run())
 
 
+def test_an_open_vote_round_costs_no_dispatch_until_it_can_be_decided():
+    """PR 32: a vote round that is open and has nothing to decide (no reply
+    since the last tally, its deadline ahead) lets the ticks pass over it:
+    no quorum-step dispatch and no tally, and neither opening a round nor a
+    reply wakes the tick loop.  (Open rounds used to open the sweep gate on
+    every tick and every reply woke the loop: an election storm fed itself
+    on the engines' dispatches.)"""
+    async def run():
+        e = _mk_engine(use_device=True)
+        rec = Recorder()
+        slot = _setup_candidate(e, rec)
+        await e.tick()  # uploads the device state, clears the dirty row
+        fut = e.begin_vote_round(slot, deadline_ms=10_000)
+        assert not e._wake.is_set()
+        before = (e.metrics["batched_dispatches"], e.metrics["vote_tallies"])
+        for _ in range(5):
+            await e.tick()
+        assert (e.metrics["batched_dispatches"],
+                e.metrics["vote_tallies"]) == before
+        assert e.metrics["idle_skips"] >= 5 and not fut.done()
+        # a reply is tallied at the next tick, once, without the quorum step
+        e.on_vote_reply(slot, 1, granted=False)
+        assert not e._wake.is_set()
+        await e.tick()
+        await e.tick()
+        assert e.metrics["vote_tallies"] == before[1] + 1
+        assert e.metrics["batched_dispatches"] == before[0]
+        assert not fut.done()
+        # and the deadline alone is enough to decide it
+        e.clock.t = 10_001
+        await e.tick()
+        assert fut.result() == "TIMEOUT"
+        assert e.metrics["vote_tallies"] == before[1] + 2
+        assert e.state.vote_deadline_ms.min() == NO_DEADLINE
+
+    asyncio.run(run())
+
+
+def test_a_candidacy_rides_the_next_dispatch_and_causes_none():
+    """PR 32: a row marked lazy (a candidacy begun or given up) does not
+    open the sweep gate; the next dispatch, whatever causes it, refreshes
+    the row before the kernel decides anything, so the device agrees with
+    the mirror again and the follower's re-armed deadline fires."""
+    from ratis_tpu.engine.state import ROLE_CANDIDATE
+
+    async def run():
+        e = _mk_engine(use_device=True)
+        rec = Recorder()
+        slot = e.attach(rec)
+        s = e.state
+        s.role[slot] = ROLE_FOLLOWER
+        s.election_deadline_ms[slot] = NO_DEADLINE  # fired: a candidate now
+        s.mark_dirty(slot)
+        e.clock.t = 10
+        await e.tick()  # upload
+        s.role[slot] = ROLE_CANDIDATE
+        s.mark_lazy(slot)
+        before = e.metrics["batched_dispatches"]
+        for _ in range(3):
+            await e.tick()
+        assert e.metrics["batched_dispatches"] == before
+        assert s.lazy == {slot}
+        # candidacy given up: follower again, deadline re-armed
+        s.role[slot] = ROLE_FOLLOWER
+        s.mark_lazy(slot)
+        e.on_deadline(slot, 100)
+        await e.tick()
+        assert e.metrics["batched_dispatches"] == before
+        e.clock.t = 101
+        await e.tick()  # the deadline opens the gate; the row goes first
+        assert e.metrics["batched_dispatches"] == before + 1
+        assert not s.lazy and "timeout" in rec.events
+        assert int(np.asarray(e._dev.role)[slot]) == ROLE_FOLLOWER
+
+    asyncio.run(run())
+
+
 def test_vote_round_first_reply_wins_and_end_round():
     """A flip-flopped duplicate reply must not double-count
     (waitForResults responses.putIfAbsent); end_vote_round cancels."""

@@ -7,6 +7,8 @@ a REAL leader's appender objects (full wiring, no mocks)."""
 import asyncio
 import time
 
+import pytest
+
 from minicluster import MiniCluster, batched_properties, run_with_new_cluster
 
 
@@ -38,14 +40,25 @@ def test_heartbeat_emits_despite_backoff_and_queued_sends():
     run_with_new_cluster(3, body, properties=batched_properties())
 
 
-def test_heartbeat_suppressed_while_follower_demonstrably_fresh():
+@pytest.mark.parametrize("sent_ago, suppressed", [
+    # a reply just in, to something sent half an interval ago: the follower
+    # heard from us that lately, no heartbeat
+    (0.5, True),
+    # a reply just in, but to something sent two intervals ago (it was
+    # late: a stalled loop): the follower has heard nothing for two
+    # intervals, which is its shortest election timeout; the reply's age
+    # alone used to read as fresh here (PR 32)
+    (2.0, False)])
+def test_heartbeat_suppressed_while_follower_demonstrably_fresh(
+        sent_ago, suppressed):
     async def body(cluster: MiniCluster):
         leader, a = await _leader_appender(cluster)
         now = time.monotonic()
         hb = a.heartbeat_interval_s
         a.follower.last_rpc_response_s = now - 0.1 * hb  # fresh reply
-        a._last_send_s = now - 2 * hb
-        assert a.heartbeat_item(now) is None
+        a._last_send_s = now - sent_ago * hb
+        assert (a.next_due(now) > now) == suppressed  # (the plane's view)
+        assert (a.heartbeat_item(now) is None) == suppressed
 
     run_with_new_cluster(3, body, properties=batched_properties())
 

@@ -224,6 +224,39 @@ class TestDurableCluster:
         run(body())
 
 
+    def test_a_second_group_add_under_way_is_refused(self, tmp_path):
+        """Review regression (PR 32): a durable group's directory is made
+        and locked in a thread, so ``_add_division`` suspends between its
+        check and the registration.  A retry of the same ``group_add``
+        meanwhile is refused as one after it would be, and exactly one
+        Division sits on the directory."""
+        from ratis_tpu.protocol.exceptions import AlreadyExistsException
+        from ratis_tpu.protocol.group import RaftGroup
+
+        async def body():
+            cluster = MiniCluster(1, storage_root=str(tmp_path))
+            await cluster.start()
+            try:
+                server = next(iter(cluster.servers.values()))
+                g2 = RaftGroup.value_of(RaftGroupId.random_id(),
+                                        cluster.group.peers)
+                got = await asyncio.gather(server.group_add(g2),
+                                           server.group_add(g2),
+                                           return_exceptions=True)
+                assert sorted(type(r).__name__ for r in got) == [
+                    "AlreadyExistsException", "Division"], got
+                div = next(r for r in got
+                           if not isinstance(r, Exception))
+                assert server.divisions[g2.group_id] is div
+                assert not server._adding
+                with pytest.raises(AlreadyExistsException):
+                    await server.group_add(g2)
+            finally:
+                await cluster.close()
+
+        run(body())
+
+
 class TestSnapshotBoundary:
     def test_empty_log_restarts_above_snapshot(self, tmp_path):
         """Review regression: snapshot at 100 + purged log must not restart

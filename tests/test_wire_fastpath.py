@@ -72,7 +72,7 @@ def test_thresholds_zero_is_per_frame_bit_identical():
         c = _coalescer(w, flush_micros=0)
         frames = [b"frame-%d" % i for i in range(5)]
         for f in frames:
-            await c.send(f, len(f))
+            await c.send(f)
         assert not c.coalescing
         assert w.chunks == frames          # one write per frame, in order
         assert w.drains == len(frames)     # one drain per frame
@@ -91,7 +91,7 @@ def test_coalescing_batches_but_stream_is_identical():
         w = _FakeWriter()
         c = _coalescer(w, flush_micros=100)
         frames = [b"frame-%d" % i for i in range(8)]
-        await asyncio.gather(*(c.send(f, len(f)) for f in frames))
+        await asyncio.gather(*(c.send(f) for f in frames))
         await c.aclose()
         assert b"".join(w.chunks) == b"".join(frames)  # bit-identical
         assert w.drains < len(frames)                  # actually coalesced
@@ -109,7 +109,7 @@ def test_frame_threshold_boundary_flushes_immediately():
         w = _FakeWriter()
         c = _coalescer(w, max_frames=2, flush_micros=10_000_000)
         t0 = asyncio.get_running_loop().time()
-        await asyncio.gather(c.send(b"12345", 5), c.send(b"67890", 5))
+        await asyncio.gather(c.send(b"12345"), c.send(b"67890"))
         took = asyncio.get_running_loop().time() - t0
         assert took < 1.0, "frame-threshold flush waited on the timer"
         assert b"".join(w.chunks) == b"1234567890"
@@ -124,7 +124,7 @@ def test_latency_threshold_flushes_single_frame():
     async def main():
         w = _FakeWriter()
         c = _coalescer(w, flush_micros=5_000)
-        await asyncio.wait_for(c.send(b"lonely", 6), 2.0)
+        await asyncio.wait_for(c.send(b"lonely"), 2.0)
         assert w.chunks == [b"lonely"]
         await c.aclose()
 
@@ -137,7 +137,7 @@ def test_flush_on_close():
     async def main():
         w = _FakeWriter()
         c = _coalescer(w, flush_micros=5_000_000)
-        t = asyncio.create_task(c.send(b"queued", 6))
+        t = asyncio.create_task(c.send(b"queued"))
         await asyncio.sleep(0)  # frame is pending, timer far away
         assert w.chunks == []
         await c.aclose()
@@ -156,11 +156,11 @@ def test_partial_batch_failure_poisons_connection_not_loop():
         w = _FakeWriter(fail_after_drains=0)
         c = _coalescer(w, flush_micros=100)
         results = await asyncio.gather(
-            c.send(b"a", 1), c.send(b"b", 1), return_exceptions=True)
+            c.send(b"a"), c.send(b"b"), return_exceptions=True)
         assert all(isinstance(r, ConnectionResetError) for r in results)
         assert c.poisoned
         with pytest.raises(ConnectionResetError):
-            await c.send(b"c", 1)
+            await c.send(b"c")
         # the flusher died CLEANLY (no exception escaped to the loop)
         await asyncio.sleep(0.01)
         assert c._flusher is None
@@ -283,9 +283,11 @@ def test_grpc_stream_keyed_fifo_dispatch():
                 yield msgpack.packb([i, name.encode()])
 
         replies = []
-        async for item in t._serve_stream(chunks(), dispatch,
-                                          classify=classify):
+
+        async def write(item: bytes) -> None:
             replies.append(msgpack.unpackb(item))
+
+        await t._serve_stream(chunks(), write, dispatch, classify=classify)
         assert order.index("a1") < order.index("a2")
         assert order.index("b1") < order.index("b2")
         assert {r[0] for r in replies} == {0, 1, 2, 3}
@@ -312,13 +314,16 @@ def test_grpc_stream_accepts_coalesced_chunk_batches():
             yield msgpack.packb([[0, b"a"], [1, b"b"], [2, b"c"]])
 
         got = {}
-        async for item in t._serve_stream(chunks(), dispatch):
+
+        async def write(item: bytes) -> None:
             decoded = msgpack.unpackb(item)
             triples = (decoded if decoded
                        and isinstance(decoded[0], (list, tuple))
                        else [decoded])
             for call_id, status, payload in triples:
                 got[call_id] = (status, payload)
+
+        await t._serve_stream(chunks(), write, dispatch)
         assert got == {0: (0, b"ok-a"), 1: (0, b"ok-b"), 2: (0, b"ok-c")}
         assert t.dispatch_metrics["batched_messages"] == 1
 
