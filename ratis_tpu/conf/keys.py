@@ -185,8 +185,14 @@ class RaftServerConfigKeys:
             # RPC per batch (the reference's cost shape).
             COALESCING_ENABLED_KEY = "raft.server.log.appender.coalescing.enabled"
             COALESCING_ENABLED_DEFAULT = True
+            # Envelopes unanswered per (peer, loop-shard) lane.  On the
+            # sequenced path (raft.tpu.replication.window-depth > 1) this
+            # is the lane's whole window where the follower's transport
+            # takes a lane's frames in turn (gRPC), and is multiplied by
+            # the depth (cap 64) where frames are worked on as they
+            # arrive (TCP, simulated): server/replication.py:PeerSender.
             ENVELOPE_INFLIGHT_KEY = "raft.server.log.appender.envelope.inflight"
-            ENVELOPE_INFLIGHT_DEFAULT = 4  # concurrent envelopes per peer
+            ENVELOPE_INFLIGHT_DEFAULT = 4
             ENVELOPE_BYTE_LIMIT_KEY = "raft.server.log.appender.envelope.byte-limit"
             ENVELOPE_BYTE_LIMIT_DEFAULT = "8MB"
 
@@ -1034,7 +1040,10 @@ class RaftServerConfigKeys:
         # the one-frame-per-group busy latch.  1 = exactly the latched
         # (stop-and-wait per group) behavior — the deterministic fallback
         # and the scalar-reference cost shape.  Only effective with
-        # sweep=1 and appender coalescing on.
+        # sweep=1 and appender coalescing on.  The depth also multiplies
+        # the lane's envelope slots (envelope.inflight above), but only
+        # where the follower works on a lane's frames side by side: a
+        # transport that takes them in turn would only queue the rest.
         WINDOW_DEPTH_KEY = "raft.tpu.replication.window-depth"
         WINDOW_DEPTH_DEFAULT = 4
         # Follower-side lane intake: frames parked past a sequence HOLE

@@ -119,13 +119,17 @@ class PeerSender:
         self.window_depth = max(1, window_depth)
         self.sequenced = coalescing and sweep and self.window_depth > 1
         self.group_window = self.window_depth if self.sequenced else 1
-        if self.sequenced:
-            # The lane must hold enough envelope slots for the per-group
-            # window to actually fill: with the slot cap at the legacy 4,
-            # the depth knob never engages (measured: slots pinned full,
-            # occupancy 1.0, zero throughput delta across depths — the
-            # envelope window was the binding pipeline, docs/perf.md
-            # round 9).  Depth 1 keeps the exact legacy cap.
+        if self.sequenced and not server.transport.lane_frames_in_turn:
+            # Where the follower works on a lane's frames side by side the
+            # lane holds depth times the slots, so that the per-group
+            # window can fill with frames in work (docs/perf.md round 9:
+            # at the legacy 4 the depth knob never engaged).  Where its
+            # transport takes them in turn, more unanswered frames are
+            # only a queue at the follower, where they can no longer
+            # merge: the lane keeps envelope.inflight slots, and once
+            # they are full a frame carries all that gathered while the
+            # lane waited for a reply (docs/replication.md §5).  Depth 1
+            # keeps the exact legacy cap.
             self.inflight_cap = min(64,
                                     self.inflight_cap * self.window_depth)
         # lane identity + next frame sequence (sequenced mode): reset to a
@@ -138,6 +142,14 @@ class PeerSender:
             "envelopes": 0, "items": 0, "rewinds": 0,
             "windowed_rewinds": 0, "lane_rejects": 0, "lane_resets": 0,
             "win_hwm": 0, "seq_frames": 0}
+        # always-on counters (docs/tracing.md): frames cut and the items
+        # in them; drain passes that found work, and those of them that
+        # left appenders marked because every slot was taken
+        key = f"{server.peer_id}->{to}"
+        self._n_frames = TRACER.counter("replicate.frames", key)
+        self._n_items = TRACER.counter("replicate.items", key)
+        self._n_sweeps = TRACER.counter("replicate.sweeps", key)
+        self._n_window_full = TRACER.counter("replicate.window_full", key)
         self._dirty: dict[object, None] = {}  # insertion-ordered appender set
         self.refs: set = set()  # registered appenders (scheduler-managed)
         # the loop this sender (and every appender feeding it) lives on:
@@ -190,6 +202,12 @@ class PeerSender:
         """Envelopes currently awaiting their reply (window-state gauge)."""
         return self._frames_out
 
+    def _count_frame(self, n_items: int) -> None:
+        self.metrics["envelopes"] += 1
+        self.metrics["items"] += n_items
+        self._n_frames.n += 1
+        self._n_items.n += n_items
+
     def _next_frame(self) -> tuple[int, int]:
         """(lane, seq) for the envelope being dispatched — assigned in
         collect order on this sender's loop, so lane sequence == intended
@@ -227,6 +245,9 @@ class PeerSender:
         in-flight cap reached, the remaining dirty appenders keep their
         marks and the slot release re-arms the sweep."""
         server = self.server
+        if not (self._running and self._dirty):
+            return
+        self._n_sweeps.n += 1
         while self._running and self._dirty and self._slots_free > 0:
             items: list[OutItem] = []
             budget = self.envelope_byte_limit
@@ -247,9 +268,8 @@ class PeerSender:
                     LOG.exception("%s->%s collect failed for %s",
                                   server.peer_id, self.to, a)
             if not items:
-                return
-            self.metrics["envelopes"] += 1
-            self.metrics["items"] += len(items)
+                break
+            self._count_frame(len(items))
             if self.coalescing:
                 self._slots_free -= 1
                 lane, seq = self._next_frame()
@@ -267,6 +287,9 @@ class PeerSender:
                     t = asyncio.create_task(self._send_unary(it))
                     self._inflight_tasks.add(t)
                     t.add_done_callback(self._inflight_tasks.discard)
+        if self._dirty and self._slots_free <= 0:
+            # the marks that stay ride the frame a freed slot's sweep cuts
+            self._n_window_full.n += 1
 
     def _release_slot(self) -> None:
         self._frames_out = max(0, self._frames_out - 1)
@@ -309,8 +332,7 @@ class PeerSender:
             if not items:
                 self._slots.release()
                 continue
-            self.metrics["envelopes"] += 1
-            self.metrics["items"] += len(items)
+            self._count_frame(len(items))
             if self.coalescing:
                 lane, seq = self._next_frame()
                 t = asyncio.create_task(self._send(
@@ -571,16 +593,6 @@ class ReplicationScheduler:
         return s
 
     # -- window state (gauges / watchdog) -------------------------------------
-
-    @property
-    def lane_slots(self) -> int:
-        """Envelope slots per (destination, loop-shard) lane — the
-        configured inflight cap, scaled by window-depth on the sequenced
-        path (matches PeerSender's own computation; the bench's
-        window-occupancy denominator)."""
-        if self.coalescing and self.sweep and self.window_depth > 1:
-            return min(64, self.inflight_cap * self.window_depth)
-        return self.inflight_cap
 
     def frames_in_flight(self, to: Optional[RaftPeerId] = None) -> int:
         """Envelopes in flight toward ``to`` (all destinations when None),

@@ -27,6 +27,11 @@ class ServerTransport(abc.ABC):
     """One server's endpoint: receives server RPCs + client requests, and
     sends server RPCs to peers."""
 
+    #: True where this end hands a lane's sequenced append frames to the
+    #: server in turn, each only once its predecessor's reply is ready;
+    #: False where every frame that arrives is worked on beside the others.
+    lane_frames_in_turn = False
+
     @abc.abstractmethod
     async def start(self) -> None: ...
 

@@ -827,6 +827,11 @@ class GrpcServerTransport(ServerTransport):
             for t in list(tasks):
                 t.cancel()
 
+    # classify below keys a sequenced envelope by its lane, and _serve_stream
+    # starts a keyed chunk only when its predecessor's dispatch has ended:
+    # the follower's flush and the reply included
+    lane_frames_in_turn = True
+
     async def _handle_append_stream(self, request_iterator, context):
         """Server side of the per-peer append stream
         (GrpcServerProtocolService.java:46 appendEntries stream observer).
