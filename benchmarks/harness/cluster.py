@@ -146,10 +146,19 @@ class Cluster:
             RaftServerConfigKeys.set_storage_dir(self.properties,
                                                  self.storage_dir)
         self.factory = TransportFactory.get(config["transport"])
+        # a peer has one address, unless the configuration asks for a
+        # stream server beside it ("datastream": true): then each has a
+        # ``datastream_address`` on a port of its own, which is what makes
+        # ``RaftServer`` start its stream server and a client find a primary
+        streams = bool(config.get("datastream"))
         base = [RaftPeer(RaftPeerId.value_of(f"s{i}"),
-                         address=f"127.0.0.1:{_ephemeral_port()}")
+                         address=f"127.0.0.1:{_ephemeral_port()}",
+                         datastream_address=f"127.0.0.1:{_ephemeral_port()}"
+                         if streams else None)
                 for i in range(self.peers_n)]
         self.addresses = [(p.id.id, p.address) for p in base]
+        self.datastream_addresses = {p.id.id: p.datastream_address
+                                     for p in base if p.datastream_address}
         self.group_id_bytes = seeded_ids(seed, self.groups_n, "group")
         # group i's appointee is the voting peer of highest priority: server
         # i mod peers (Division.bootstrap_appointee)
@@ -275,7 +284,30 @@ class Cluster:
         out["elections"] = sum(
             d.election_metrics.election_count.count
             for s in self.servers for d in s.divisions.values())
+        out["reads"] = self._read_counters()
+        # CPU seconds of the calling thread, which is the servers' loop's
+        out["loop_cpu_s"] = time.thread_time()
         return out
+
+    def _read_counters(self) -> Optional[dict]:
+        """The read path's own counts, where the program keeps them: every
+        division's ``readRequestLatency`` timer (reads served, and their
+        seconds from the request's arrival at its division to its reply)
+        and every server's batched readIndex scheduler (confirmation sweeps
+        fired, group confirmations sent to followers).  None in a program
+        that keeps none of them."""
+        try:
+            timers = [d.metrics.read_timer for s in self.servers
+                      for d in s.divisions.values()]
+            batches = [s.serving.read_batch for s in self.servers]
+            return {
+                "requests": sum(t.count for t in timers),
+                "total_s": sum(t.mean_s * t.count for t in timers),
+                "sweeps": sum(b.sweeps for b in batches if b is not None),
+                "confirms_sent": sum(sum(b.confirm_sent.values())
+                                     for b in batches if b is not None)}
+        except AttributeError:
+            return None
 
     def leader_terms(self) -> list[int]:
         return [self.servers[self.leader_server(i)]

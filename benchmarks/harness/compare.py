@@ -49,6 +49,23 @@ def durable_short(ref, log_dirs: Sequence[Sequence[str]],
     return short
 
 
+WINDOW_PART = 1     # of the run's parts: warm-up, window, settle
+
+
+def window_entries(answers: dict, requests: dict, groups: int) -> list[int]:
+    """Log entries the window added to each group.  The reference says, under
+    ``entries_per_part`` of what its ``judge_answers`` returns (a read adds
+    none, a request may add several); one that does not say holds every
+    answered request to be one entry."""
+    per_part = answers.get("entries_per_part")
+    if per_part is not None:
+        return list(per_part[WINDOW_PART])
+    out = [0] * groups
+    for g, answer in zip(requests["group"], requests["answer"]):
+        out[g] += answer is not None
+    return out
+
+
 def check_device(ref, snapshots: Sequence[dict], leader_server: Sequence[int],
                  leader_slot: Sequence[int],
                  commit_baseline: Optional[Sequence[int]],

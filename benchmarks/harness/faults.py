@@ -48,6 +48,18 @@ class AlteredAnswerCounter(CounterStateMachine):
         return reply
 
 
+class StaleReadCounter(CounterStateMachine):
+    """The fault "a read that is not linearizable": ``query`` answers the
+    count as it was before the group's last INCREMENT, so a GET of a group
+    at rest reads one less than what was acknowledged before it was sent.
+    Writes answer right, and every replica holds the count."""
+
+    async def query(self, request):
+        from ratis_tpu.protocol.message import Message
+        reply = await super().query(request)      # (refuses what is no GET)
+        return Message.value_of(str(max(0, int(bytes(reply.content)) - 1)))
+
+
 def sm_factory_for(name: str, peers: int):
     """``(server index, group index) -> state machine`` of a control or fault
     that swaps the state machine; None for the others."""
@@ -56,6 +68,8 @@ def sm_factory_for(name: str, peers: int):
             lossy=server != group % peers)
     if name == "altered-answer":
         return lambda server, group: AlteredAnswerCounter()
+    if name == "stale-read":
+        return lambda server, group: StaleReadCounter()
     return None
 
 

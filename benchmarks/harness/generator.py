@@ -185,7 +185,11 @@ def load_op(checkout: str, name: str):
 def build_senders(spec: dict):
     """One RaftClient per group on one shared client transport; returns the
     transport and, per group, the sender that the traffic's operation makes
-    of that client."""
+    of that client, and the sender of the warm-up and settle rounds: the
+    same one, unless the traffic names a ``round_op`` of its own for them
+    (a mix with reads keeps its rounds writes).  An operation gets the
+    traffic file's parameters and ``group_index``, the group's place in the
+    deployment, which no seed changes."""
     import ratis_tpu.transport.tcp  # noqa: F401  (registers TCP)
     from ratis_tpu.client import RaftClient
     from ratis_tpu.conf import RaftProperties
@@ -200,15 +204,19 @@ def build_senders(spec: dict):
         props.set(k, str(v))
     transport = TransportFactory.get(spec["transport"]) \
         .new_client_transport(props)
-    peers = [RaftPeer(RaftPeerId.value_of(pid), address=addr)
+    streams = spec.get("datastream") or {}
+    peers = [RaftPeer(RaftPeerId.value_of(pid), address=addr,
+                      datastream_address=streams.get(pid))
              for pid, addr in spec["peers"]]
     retry = RetryPolicies.retry_up_to_maximum_count_with_fixed_sleep(
         int(spec["client"]["retry_count"]), spec["client"]["retry_sleep"])
     traffic = spec["traffic"]
     op = load_op(spec["checkout"], traffic["op"])
-    senders = []
-    for ghex, chex, lead in zip(spec["groups"], spec["client_ids"],
-                                spec["leaders"]):
+    round_op = (load_op(spec["checkout"], traffic["round_op"])
+                if "round_op" in traffic else None)
+    senders, round_senders = [], []
+    for i, (ghex, chex, lead) in enumerate(zip(
+            spec["groups"], spec["client_ids"], spec["leaders"])):
         client = (RaftClient.builder()
                   .set_raft_group(RaftGroup.value_of(
                       RaftGroupId.value_of(bytes.fromhex(ghex)), peers))
@@ -216,8 +224,11 @@ def build_senders(spec: dict):
                   .set_leader_id(peers[lead].id)
                   .set_transport(transport).set_retry_policy(retry)
                   .set_properties(props).build())
-        senders.append(op.sender(client, traffic))
-    return transport, senders
+        params = dict(traffic, group_index=i)
+        senders.append(op.sender(client, params))
+        round_senders.append(senders[-1] if round_op is None
+                             else round_op.sender(client, params))
+    return transport, senders, round_senders
 
 
 def say(prefix: str, obj: dict) -> None:
@@ -246,14 +257,15 @@ async def child_main(spec: dict) -> None:
     traffic = spec["traffic"]
     groups = len(spec["groups"])
     seed, seconds = int(spec["seed"]), float(spec["seconds"])
-    transport, senders = build_senders(spec)
+    transport, senders, round_senders = build_senders(spec)
     in_flight = int(traffic.get("warmup_in_flight", 64))
 
     # warm-up: the same number of writes to every group, through the same
     # clients — connections, windows and retry caches exist before the window
     t_w = time.monotonic()
-    warm = await rounds(senders, int(traffic.get("warmup_writes_per_group",
-                                                 1)), in_flight)
+    warm = await rounds(round_senders,
+                        int(traffic.get("warmup_writes_per_group", 1)),
+                        in_flight)
     if traffic["loop"] == "open":
         due, targets = open_schedule(traffic, groups, seconds, seed)
     else:
@@ -271,6 +283,11 @@ async def child_main(spec: dict) -> None:
         raise SystemExit(f"generator: expected GO, got {line!r}")
     t0 = float(arg)  # CLOCK_MONOTONIC, shared with the parent
     drain_s = float(traffic.get("drain_s", 60))
+    # this process's CPU seconds over the window: a generator that needs
+    # most of one core is what a sweep's knee may be
+    cpu_go, cpu_close = time.process_time(), []
+    loop.call_later(max(0.0, t0 + seconds - time.monotonic()),
+                    lambda: cpu_close.append(time.process_time()))
     if traffic["loop"] == "open":
         rec = await run_open(senders, due, targets, t0, drain_s)
     elif traffic["loop"] == "closed":
@@ -279,6 +296,8 @@ async def child_main(spec: dict) -> None:
     else:
         raise ValueError(f"unknown loop {traffic['loop']!r}")
     say("GENDONE", {"requests": rec.as_dict(t0), "t0": t0,
+                    "cpu_s_in_window": (cpu_close or [time.process_time()])[0]
+                    - cpu_go,
                     "jax_imported": "jax" in sys.modules})
     # settle, when the parent asks for it (it has looked at the device
     # first): one more write to every group.  A follower learns that an
@@ -289,7 +308,7 @@ async def child_main(spec: dict) -> None:
     line = await loop.run_in_executor(None, sys.stdin.readline)
     if line.strip() == "SETTLE":
         settle = await rounds(
-            senders, int(traffic.get("settle_writes_per_group", 0)),
+            round_senders, int(traffic.get("settle_writes_per_group", 0)),
             in_flight)
         say("GENSETTLED", {"settle": settle.as_dict(t0)})
     await transport.close()

@@ -31,6 +31,7 @@ from benchmarks.harness import trace_reduce
 
 SPAN_PREFIX = "ratis:"
 BETWEEN = "(between ratis: spans)"
+LOOP_SELECT = "ratis:loop.select"   # only an event loop's thread holds it
 
 
 def load(path: str) -> dict:
@@ -96,8 +97,11 @@ def leaf_segments(spans: list, t0: float, t1: float) -> list:
 
 
 def split_idle(parsed: dict) -> dict:
-    """The table: the device's idle seconds by label, on the host thread
-    that spent most time inside ``ratis:`` spans."""
+    """The table: the device's idle seconds by label, on the servers' event
+    loop's thread: the one that holds ``ratis:loop.select`` (the busiest of
+    them where loops are sharded), and only where no thread holds one the
+    thread with most time inside ``ratis:`` spans, which a log worker's
+    thread can win where fsyncs are slow."""
     threads = parsed["threads"]
     if not threads:
         return {"error": "no ratis: span in the trace: the program had no "
@@ -106,9 +110,12 @@ def split_idle(parsed: dict) -> dict:
     t0, t1 = parsed["window"] or (min(s for s, _ in every),
                                   max(e for _, e in every))
     gaps = trace_reduce._gaps(parsed["device"], t0, t1)
-    in_spans = [trace_reduce.union_ns([(s, e) for _, s, e in spans])
-                for spans in threads]
-    main = max(range(len(threads)), key=in_spans.__getitem__)
+    at_work = [trace_reduce.union_ns([(s, e) for name, s, e in spans
+                                      if name != LOOP_SELECT])
+               for spans in threads]
+    loops = [i for i, spans in enumerate(threads)
+             if any(name == LOOP_SELECT for name, _, _ in spans)]
+    main = max(loops or range(len(threads)), key=at_work.__getitem__)
     by_label: dict[str, float] = {}
     g = 0
     for s, e, label in leaf_segments(threads[main], t0, t1):

@@ -1,6 +1,8 @@
 """Log: fsyncs issued by every LogWorker during the window per acknowledged
-write (all replicas together).  Durable cells only: without a durable log
-there is nothing to read."""
+operation (all replicas together): three a write in a durable cell, none a
+read, so in a cell that mixes them it reads three times the share of
+writes.  Durable configurations only: without a durable log there is
+nothing to read."""
 
 
 def read(ctx):

@@ -1,7 +1,9 @@
 """Log: median of ``server.flush_wait`` over the traced requests: from the
 leader's in-memory append to its own log worker's flush seen on the loop
-(division.py:_write_impl -> _on_log_flush): queue wait, write, fsync and the
-hop back to the loop."""
+(division.py:_write_impl -> _on_log_flush, which the worker's one call back
+to the loop a batch runs: segmented.py:LogWorker._completed ->
+log/base.py:_on_record_flushed): queue wait, write, fsync and the hop back
+to the loop."""
 from benchmarks.harness.stats import percentile
 
 

@@ -1,5 +1,5 @@
 """Log: how long ONE fsync takes, from the ``log.fsync`` work spans on the
-log worker's thread (segmented.py:LogWorker._run/_do_io): the spans' summed
+log worker's thread (segmented.py:LogWorker._do_io): the spans' summed
 duration over their summed tag (the distinct files of each batch, one fsync
 each)."""
 

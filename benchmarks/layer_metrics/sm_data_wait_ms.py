@@ -1,8 +1,9 @@
-"""State machine data: median of ``server.data_wait``: how long the log
-worker held a batch back for the data_write of one of its records before
-writing and fsyncing it (segmented.py:LogWorker._after_gates): what the data
-before the record added to ``server.flush_wait``; 0 for a record whose data
-came first.  It leaves 0 when a log's queue gets shorter than a data write
+"""State machine data: median of ``server.data_wait``, one row a gated
+record, on the log worker's own thread (segmented.py:LogWorker._write): from
+when the thread could have taken the record (it met the record's shut gate
+at the head of its queue) to when it saw the record's data_write done: what
+the data before the record added to ``server.flush_wait``; 0 for a record
+whose data came first.  It leaves 0 when a log's queue gets shorter than a data write
 (fsyncs merged, a faster log device) or the data writes slower."""
 from benchmarks.harness.stats import percentile
 

@@ -1,7 +1,8 @@
-"""Wire: bytes the servers' process wrote to its sockets during the trace
-session per acknowledged write: the counter ``wire.bytes``
-(transport/coalesce.py:WriteCoalescer) over the window's acknowledged
-writes."""
+"""Wire: bytes the servers' process handed to the socket layer during the
+trace session per acknowledged operation: the counter ``wire.bytes``
+(TCP: transport/tcp.py:_FramedProtocol._flush; gRPC:
+transport/grpc.py:_WireCount.wrote) over the window's acknowledged
+operations."""
 
 
 def read(ctx):

@@ -1,8 +1,10 @@
-"""Wire: frames the servers' process wrote to its sockets during the trace
-session per acknowledged write: the counter ``wire.frames``
-(transport/coalesce.py:WriteCoalescer) over the window's acknowledged
-writes.  Append frames, their replies and the clients' replies; the
-generator's requests are written by its own process and not counted."""
+"""Wire: rpc frames the servers' process handed to the socket layer during
+the trace session per acknowledged operation: the counter ``wire.frames``
+(TCP: transport/tcp.py:_FramedProtocol._flush, after a loop pass's one
+write; gRPC: transport/grpc.py:_WireCount.wrote, once grpc.aio has taken a
+message) over the window's acknowledged operations.  Append frames, their
+replies and the clients' replies; the generator's requests are written by
+its own process and not counted."""
 
 
 def read(ctx):

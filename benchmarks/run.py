@@ -47,6 +47,9 @@ DEADLINE_S = 345          # a run exits within 360 s, whatever happens
 REPLICA_WAIT_S = 60.0     # an answer that comes late is late, not wrong
 READY_GRACE_S = 30.0      # for a leadership that an election took to return
 PIPE_LIMIT = 1 << 28      # the generator's result is one long line
+# the configuration's keys that a client reads: the generator's gets them all
+CLIENT_KEY_PREFIXES = ("raft.tpu.tcp.", "raft.grpc.", "raft.tpu.grpc.",
+                       "raft.client.", "raft.netty.")
 
 
 def say(msg: str) -> None:
@@ -177,8 +180,9 @@ async def drive_window(cluster, traffic: dict, seed: int, seconds: float,
         "traffic": traffic, "transport": config["transport"],
         "client": config["client"],
         "properties": {k: v for k, v in cluster.properties.items()
-                       if k.startswith("raft.tpu.tcp.")},
+                       if k.startswith(CLIENT_KEY_PREFIXES)},
         "peers": cluster.addresses,
+        "datastream": cluster.datastream_addresses,
         "groups": [b.hex() for b in cluster.group_id_bytes],
         "client_ids": [b.hex() for b in seeded_ids(seed, cluster.groups_n,
                                                    "client")],
@@ -244,6 +248,7 @@ async def drive_window(cluster, traffic: dict, seed: int, seconds: float,
             "on_drained": drained,
             "t0": t0, "c0": c0, "c1": c1,
             "generator_imported_jax": done["jax_imported"],
+            "generator_cpu_s": done["cpu_s_in_window"],
             "compiled_in_window": compiled, "memory_peak_bytes": peak,
             "lag_ms": probe.overshoots_ms(t0, t0 + seconds),
             "on_ready": extra}
@@ -385,9 +390,8 @@ async def run_cell(args, resolved: dict, manifest: dict, device: dict,
                    "submitted": submitted[g], "unsettled": unsettled[g]}
                   for g in short[:8]]
     snaps, base = w["on_drained"]["snaps"], w["on_ready"]
-    in_window = [0] * cluster.groups_n
-    for g, answer in zip(w["requests"]["group"], w["requests"]["answer"]):
-        in_window[g] += answer is not None
+    in_window = compare.window_entries(answers, w["requests"],
+                                       cluster.groups_n)
     dev = compare.check_device(
         ref, snaps, [cluster.leader_server(g)
                      for g in range(cluster.groups_n)],
@@ -410,6 +414,9 @@ async def run_cell(args, resolved: dict, manifest: dict, device: dict,
             [[cluster.replica_log_dir(s, g) for s in range(cluster.peers_n)]
              for g in range(cluster.groups_n)], acked, need, needle)
         numbers["groups_short_of_durable"] = (len(lost), 0)
+    # whatever further numbers the reference judged by itself, each exact
+    numbers.update((name, (value, 0))
+                   for name, value in answers.get("compared", {}).items())
     correct, compared = compare.verdict(numbers)
 
     # ---- metrics
@@ -423,6 +430,7 @@ async def run_cell(args, resolved: dict, manifest: dict, device: dict,
             "end_to_end": {k: v for k, v in e2e.items()
                            if k.startswith("commit")},
             "answers_compared": answers["answers_compared"],
+            "reads_compared": answers.get("reads_compared"),
             "replica_wait_s": replica_wait_s, "groups_short": short_seen,
             "elections_at_end": cluster.counters()["elections"],
             "device_rows_compared": dev["device_rows_compared"],

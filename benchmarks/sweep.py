@@ -39,6 +39,7 @@ async def sweep(args, resolved: dict, compiles) -> list[dict]:
             return sum(1 for s, a in zip(r["sent"], r["acked"])
                        if s <= at and (a is None or a > at))
         late = [(s - d) * 1e3 for s, d in zip(r["sent"], r["due"])]
+        wall = w["c1"]["t"] - w["c0"]["t"]
         steps.append({
             args.key: value, "commits_per_s": e2e["commits_per_s"],
             "p50_ms": e2e["commit_p50_ms"], "p99_ms": e2e["commit_p99_ms"],
@@ -48,7 +49,12 @@ async def sweep(args, resolved: dict, compiles) -> list[dict]:
             "in_flight_at_close": in_flight(args.seconds),
             "gen_late_p99_ms": percentile(late, 0.99),
             "elections": w["c1"]["elections"] - w["c0"]["elections"],
-            "lag_p99_ms": percentile(w["lag_ms"], 0.99)})
+            "lag_p99_ms": percentile(w["lag_ms"], 0.99),
+            # whose knee it is: the CPU share of the servers' loop thread
+            # and of the generator's process over the window
+            "loop_cpu_pct": 100 * (w["c1"]["loop_cpu_s"]
+                                   - w["c0"]["loop_cpu_s"]) / wall,
+            "gen_cpu_pct": 100 * w["generator_cpu_s"] / args.seconds})
         bench.say(f"sweep {steps[-1]}")
     return steps
 

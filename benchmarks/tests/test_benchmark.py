@@ -68,7 +68,12 @@ def test_every_name_resolves_to_its_files():
     for w in m["workloads"]:
         r = bench_run.resolve_cell(m, w["name"])
         assert r["traffic"]["name"] == w["traffic"]
-        assert r["reference"] == "counter"
+        # the plain reference is the configuration's, unless the traffic
+        # brings operations that need one of their own
+        assert r["reference"] == r["traffic"].get("reference",
+                                                  r["config"]["reference"])
+        assert os.path.exists(os.path.join(
+            ROOT, "benchmarks", "reference", r["reference"] + ".py"))
         assert callable(generator.load_op(ROOT, r["traffic"]["op"]).sender)
         on_file = bench_run.load_json(os.path.join(
             ROOT, "benchmarks", "workloads", w["name"] + ".json"))

@@ -37,26 +37,28 @@ def rehearse(cell, *extra, timeout=180):
 def test_every_name_of_the_cell_resolves_to_its_files():
     m = bench_run.load_manifest()
     r = bench_run.resolve_cell(m, CELL)
-    assert r["cell"] == m["workloads"][-1]          # appended, not inserted
+    assert r["cell"] in m["workloads"]
     assert (r["cell"]["config"], r["cell"]["traffic"], r["cell"]["chips"]) \
         == ("ratis-3x1k-grpc", "write-closed", 1)
     assert bench_run.load_json(os.path.join(
         ROOT, "benchmarks", "workloads", CELL + ".json")) \
         == {k: r["cell"][k] for k in ("config", "traffic", "chips", "why")}
-    entry = m["configs"][-1]
+    entry = {c["name"]: c for c in m["configs"]}["ratis-3x1k-grpc"]
     assert entry["name"] == r["config"]["name"] == "ratis-3x1k-grpc"
     assert entry["file"] == "benchmarks/configs/ratis-3x1k-grpc.json"
     assert entry["source"] == r["config"]["source"]
     assert len(entry["source"]) <= 200 and len(r["cell"]["why"]) <= 200
     assert entry["reduced"] == r["config"]["reduced"] == ["groups",
                                                           "processes"]
-    assert r["reference"] == "counter"
+    assert r["reference"] == r["config"]["reference"] == "counter"
     assert os.path.exists(os.path.join(ROOT, "benchmarks", "reference",
                                        "counter.py"))
     assert r["traffic"]["name"] == "write-closed"
     assert callable(generator.load_op(ROOT, r["traffic"]["op"]).sender)
     listed = [x for x in m["per_layer"] if x.get("workloads") == [CELL]]
-    assert listed == m["per_layer"][-2:]            # appended
+    # there, in the order they were appended in (later PRs append behind)
+    assert [x["name"] for x in listed] == ["grpc_messages_per_commit",
+                                           "grpc_chunks_per_message"]
     assert {x["name"] for x in listed} == NEW_METRICS
     for x in listed:
         assert callable(bench_run.load_reader(x["name"]))
@@ -120,14 +122,17 @@ def test_a_traced_rehearsal_reports_the_new_metrics_and_the_whole_wire():
     # two acks, the client's request and its reply, written or read here
     assert got["grpc_chunks_per_message"] == 1.0
     assert got["grpc_messages_per_commit"] >= 2 * got["wire_frames_per_commit"] - 1
-    # the same commits over TCP: every reply is counted under gRPC too, and
-    # a gRPC message carries no fewer bytes than a TCP frame of the same rpc
+    # the same commits over TCP report the shared wire metrics and not
+    # gRPC's own.  (Neither frames nor bytes are held to each other: since
+    # PR 34 a gRPC lane keeps 4 frames unanswered where TCP's keeps 16, so it
+    # sends fewer, fuller frames and fewer envelope headers a commit: 1.89
+    # against 2.26 frames at 16 groups on the CPU, 1.94 against 1.79 at
+    # 1,024 on the chip.)
     q, pair = rehearse(PAIR, "--trace", "1")
     assert q.returncode == 0, q.stderr[-2000:]
     tcp = {k: v["value"] for k, v in pair["metrics"].items()}
     assert NEW_METRICS.isdisjoint(tcp)
-    assert got["wire_bytes_per_commit"] >= tcp["wire_bytes_per_commit"]
-    assert got["wire_frames_per_commit"] >= tcp["wire_frames_per_commit"]
+    assert tcp["wire_frames_per_commit"] > 0 < got["wire_frames_per_commit"]
     for c in result["compared"].values():
         assert c["value"] <= c["limit"] == 0
 

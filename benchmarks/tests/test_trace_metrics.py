@@ -34,9 +34,9 @@ SESSION_METRICS = {
 ENGINE = {"engine_pack_ms", "engine_fetch_ms"}
 
 
-def test_the_eleven_are_appended_and_none_reads_the_device_trace():
+def test_the_eleven_are_there_in_order_and_none_reads_the_device_trace():
     m = bench_run.load_manifest()
-    tail = m["per_layer"][-len(SESSION_METRICS):]
+    tail = [x for x in m["per_layer"] if x["name"] in SESSION_METRICS]
     assert [x["name"] for x in tail] == list(SESSION_METRICS)
     for x in tail:
         assert x["layer"] == SESSION_METRICS[x["name"]]
@@ -244,5 +244,12 @@ def test_idle_is_split_by_the_innermost_span():
     assert table["under_select_or_named_span_pct"] == \
         pytest.approx(100 * 70 / 90)
     assert table["spans_on_other_threads_s"] == {"ratis:log.fsync": 20e-9}
+    # the loop is the thread that holds ratis:loop.select, also where a log
+    # worker's thread spent longer inside spans (slow fsyncs)
+    slow = idle_by_span.split_idle({
+        "device": [(75, 85)], "window": (0, 100), "clock_marks": 1,
+        "threads": [[("ratis:log.fsync", 0, 99)], spans]})
+    assert slow["idle_by_label_s"] == table["idle_by_label_s"]
+    assert slow["spans_on_other_threads_s"] == {"ratis:log.fsync": 99e-9}
     assert "error" in idle_by_span.split_idle(
         {"device": [], "window": None, "threads": [], "clock_marks": 0})
