@@ -47,7 +47,7 @@ from ratis_tpu.protocol.message import Message
 from ratis_tpu.server.statemachine import (BaseStateMachine, DataChannel,
                                            DataStream, TransactionContext)
 from ratis_tpu.trace.tracer import (STAGE_DATA_FSYNC, STAGE_DATA_WRITE,
-                                    TRACER)
+                                    STAGE_STREAM_FORCE, TRACER)
 
 # The writers of every FileStore of the process (the reference runs a
 # writer executor per state machine; thousands of co-hosted groups share
@@ -76,14 +76,25 @@ class FileChunkChannel(DataChannel):
     def __init__(self, tmp_path: pathlib.Path) -> None:
         self.tmp_path = tmp_path
         self._file = open(tmp_path, "wb")
+        self._unforced = 0      # bytes written since the last force
 
     async def write(self, data: bytes) -> int:
-        return await asyncio.to_thread(self._file.write, data)
+        written = await asyncio.to_thread(self._file.write, data)
+        self._unforced += written
+        return written
 
     async def force(self, metadata: bool = False) -> None:
+        nbytes, self._unforced = self._unforced, 0
+
         def _sync():
-            self._file.flush()
-            os.fsync(self._file.fileno())
+            span = TRACER.begin(STAGE_STREAM_FORCE) if TRACER.enabled \
+                else None
+            try:
+                self._file.flush()
+                os.fsync(self._file.fileno())
+            finally:
+                if span is not None:
+                    TRACER.end(span, tag=nbytes)
         await asyncio.to_thread(_sync)
 
     async def close(self) -> None:
@@ -158,6 +169,7 @@ class FileStoreStateMachine(BaseStateMachine):
         self._tmp_holder: Optional[tempfile.TemporaryDirectory] = None
         self.files: Dict[str, int] = {}  # path -> size (committed metadata)
         self.writes_committed = 0        # WRITE transactions applied
+        self.streams_committed = 0       # streams linked and applied
         self._open: Dict[str, _UnderConstruction] = {}
         # log index -> (path, offset, the write's future) of the writes
         # data_write has taken in this life and apply has not reached: what
@@ -213,11 +225,13 @@ class FileStoreStateMachine(BaseStateMachine):
                 raise ValueError(f"not a transaction op: {op!r}")
             path = cmd["path"]
             _safe_relpath(path)
+            if op != "delete" and path in self.files:
+                # (a stream's bytes are linked over its path at apply: one
+                # to a committed path would replace the file unseen)
+                raise ValueError(f"{path!r} is closed")
             if op == "write":
                 data = cmd["data"]
                 offset = int(cmd.get("offset", 0))
-                if path in self.files:
-                    raise ValueError(f"{path!r} is closed")
                 uc = self._open.get(path)
                 expected = uc.appended if uc is not None else 0
                 if offset != expected:
@@ -250,6 +264,7 @@ class FileStoreStateMachine(BaseStateMachine):
             if target.exists():
                 size = target.stat().st_size
                 self.files[path] = size
+                self.streams_committed += 1
                 reply = {"ok": True, "size": size}
             else:
                 reply = {"ok": False, "error": "data not streamed here"}

@@ -23,6 +23,9 @@ from ratis_tpu.protocol.exceptions import DataStreamException
 from ratis_tpu.protocol.ids import RaftPeerId
 from ratis_tpu.protocol.requests import RaftClientRequest, RequestType
 from ratis_tpu.protocol.routing import RoutingTable
+from ratis_tpu.trace.tracer import (STAGE_RESPOND, STAGE_STREAM_CLOSE,
+                                    STAGE_STREAM_HEADER, STAGE_STREAM_PACKET,
+                                    STAGE_STREAM_WRITE, TRACER)
 from ratis_tpu.transport.datastream import (FLAG_CLOSE, FLAG_PRIMARY,
                                             FLAG_SUCCESS, FLAG_SYNC,
                                             KIND_DATA, KIND_HEADER,
@@ -34,6 +37,16 @@ from ratis_tpu.transport.datastream import (FLAG_CLOSE, FLAG_PRIMARY,
 LOG = logging.getLogger(__name__)
 
 LinkKey = Tuple[bytes, int]  # (clientId, callId) of the header request
+
+# counters (docs/tracing.md): streams finished at their primary; DATA packets
+# and their bytes written to a local channel, by the peer's part in the
+# stream; connections opened to a successor (those accepted are counted by
+# the transport)
+_STREAMS = TRACER.counter("stream.streams")
+_PARTS = ((True, "primary"), (False, "successor"))    # StreamInfo.is_primary
+_PACKETS = {p: TRACER.counter("stream.packets", key) for p, key in _PARTS}
+_BYTES = {p: TRACER.counter("stream.bytes", key) for p, key in _PARTS}
+_CONNECTS_OPENED = TRACER.counter("stream.connects", "opened")
 
 
 def _consume_result(fut: asyncio.Future) -> None:
@@ -76,6 +89,7 @@ class _RemoteStream:
 
     async def connect(self) -> None:
         await self.conn.connect()
+        _CONNECTS_OPENED.n += 1
 
     async def forward(self, packet: Packet) -> Packet:
         """Forward and await the successor's ack."""
@@ -171,14 +185,15 @@ class DataStreamManagement:
         handling is just an error reply — stay on the accept loop.  The
         read loop awaits this per packet, so per-stream packet order is
         preserved across the hop."""
+        t0 = TRACER.now() if TRACER.enabled else 0   # off the socket
         await self._expire_idle()
         if self._pin_shards:
             shard = self._route_shard(packet)
             if shard is not None:
                 await self.server.shards.run_on(
-                    shard, self._handle_packet(packet, conn))
+                    shard, self._handle_packet(packet, conn, t0))
                 return
-        await self._handle_packet(packet, conn)
+        await self._handle_packet(packet, conn, t0)
 
     def _route_shard(self, packet: Packet) -> Optional[int]:
         """Loop shard owning ``packet``'s stream: registered at HEADER
@@ -195,11 +210,12 @@ class DataStreamManagement:
             return shard
         return self._stream_shards.get(packet.stream_id)
 
-    async def _handle_packet(self, packet: Packet,
-                             conn: PeerConnection) -> None:
+    async def _handle_packet(self, packet: Packet, conn: PeerConnection,
+                             t0: int = 0) -> None:
         """The real packet handler (on the stream's pinned loop when
-        sharded).  HEADER and CLOSE are handled fully inline (once per
-        stream).  DATA is PIPELINED: the ordered work — offset check,
+        sharded); ``t0`` is when the packet came off the socket, on the
+        tracer's clock (0: no session).  HEADER and CLOSE are handled fully
+        inline (once per stream).  DATA is PIPELINED: the ordered work — offset check,
         local channel write, putting the forward copies on the successor
         sockets — happens inline (so stream order is the read-loop
         order), but awaiting the successor acks and answering the client
@@ -211,7 +227,7 @@ class DataStreamManagement:
         (DataStreamManagement.java:85 writeTo/thenCombine chains)."""
         self.metrics.num_requests.inc()
         with self.metrics.request_timer.time():
-            reply_data = b""
+            reply_data, tid = b"", 0
             try:
                 if packet.kind == KIND_HEADER:
                     is_new = packet.stream_id not in self._streams
@@ -220,7 +236,7 @@ class DataStreamManagement:
                         self.metrics.streams_started.inc()
                 elif packet.kind == KIND_DATA:
                     if not packet.is_close:
-                        await self._on_data_pipelined(packet, conn)
+                        await self._on_data_pipelined(packet, conn, t0)
                         return  # completion task acks the client
                     await self._on_close_data(packet)
                 else:
@@ -228,7 +244,7 @@ class DataStreamManagement:
                 if packet.is_close:
                     # inside the try: a failing close must still answer the
                     # client (failure reply) and count as failed
-                    reply_data = await self._finish(packet)
+                    reply_data, tid = await self._finish(packet, t0)
                     self.metrics.streams_closed.inc()
             except Exception as e:
                 LOG.warning("datastream packet failed: %s", e)
@@ -239,6 +255,14 @@ class DataStreamManagement:
                 return
             await conn.send(Packet(KIND_REPLY, packet.stream_id, packet.offset,
                                    packet.flags | FLAG_SUCCESS, reply_data))
+            if packet.kind == KIND_HEADER:
+                TRACER.interval(STAGE_STREAM_HEADER, t0)
+            elif tid:
+                # the stream's raft request, answered in the CLOSE's ack:
+                # what a transport's respond span is to a client request
+                egress = TRACER.pop_egress(tid)
+                if egress:
+                    TRACER.record(tid, STAGE_RESPOND, egress, TRACER.now())
 
     async def _on_header(self, packet: Packet) -> None:
         request, routing = decode_header(packet.data)
@@ -284,8 +308,20 @@ class DataStreamManagement:
             raise DataStreamException(f"unknown stream {packet.stream_id}")
         return info
 
-    async def _on_data_pipelined(self, packet: Packet,
-                                 conn: PeerConnection) -> None:
+    async def _write_local(self, info: StreamInfo, data: bytes) -> None:
+        """A packet's bytes into the stream's local channel, counted."""
+        t0 = TRACER.now() if TRACER.enabled else 0
+        written = await info.local.channel.write(data)
+        TRACER.interval(STAGE_STREAM_WRITE, t0, len(data))
+        if written != len(data):
+            raise DataStreamException(f"short write {written}/{len(data)}")
+        info.next_offset += len(data)
+        info.bytes_written += len(data)
+        _PACKETS[info.is_primary].n += 1
+        _BYTES[info.is_primary].n += len(data)
+
+    async def _on_data_pipelined(self, packet: Packet, conn: PeerConnection,
+                                 t0: int = 0) -> None:
         """Ordered phase of a (non-close) DATA packet: validate, write the
         local channel, put the forward copies on the wire; then hand the
         ack-collection to a completion task so the read loop pipelines."""
@@ -299,10 +335,7 @@ class DataStreamManagement:
                 f"{packet.offset}, expected {info.next_offset}")
         ack_futs: list = []
         try:
-            written = await info.local.channel.write(packet.data)
-            if written != len(packet.data):
-                raise DataStreamException(
-                    f"short write {written}/{len(packet.data)}")
+            await self._write_local(info, packet.data)
             # sends happen NOW, in read-loop order (per-successor FIFO);
             # only the ack futures move to the completion task
             for r in info.remotes:
@@ -322,8 +355,6 @@ class DataStreamManagement:
                 fut.add_done_callback(_consume_result)
                 fut.cancel()
             raise
-        info.next_offset += len(packet.data)
-        info.bytes_written += len(packet.data)
         if packet.is_sync:
             await info.local.channel.force()
 
@@ -350,6 +381,8 @@ class DataStreamManagement:
             await conn.send(Packet(KIND_REPLY, packet.stream_id,
                                    packet.offset,
                                    packet.flags | FLAG_SUCCESS, b""))
+            TRACER.interval(STAGE_STREAM_PACKET, t0, len(packet.data)
+                            if info.is_primary else -len(packet.data))
 
         t = asyncio.create_task(complete())
         info.pending.add(t)
@@ -371,19 +404,16 @@ class DataStreamManagement:
                 f"stream {packet.stream_id}: out-of-order close offset "
                 f"{packet.offset}, expected {info.next_offset}")
         if packet.data:
-            written = await info.local.channel.write(packet.data)
-            if written != len(packet.data):
-                raise DataStreamException(
-                    f"short write {written}/{len(packet.data)}")
-            info.next_offset += len(packet.data)
-            info.bytes_written += len(packet.data)
+            await self._write_local(info, packet.data)
             self.metrics.bytes_written.inc(len(packet.data))
         await asyncio.gather(*(r.forward(packet) for r in info.remotes))
         await info.local.channel.force()
 
-    async def _finish(self, packet: Packet) -> bytes:
+    async def _finish(self, packet: Packet, t0: int = 0
+                      ) -> Tuple[bytes, int]:
         """CLOSE handling after the data landed everywhere: primary submits
-        the raft write; reply bytes ride back in the CLOSE ack."""
+        the raft write; reply bytes ride back in the CLOSE ack.  Returns
+        them and the raft request's trace id (0: not traced)."""
         info = self._info_for(packet)
         info.closed = True
         self._streams.pop(packet.stream_id, None)
@@ -394,12 +424,18 @@ class DataStreamManagement:
         link_key = (info.request.client_id.to_bytes(), info.request.call_id)
         self._links[link_key] = (info, time.monotonic())
         if not info.is_primary:
-            return b""
+            return b"", 0
+        TRACER.interval(STAGE_STREAM_CLOSE, t0)
+        # the raft request a stream ends in arrives here: traced from here
+        # like a client request from its transport's ingress
+        tid = TRACER.ingress(info.request) if TRACER.enabled else 0
         reply = await self.server.submit_data_stream_request(info.request)
-        if not reply.success:
+        if reply.success:
+            _STREAMS.n += 1
+        else:
             self._links.pop(link_key, None)
             await self._cleanup(info)
-        return reply.to_bytes()
+        return reply.to_bytes(), tid
 
     async def _cleanup(self, info: StreamInfo) -> None:
         # a shard-pinned stream's tasks and successor connections are

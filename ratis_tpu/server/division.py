@@ -62,7 +62,8 @@ from ratis_tpu.trace.tracer import (STAGE_APPEND, STAGE_APPLY,
                                     STAGE_APPLY_QUEUE, STAGE_FANOUT,
                                     STAGE_FLUSH_WAIT, STAGE_FOLLOWER,
                                     STAGE_QUORUM_WAIT, STAGE_REPLICATE,
-                                    STAGE_REPLY, STAGE_TXN, TRACER)
+                                    STAGE_REPLY, STAGE_STREAM_LINK, STAGE_TXN,
+                                    TRACER)
 from ratis_tpu.util import injection
 
 LOG = logging.getLogger(__name__)
@@ -2566,8 +2567,10 @@ class Division:
             # detect the miss and fetch/repair — the reference passes a null
             # stream for exactly this case.
             if entry.smlog is not None:
-                link = None
+                link, t_link = None, 0
                 if self.server.datastream is not None:
+                    if TRACER.enabled:
+                        t_link = TRACER.now()
                     link = self.server.datastream.take_link(
                         entry.smlog.client_id, entry.smlog.call_id)
                 if link is not None or entry.smlog.is_datastream:
@@ -2576,6 +2579,7 @@ class Division:
                             link.local if link is not None else None, entry)
                     except Exception:
                         LOG.exception("%s data_link failed", self.member_id)
+                    TRACER.interval(STAGE_STREAM_LINK, t_link)
             try:
                 # applyTransactionSerial runs strictly in log order ahead of
                 # applyTransaction (StateMachine.java:565: the serial hook

@@ -1663,13 +1663,27 @@ class RaftServer:
         """Primary-side raft submit of a completed DataStream
         (DataStreamManagement.java:139-193: on CLOSE the primary drives the
         header request through the ordinary consensus path).  The primary
-        may not be the leader — forward like any client request would be."""
+        may not be the leader — forward like any client request would be.
+        Traced like a client request (the stream server minted its id at
+        the CLOSE): ``server.route`` is the synchronous part up to the
+        division's submit, and the egress mark is where the stream server's
+        ``server.respond`` starts."""
+        from ratis_tpu.trace.tracer import STAGE_ROUTE, TRACER
+        route = (TRACER.begin(STAGE_ROUTE, request.trace_id)
+                 if TRACER.enabled else None)
         try:
-            div = self.get_division(request.group_id)
-            reply = await self._run_on_division_loop(
-                request.group_id, div.submit_client_request(request))
+            try:
+                div = self.get_division(request.group_id)
+                submit = div.submit_client_request(request)
+            finally:
+                if route is not None:
+                    TRACER.end(route)
+            reply = await self._run_on_division_loop(request.group_id,
+                                                     submit)
         except RaftException as e:
             return RaftClientReply.failure_reply(request, e)
+        if request.trace_id:
+            TRACER.mark_egress(request.trace_id)
         nle = reply.get_not_leader_exception()
         if nle is not None and nle.suggested_leader is not None:
             peer = nle.suggested_leader

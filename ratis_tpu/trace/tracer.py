@@ -125,7 +125,26 @@ STAGE_GRPC_READ = 33    # grpc.read — one inbound stream message: unpacked,
 STAGE_GRPC_WRITE = 34   # grpc.write — one outbound stream message: packed
                         # and given to grpc.aio, up to where that call
                         # suspends (tag = chunks)
-NUM_STAGES = 35
+# The stream plane (server/datastream.py): bulk bytes beside the log.  All
+# process-level (trace id 0), sampled per stage.
+STAGE_STREAM_HEADER = 35  # stream.header — a HEADER packet off the socket ->
+                          # its ack written to the connection (the state
+                          # machine's channel opened, successors connected
+                          # and the header forwarded)
+STAGE_STREAM_PACKET = 36  # stream.packet — a DATA packet off the socket at a
+                          # peer -> its ack written to the connection (local
+                          # write, sends, successors' acks; tag = bytes,
+                          # negated at a successor)
+STAGE_STREAM_WRITE = 37   # stream.write — channel.write called -> returned
+                          # on the loop (tag = bytes)
+STAGE_STREAM_CLOSE = 38   # stream.close — the CLOSE packet off the socket at
+                          # the primary -> submit_data_stream_request called
+                          # (pipeline drained, CLOSE forwarded, channel forced)
+STAGE_STREAM_FORCE = 39   # stream.force — a channel's force, on the forcing
+                          # thread (tag = bytes since the last force)
+STAGE_STREAM_LINK = 40    # stream.link — take_link -> data_link returned, at
+                          # apply
+NUM_STAGES = 41
 
 STAGE_NAMES = (
     "client.send", "codec.encode", "codec.decode", "wire.rtt",
@@ -139,6 +158,8 @@ STAGE_NAMES = (
     "tcp.read", "loop.select", "wire.flush",
     "server.data_wait", "sm.data_write", "sm.data_fsync",
     "grpc.read", "grpc.write",
+    "stream.header", "stream.packet", "stream.write", "stream.close",
+    "stream.force", "stream.link",
 )
 
 # W = work span: a stretch that is synchronous on one thread by construction
@@ -158,6 +179,7 @@ STAGE_KINDS = (
     "W", "W", "W",
     "I", "W", "W",
     "W", "W",
+    "I", "I", "I", "I", "W", "I",
 )
 
 # Work spans happen once per batch, several batches per commit: their rings
@@ -459,6 +481,14 @@ class Tracer:
             return
         self._rings[stage].record(trace_id, t0_ns, t1_ns, tag,
                                   origin=threading.get_ident())
+
+    def interval(self, stage: int, t0_ns: int, tag: int = 0) -> None:
+        """Close a process-level interval of ``stage`` that began at
+        ``t0_ns`` (0: no session was open then), under the stage's own
+        sampling."""
+        if t0_ns and self.sample(stage):
+            self._rings[stage].record(0, t0_ns, time.monotonic_ns(), tag,
+                                      origin=threading.get_ident())
 
     def begin(self, stage: int, trace_id: int = -1, always: bool = False):
         """Open a work span (call only while ``enabled``).  ``trace_id`` -1

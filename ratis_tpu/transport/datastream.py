@@ -24,7 +24,13 @@ import logging
 import struct
 from typing import Awaitable, Callable, Optional
 
+from ratis_tpu.trace.tracer import TRACER
+
 LOG = logging.getLogger(__name__)
+
+# connections accepted on a stream port (docs/tracing.md; those a server
+# opens to a successor are counted where it opens them)
+_CONNECTS_ACCEPTED = TRACER.counter("stream.connects", "accepted")
 
 KIND_HEADER = 1
 KIND_DATA = 2
@@ -166,6 +172,7 @@ class DataStreamServer:
                           writer: asyncio.StreamWriter) -> None:
         conn = PeerConnection(reader, writer)
         self._conns.add(conn)
+        _CONNECTS_ACCEPTED.n += 1
         try:
             while True:
                 packet = await read_packet(reader)
