@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import asyncio
 import concurrent.futures
+import logging
 import os
 import pathlib
 import tempfile
@@ -49,6 +50,8 @@ from ratis_tpu.server.statemachine import (BaseStateMachine, DataChannel,
 from ratis_tpu.trace.tracer import (STAGE_DATA_FSYNC, STAGE_DATA_WRITE,
                                     STAGE_STREAM_FORCE, TRACER)
 
+LOG = logging.getLogger(__name__)
+
 # The writers of every FileStore of the process (the reference runs a
 # writer executor per state machine; thousands of co-hosted groups share
 # one).  Each job is one pwrite and, where the request says sync, one fsync.
@@ -57,10 +60,109 @@ _IO = concurrent.futures.ThreadPoolExecutor(   # (threads start on demand)
     IO_THREADS, thread_name_prefix="filestore-io")
 
 
+# The writers of every streamed file of the process: a stream's channel is
+# pinned to one lane (by stream id), a thread that takes everything queued in
+# one pass, writes each file's run of packets with one pwritev and calls each
+# loop that queued back once (as server/log/segmented.py's LogWorker does).
+STREAM_LANES = 3
+_IOV_MAX = 1024     # buffers a pwritev takes (Linux's UIO_MAXIOV)
+
 # counters (docs/tracing.md): payload bytes the leaders put into sm_data
 # (start_transaction runs on the leader alone); forces behind data_write
 _DATA_BYTES = TRACER.counter("sm.data_bytes", "leader")
 _DATA_FSYNCS = TRACER.counter("sm.data_fsyncs")
+
+
+class _WriterLane:
+    """One ordered writer thread for streamed files.
+
+    ``submit`` runs on a loop and only appends ``(channel, data, future)``
+    under the condition, waking the thread if it waits.  The thread takes
+    the whole queue as one batch, hands each channel its items in queue
+    order (``FileChunkChannel._take``) and resolves the batch with one
+    ``call_soon_threadsafe`` to each loop that queued into it.  Counted:
+    ``stream.write_batches`` a pass, ``stream.write_calls`` a ``pwritev``."""
+
+    def __init__(self, name: str) -> None:
+        self.name = name
+        self._queue: list = []
+        self._cond = threading.Condition(threading.Lock())
+        self._thread: Optional[threading.Thread] = None
+        # (only this lane's thread adds to them)
+        self.batches = TRACER.counter("stream.write_batches", name)
+        self.calls = TRACER.counter("stream.write_calls", name)
+
+    def submit(self, channel: "FileChunkChannel",
+               data: Optional[bytes]) -> asyncio.Future:
+        """Queue ``data`` for the end of ``channel``'s file (None: close its
+        descriptor); the future, of the running loop, holds the bytes
+        written or the error that stopped them."""
+        fut = asyncio.get_running_loop().create_future()
+        with self._cond:
+            self._queue.append((channel, data, fut))
+            if self._thread is None:
+                self._thread = threading.Thread(
+                    target=self._run, name=f"filestore-{self.name}",
+                    daemon=True)
+                self._thread.start()
+            elif len(self._queue) == 1:     # (it waits only on an empty one)
+                self._cond.notify()
+        return fut
+
+    def _run(self) -> None:
+        cond, queue = self._cond, self._queue
+        while True:
+            with cond:
+                while not queue:
+                    cond.wait()
+                batch = queue[:]
+                del queue[:]
+            self.batches.n += 1
+            try:
+                done = self._write(batch)
+            except BaseException as e:  # the batch fails; the lane goes on
+                LOG.exception("stream writer %s: a pass failed", self.name)
+                err = e if isinstance(e, Exception) else RuntimeError(repr(e))
+                done = [(fut, err) for _, _, fut in batch]
+            by_loop: dict = {}
+            for fut, out in done:
+                by_loop.setdefault(fut.get_loop(), []).append((fut, out))
+            for loop, outs in by_loop.items():
+                try:
+                    loop.call_soon_threadsafe(_resolve, outs)
+                except RuntimeError:
+                    pass    # that loop has closed: nobody is left to tell
+
+    @staticmethod
+    def _write(batch: list) -> list:
+        """A pass: each channel's items in queue order; each future's
+        outcome."""
+        items: dict = {}
+        for channel, data, fut in batch:
+            items.setdefault(channel, []).append((data, fut))
+        done = []
+        for channel, queued in items.items():
+            try:
+                outcomes = channel._take([d for d, _ in queued])
+            except Exception as e:      # (a close that failed)
+                outcomes = [e] * len(queued)
+            channel._taken += len(queued)
+            done += [(fut, out) for (_, fut), out in zip(queued, outcomes)]
+        return done
+
+
+def _resolve(done: list) -> None:
+    """Back on the loop that queued them: a lane pass's outcomes."""
+    for fut, out in done:
+        if fut.done():
+            continue        # (its awaiter was cancelled)
+        if isinstance(out, BaseException):
+            fut.set_exception(out)
+        else:
+            fut.set_result(out)
+
+
+_LANES = [_WriterLane(f"lane-{i}") for i in range(STREAM_LANES)]
 
 
 def _safe_relpath(path: str) -> pathlib.PurePosixPath:
@@ -71,35 +173,118 @@ def _safe_relpath(path: str) -> pathlib.PurePosixPath:
 
 
 class FileChunkChannel(DataChannel):
-    """Streams into ``<root>/.tmp/<stream>``; linked (renamed) at apply."""
+    """Streams into ``<root>/.tmp/<stream>``; linked (renamed) at apply.
+    Writes go through ``lane`` in order, and the close too while the lane
+    holds some of them; the force does not (the plane forces only what it
+    has seen written)."""
 
-    def __init__(self, tmp_path: pathlib.Path) -> None:
+    def __init__(self, tmp_path: pathlib.Path, lane: _WriterLane) -> None:
         self.tmp_path = tmp_path
-        self._file = open(tmp_path, "wb")
-        self._unforced = 0      # bytes written since the last force
+        self._lane = lane
+        self._fd = os.open(tmp_path, os.O_WRONLY | os.O_CREAT | os.O_TRUNC,
+                           0o644)
+        self._end = 0           # bytes landed (the lane's thread moves it)
+        self._forced = 0        # ... of them as far as the last force
+        self._error: Optional[BaseException] = None   # the lane's thread's
+        # items handed to the lane, and those it is done with (its thread's)
+        self._submitted = self._taken = 0
+        self._closed: Optional[asyncio.Future] = None
+
+    def submit_write(self, data: bytes) -> "asyncio.Future[int]":
+        """Queue ``data`` behind this channel's earlier writes: the future
+        holds the bytes written (fewer: a short write) or the error."""
+        self._submitted += 1
+        return self._lane.submit(self, data)
 
     async def write(self, data: bytes) -> int:
-        written = await asyncio.to_thread(self._file.write, data)
-        self._unforced += written
-        return written
+        return await self.submit_write(data)
 
     async def force(self, metadata: bool = False) -> None:
-        nbytes, self._unforced = self._unforced, 0
+        nbytes, self._forced = self._end - self._forced, self._end
 
         def _sync():
             span = TRACER.begin(STAGE_STREAM_FORCE) if TRACER.enabled \
                 else None
             try:
-                self._file.flush()
-                os.fsync(self._file.fileno())
+                os.fsync(self._fd)
             finally:
                 if span is not None:
                     TRACER.end(span, tag=nbytes)
         await asyncio.to_thread(_sync)
 
     async def close(self) -> None:
-        if not self._file.closed:
-            await asyncio.to_thread(self._file.close)
+        if self._closed is None:
+            if self._taken == self._submitted:
+                self._closed = asyncio.get_running_loop().run_in_executor(
+                    None, self._close_fd)
+            else:   # behind them: the descriptor is not closed under one
+                self._submitted += 1
+                self._closed = self._lane.submit(self, None)
+        if not self._closed.done():
+            await self._closed
+
+    def _close_fd(self) -> int:
+        fd, self._fd = self._fd, -1     # (once: a failed close still frees it)
+        if fd >= 0:
+            os.close(fd)
+        return 0
+
+    # ------------------------------------------------- the lane's thread
+
+    def _take(self, queued: list) -> list:
+        """This channel's items of a lane pass, in order (None, last: the
+        close); each one's outcome."""
+        closing = queued[-1] is None
+        out = self._write_run(queued[:-1] if closing else queued)
+        if closing:
+            out.append(self._close_fd())
+        return out
+
+    def _write_run(self, chunks: list) -> list:
+        """A run of writes in one ``_append``.  Where it falls short, the
+        file is cut back to its last whole packet and every later write
+        of the channel fails."""
+        start, err = self._end, None
+        if self._error is None and chunks:
+            try:
+                self._append(chunks)
+            except OSError as e:
+                err = e
+        landed, out = self._end - start, []
+        for data in chunks:
+            if self._error is None and landed >= len(data):
+                landed -= len(data)
+                out.append(len(data))
+            elif self._error is None:
+                self._error = err or IOError(
+                    f"{self.tmp_path.name}: short write {landed}/{len(data)}")
+                out.append(err or landed)
+            else:
+                out.append(IOError(f"{self.tmp_path.name}: an earlier "
+                                   f"write failed: {self._error}"))
+        if landed:      # part of a packet
+            try:
+                os.ftruncate(self._fd, self._end - landed)
+                self._end -= landed
+            except OSError:
+                pass
+        return out
+
+    def _append(self, chunks: list) -> None:
+        """``chunks`` at the end of what has landed: one ``pwritev`` (more
+        only where the kernel takes less); stops at a call that takes
+        nothing."""
+        while chunks:
+            n = os.pwritev(self._fd, chunks[:_IOV_MAX], self._end)
+            self._lane.calls.n += 1
+            if n == 0:
+                return
+            self._end += n
+            while chunks and n >= len(chunks[0]):
+                n -= len(chunks[0])
+                chunks = chunks[1:]
+            if n:
+                chunks = [memoryview(chunks[0])[n:]] + chunks[1:]
 
 
 class FileStoreDataStream(DataStream):
@@ -429,7 +614,9 @@ class FileStoreStateMachine(BaseStateMachine):
         # (the directories are made where they are first needed, off the
         # loop: thousands of co-hosted groups make theirs at once)
         await asyncio.to_thread(tmp.parent.mkdir, parents=True, exist_ok=True)
-        return FileStoreDataStream(FileChunkChannel(tmp), request, target)
+        lane = _LANES[request.type.stream_id % STREAM_LANES]
+        return FileStoreDataStream(FileChunkChannel(tmp, lane), request,
+                                   target)
 
     async def data_link(self, stream: Optional[DataStream], entry) -> None:
         if stream is None:

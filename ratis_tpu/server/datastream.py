@@ -309,22 +309,38 @@ class DataStreamManagement:
         return info
 
     async def _write_local(self, info: StreamInfo, data: bytes) -> None:
-        """A packet's bytes into the stream's local channel, counted."""
+        """A packet's bytes into the stream's local channel, awaited and
+        counted."""
+        self._written(info, data, await self._queue_local(info, data))
+
+    @staticmethod
+    def _queue_local(info: StreamInfo, data: bytes) -> asyncio.Future:
+        """A packet's bytes queued to the stream's local channel
+        (``DataChannel.submit_write``): the offset moves now, the future
+        holds the bytes written."""
         t0 = TRACER.now() if TRACER.enabled else 0
-        written = await info.local.channel.write(data)
-        TRACER.interval(STAGE_STREAM_WRITE, t0, len(data))
+        fut = info.local.channel.submit_write(data)
+        info.next_offset += len(data)
+        if t0:
+            fut.add_done_callback(lambda _: TRACER.interval(
+                STAGE_STREAM_WRITE, t0, len(data)))
+        return fut
+
+    @staticmethod
+    def _written(info: StreamInfo, data: bytes, written: int) -> None:
         if written != len(data):
             raise DataStreamException(f"short write {written}/{len(data)}")
-        info.next_offset += len(data)
         info.bytes_written += len(data)
         _PACKETS[info.is_primary].n += 1
         _BYTES[info.is_primary].n += len(data)
 
     async def _on_data_pipelined(self, packet: Packet, conn: PeerConnection,
                                  t0: int = 0) -> None:
-        """Ordered phase of a (non-close) DATA packet: validate, write the
-        local channel, put the forward copies on the wire; then hand the
-        ack-collection to a completion task so the read loop pipelines."""
+        """Ordered phase of a (non-close) DATA packet: validate, queue the
+        local write, put the forward copies on the wire; then hand the local
+        write and the ack-collection to a completion task so the read loop
+        pipelines (the reference's writeTo combines the local write and the
+        remote ones the same way)."""
         info = self._info_for(packet)
         info.touched_s = time.monotonic()
         if info.failed is not None:
@@ -334,12 +350,15 @@ class DataStreamManagement:
                 f"stream {packet.stream_id}: out-of-order offset "
                 f"{packet.offset}, expected {info.next_offset}")
         ack_futs: list = []
+        write: Optional[asyncio.Future] = None
         try:
-            await self._write_local(info, packet.data)
+            write = self._queue_local(info, packet.data)
             # sends happen NOW, in read-loop order (per-successor FIFO);
             # only the ack futures move to the completion task
             for r in info.remotes:
                 ack_futs.append(await r.send(packet))
+            if packet.is_sync:
+                await asyncio.wait((write,))    # (the force below covers it)
         except asyncio.CancelledError:
             raise
         except Exception as e:
@@ -351,7 +370,7 @@ class DataStreamManagement:
             # noise with no handler (ADVICE r5).
             info.failed = e if isinstance(e, DataStreamException) \
                 else DataStreamException(str(e))
-            for fut in ack_futs:
+            for fut in ack_futs if write is None else [write, *ack_futs]:
                 fut.add_done_callback(_consume_result)
                 fut.cancel()
             raise
@@ -360,6 +379,7 @@ class DataStreamManagement:
 
         async def complete() -> None:
             try:
+                self._written(info, packet.data, await write)
                 replies = await asyncio.gather(*ack_futs)
                 for r, reply in zip(info.remotes, replies):
                     if not reply.success:
@@ -371,6 +391,8 @@ class DataStreamManagement:
             except Exception as e:
                 # poison the stream: later packets and the CLOSE must fail
                 info.failed = e
+                for fut in ack_futs:    # (where the write failed first)
+                    fut.add_done_callback(_consume_result)
                 LOG.warning("datastream packet failed: %s", e)
                 self.metrics.num_failed.inc()
                 await conn.send(Packet(KIND_REPLY, packet.stream_id,
@@ -389,12 +411,16 @@ class DataStreamManagement:
         t.add_done_callback(info.pending.discard)
 
     async def _on_close_data(self, packet: Packet) -> None:
-        """The CLOSE packet's data phase: drain the pipeline first, then the
-        fully-awaited ordered path (forwarding the close to successors and
-        forcing the local channel)."""
+        """The CLOSE packet's data phase: drain the pipeline first (each
+        packet's completion task waits for its local write too, so the
+        force covers every byte acknowledged), then the fully-awaited
+        ordered path (forwarding the close to successors and forcing the
+        local channel)."""
         info = self._info_for(packet)
         info.touched_s = time.monotonic()
-        while info.pending:
+        # (a task done but not yet discarded is drained: a gather of done
+        # tasks never yields, so its discard would never run)
+        while any(not t.done() for t in info.pending):
             await asyncio.gather(*list(info.pending),
                                  return_exceptions=True)
         if info.failed is not None:

@@ -122,10 +122,32 @@ class StateMachineStorage:
 class DataChannel:
     """Destination of one DataStream's bytes
     (reference StateMachine.DataChannel:302 — a WritableByteChannel the SM
-    owns, e.g. an open file)."""
+    owns, e.g. an open file).
+
+    The stream plane hands each packet to ``submit_write`` and puts its
+    copies on the successors' sockets without waiting for the write.  The
+    default runs ``write`` behind the channel's previous one; a channel
+    with an ordered writer of its own overrides it (FileStore's
+    ``FileChunkChannel``)."""
+
+    _last_write: Optional[asyncio.Future] = None
 
     async def write(self, data: bytes) -> int:
         raise NotImplementedError
+
+    def submit_write(self, data: bytes) -> "asyncio.Future[int]":
+        """Queue ``data`` behind this channel's earlier writes, not awaited:
+        the future holds the bytes written or the error (an earlier
+        write's, where that one failed)."""
+        before = self._last_write
+
+        async def _write() -> int:
+            if before is not None:
+                await before
+            return await self.write(data)
+
+        self._last_write = fut = asyncio.ensure_future(_write())
+        return fut
 
     async def force(self, metadata: bool = False) -> None:
         """fsync-equivalent (DataChannel.force)."""

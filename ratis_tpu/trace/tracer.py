@@ -135,8 +135,8 @@ STAGE_STREAM_PACKET = 36  # stream.packet — a DATA packet off the socket at a
                           # peer -> its ack written to the connection (local
                           # write, sends, successors' acks; tag = bytes,
                           # negated at a successor)
-STAGE_STREAM_WRITE = 37   # stream.write — channel.write called -> returned
-                          # on the loop (tag = bytes)
+STAGE_STREAM_WRITE = 37   # stream.write — the write handed to the channel
+                          # -> its completion seen on the loop (tag = bytes)
 STAGE_STREAM_CLOSE = 38   # stream.close — the CLOSE packet off the socket at
                           # the primary -> submit_data_stream_request called
                           # (pipeline drained, CLOSE forwarded, channel forced)

@@ -2,11 +2,18 @@
 TestNettyDataStream*: framing, routing, stream-write-link end to end)."""
 
 import asyncio
+import errno
+import random
+import threading
+import time
 
 import msgpack
 import pytest
 
-from ratis_tpu.models.filestore import FileStoreStateMachine
+from ratis_tpu.models import filestore
+from ratis_tpu.models.filestore import (FileChunkChannel,
+                                        FileStoreStateMachine, _WriterLane)
+from ratis_tpu.server.statemachine import DataChannel, DataStream
 from ratis_tpu.protocol.ids import RaftPeerId
 from ratis_tpu.protocol.routing import RoutingTable
 from ratis_tpu.transport.datastream import (FLAG_CLOSE, FLAG_PRIMARY,
@@ -320,3 +327,434 @@ def test_datastream_tls_end_to_end(tmp_path):
 
     run_with_new_cluster(3, _test, sm_factory=FileStoreStateMachine,
                          properties=p)
+
+
+# ------------------------------------------- the writer lane of streamed files
+
+def test_a_lane_writes_interleaved_streams_each_in_order(tmp_path):
+    """Four streams' packets interleaved through one lane land byte for
+    byte as a plain sequential write of each stream's packets."""
+
+    async def _run():
+        lane = _WriterLane("test-order")
+        rnd = random.Random(37)
+        chans = [FileChunkChannel(tmp_path / f"s{i}", lane) for i in range(4)]
+        packets: list = [[] for _ in chans]
+        futs = []
+        for k in range(200):
+            i = rnd.randrange(len(chans))
+            data = rnd.randbytes(rnd.randrange(1, 5000))
+            packets[i].append(data)
+            futs.append((chans[i].submit_write(data), len(data)))
+            if k % 17 == 0:
+                await asyncio.sleep(0)      # let a pass take what is queued
+        for fut, n in futs:
+            assert await fut == n
+        for i, chan in enumerate(chans):
+            await chan.close()
+            plain = tmp_path / f"plain{i}"
+            with open(plain, "wb") as f:
+                for data in packets[i]:
+                    f.write(data)
+            assert chan.tmp_path.read_bytes() == plain.read_bytes()
+
+    asyncio.run(_run())
+
+
+def test_a_lane_pass_resolves_what_it_met_with_one_loop_callback(tmp_path):
+    held, entered = threading.Event(), threading.Event()
+
+    class HeldChannel(FileChunkChannel):
+        def _append(self, chunks):
+            entered.set()
+            assert held.wait(10)
+            super()._append(chunks)
+
+    async def _run():
+        loop = asyncio.get_running_loop()
+        lane = _WriterLane("test-batch")
+        callbacks = []
+        call_soon_threadsafe = loop.call_soon_threadsafe
+
+        def counted(cb, *args, **kwargs):
+            if threading.current_thread().name == "filestore-test-batch":
+                callbacks.append(cb)
+            return call_soon_threadsafe(cb, *args, **kwargs)
+
+        loop.call_soon_threadsafe = counted
+        gate = HeldChannel(tmp_path / "gate", lane)
+        first = gate.submit_write(b"g")
+        try:
+            assert entered.wait(10)     # the lane's first pass holds it
+            chans = [FileChunkChannel(tmp_path / f"s{i}", lane)
+                     for i in range(3)]
+            futs = [chans[k % 3].submit_write(bytes([k]) * 1000)
+                    for k in range(12)]
+            # a close while the lane holds the channel's writes: behind them
+            closing = asyncio.create_task(chans[0].close())
+            await asyncio.sleep(0)
+            assert not closing.done() and chans[0]._fd >= 0
+            batches, calls = lane.batches.n, lane.calls.n
+        finally:
+            held.set()
+        assert await first == 1
+        assert [await f for f in futs] == [1000] * 12
+        await closing
+        assert chans[0]._fd == -1
+        # the twelve queued packets and the close: one pass, one pwritev a
+        # file, and one call back to the loop (the held pass adds its
+        # pwritev and its call)
+        assert lane.batches.n - batches == 1
+        assert lane.calls.n - calls == 3 + 1
+        assert len(callbacks) == 2
+        for k, chan in enumerate(chans):
+            await chan.close()
+            assert chan.tmp_path.read_bytes() == b"".join(
+                bytes([j]) * 1000 for j in range(k, 12, 3))
+        await gate.close()
+
+    asyncio.run(_run())
+
+
+def test_a_lane_outlives_a_fault_of_its_own_pass(tmp_path, monkeypatch):
+    """A pass that raises fails its batch's futures with the error; the
+    lane's thread goes on and takes the next writes."""
+    write = _WriterLane._write
+    faults = [RuntimeError("a fault of the pass")]
+
+    def faulty(batch):
+        if faults:
+            raise faults.pop()
+        return write(batch)
+
+    monkeypatch.setattr(_WriterLane, "_write", staticmethod(faulty))
+
+    async def _run():
+        lane = _WriterLane("test-fault")
+        chan = FileChunkChannel(tmp_path / "f", lane)
+        with pytest.raises(RuntimeError, match="a fault of the pass"):
+            await chan.submit_write(b"lost")
+        assert await asyncio.wait_for(chan.submit_write(b"kept"), 10) == 4
+        await chan.close()
+        assert chan.tmp_path.read_bytes() == b"kept"
+
+    asyncio.run(_run())
+
+
+P = 4096            # a packet of the stream tests below
+LIMIT = 2 * P + 100  # what a failing channel takes: two packets and a bit
+
+
+class _FailingChannel(FileChunkChannel):
+    how = "short"
+
+    def _append(self, chunks):
+        data = b"".join(bytes(c) for c in chunks)
+        room = max(0, LIMIT - self._end)
+        if room:
+            super()._append([data[:room]])
+        if len(data) > room and self.how == "error":
+            raise OSError(errno.ENOSPC, "no space left on device")
+
+
+def _failing_store(how):
+    class FailingFileStore(FileStoreStateMachine):
+        async def data_stream(self, request):
+            stream = await super().data_stream(request)
+            stream.channel.__class__ = _FailingChannel
+            stream.channel.how = how
+            return stream
+    return FailingFileStore
+
+
+def _chain_from_leader(cluster, leader):
+    order = [leader.member_id.peer_id] + \
+        [d.member_id.peer_id for d in cluster.divisions()
+         if d.member_id.peer_id != leader.member_id.peer_id]
+    return RoutingTable.chain(order), cluster.group.get_peer(order[0])
+
+
+@pytest.mark.parametrize("how", ["short", "error"])
+def test_a_failed_local_write_poisons_the_stream(how):
+    """Packet 2 of 6 falls short on every peer: packets 0 and 1 are
+    acknowledged, 2 and later get failure replies, the CLOSE fails, and
+    each peer's file holds exactly the acknowledged packets."""
+
+    async def _test(cluster):
+        leader = await cluster.wait_for_leader()
+        rt, primary = _chain_from_leader(cluster, leader)
+        async with cluster.new_client() as client:
+            out = await client.data_stream().stream(
+                _stream_cmd("poisoned.bin"), routing_table=rt,
+                primary=primary)
+            for k in range(6):
+                await out.write_async(bytes([k]) * P)
+            replies = await asyncio.gather(*out._acks)
+            assert [r.success for r in replies] == [True] * 2 + [False] * 4
+            close = await (await out._conn.send(Packet(
+                KIND_DATA, out._stream_id, 6 * P, FLAG_CLOSE, b"")))
+            assert not close.success
+            await out._conn.close()
+        held = [info for s in cluster.servers.values()
+                for info in s.datastream._streams.values()]
+        assert len(held) == 3 and all(i.failed is not None for i in held)
+        for info in held:
+            channel = info.local.channel
+            assert channel.tmp_path.read_bytes() == bytes([0]) * P \
+                + bytes([1]) * P
+
+    run_with_new_cluster(3, _test, sm_factory=_failing_store(how))
+
+
+def test_a_close_drains_a_packet_done_but_not_yet_discarded(monkeypatch):
+    """The CLOSE may run in the loop pass after a packet's completion task
+    finished and before its done-callback took it out of ``pending``: the
+    drain takes it as done, and does not spin on a gather that never
+    yields (a hang, seen beside a shard-pinned stream)."""
+    from ratis_tpu.server import datastream
+
+    gather, calls = asyncio.gather, []
+
+    def counted(*futs, **kwargs):
+        calls.append(1)
+        if len(calls) > 100:
+            raise AssertionError("the drain spins")
+        return gather(*futs, **kwargs)
+
+    async def _run():
+        mgmt = datastream.DataStreamManagement.__new__(
+            datastream.DataStreamManagement)
+        info = datastream.StreamInfo(None, True, DataStream(DataChannel()),
+                                     [])
+        done = asyncio.get_running_loop().create_future()
+        done.set_result(None)
+        info.pending.add(done)      # finished; its discard not yet run
+        mgmt._streams = {7: info}
+        monkeypatch.setattr(datastream.asyncio, "gather", counted)
+        await mgmt._on_close_data(Packet(KIND_DATA, 7, 0, FLAG_CLOSE, b""))
+
+    asyncio.run(_run())
+
+
+class _MemoryChannel(DataChannel):
+    """A channel with only a ``write``: the plane queues each packet through
+    ``DataChannel.submit_write``'s default, one write behind the other."""
+
+    gate: asyncio.Event      # (set per test: holds every write)
+
+    def __init__(self) -> None:
+        self.data = bytearray()
+
+    async def write(self, data: bytes) -> int:
+        await self.gate.wait()
+        self.data += data
+        return len(data)
+
+
+class MemoryChannelFileStore(FileStoreStateMachine):
+    async def data_stream(self, request):
+        stream = DataStream(_MemoryChannel(), request)
+        stream.path = msgpack.unpackb(request.message.content,
+                                      raw=False)["path"]
+        return stream
+
+    async def data_link(self, stream, entry):
+        if stream is not None:
+            self.resolve(stream.path).write_bytes(bytes(stream.channel.data))
+
+
+def test_the_default_submit_writes_in_order_and_fails_what_follows_a_failure():
+    """``DataChannel.submit_write`` runs each ``write`` behind the one before
+    it, whatever each takes; one that fails fails every later one."""
+
+    class Jittery(DataChannel):
+        def __init__(self):
+            self.data, self.rnd = bytearray(), random.Random(37)
+
+        async def write(self, data):
+            for _ in range(self.rnd.randrange(4)):
+                await asyncio.sleep(0)
+            if data == b"bad":
+                raise OSError(errno.EIO, "write failed")
+            self.data += data
+            return len(data)
+
+    async def _run():
+        chan = Jittery()
+        futs = [chan.submit_write(bytes([k]) * (k + 1)) for k in range(40)]
+        assert [await f for f in futs] == [k + 1 for k in range(40)]
+        assert chan.data == b"".join(bytes([k]) * (k + 1) for k in range(40))
+        bad, after = chan.submit_write(b"bad"), chan.submit_write(b"late")
+        for fut in (bad, after):
+            with pytest.raises(OSError):
+                await fut
+        assert not chan.data.endswith(b"late")
+
+    asyncio.run(_run())
+
+
+def test_a_channel_with_only_a_write_streams_through_the_default_submit():
+    """Its writes held shut, all four packets still reach both successors
+    (the copy does not wait for the write) and the client has no ack; let
+    go, every peer's bytes are whole and no FileStore lane ran."""
+    lanes = sum(lane.batches.n for lane in filestore._LANES)
+
+    async def _test(cluster):
+        _MemoryChannel.gate = asyncio.Event()
+        leader = await cluster.wait_for_leader()
+        rt, primary = _chain_from_leader(cluster, leader)
+        payload = random.Random(4).randbytes(4 * P)
+        async with cluster.new_client() as client:
+            out = await client.data_stream().stream(
+                _stream_cmd("memory.bin"), routing_table=rt, primary=primary)
+            for i in range(0, len(payload), P):
+                await out.write_async(payload[i:i + P])
+            infos: list = []
+            deadline = time.monotonic() + 10
+            while not (len(infos) == 3 and all(
+                    i.next_offset == len(payload) for i in infos)):
+                assert time.monotonic() < deadline
+                await asyncio.sleep(0.01)
+                infos = [info for s in cluster.servers.values()
+                         for info in s.datastream._streams.values()]
+            assert not any(i.local.channel.data for i in infos)
+            assert not any(f.done() for f in out._acks)
+            _MemoryChannel.gate.set()
+            reply = await out.close_async()
+            assert reply.success, reply.exception
+            assert msgpack.unpackb(reply.message.content, raw=False) == \
+                {"ok": True, "size": len(payload)}
+            await cluster.wait_applied(reply.log_index)
+        for div in cluster.divisions():
+            assert div.state_machine.resolve("memory.bin").read_bytes() \
+                == payload
+
+    run_with_new_cluster(3, _test, sm_factory=MemoryChannelFileStore)
+    assert sum(lane.batches.n for lane in filestore._LANES) == lanes
+
+
+def test_the_copy_leaves_before_the_local_write_completes(monkeypatch):
+    """With every lane held shut, all four packets reach both successors
+    (their offsets move) while no peer's write has landed and the client
+    has no ack; let go, the stream completes and every file is whole."""
+    held = threading.Event()
+    append = FileChunkChannel._append
+
+    def held_append(self, chunks):
+        assert held.wait(10)
+        append(self, chunks)
+
+    monkeypatch.setattr(FileChunkChannel, "_append", held_append)
+
+    async def _test(cluster):
+        leader = await cluster.wait_for_leader()
+        rt, primary = _chain_from_leader(cluster, leader)
+        payload = random.Random(5).randbytes(4 * P)
+        async with cluster.new_client() as client:
+            out = await client.data_stream().stream(
+                _stream_cmd("held.bin"), routing_table=rt, primary=primary)
+            try:
+                for i in range(0, len(payload), P):
+                    await out.write_async(payload[i:i + P])
+                infos = [info for s in cluster.servers.values()
+                         for info in s.datastream._streams.values()]
+                deadline = time.monotonic() + 10
+                while not (len(infos) == 3 and all(
+                        i.next_offset == len(payload) for i in infos)):
+                    assert time.monotonic() < deadline
+                    await asyncio.sleep(0.01)
+                    infos = [info for s in cluster.servers.values()
+                             for info in s.datastream._streams.values()]
+                assert sum(not i.is_primary for i in infos) == 2
+                assert all(i.local.channel._end == 0 for i in infos)
+                assert not any(f.done() for f in out._acks)
+            finally:
+                held.set()
+            reply = await out.close_async()
+            assert reply.success, reply.exception
+            await cluster.wait_applied(reply.log_index)
+        for div in cluster.divisions():
+            assert div.state_machine.resolve("held.bin").read_bytes() \
+                == payload
+
+    try:
+        run_with_new_cluster(3, _test, sm_factory=FileStoreStateMachine)
+    finally:
+        held.set()
+
+
+def test_a_stream_pinned_to_a_loop_shard_is_called_back_on_that_loop(
+        monkeypatch):
+    """raft.tpu.replication.stream-shards with two loop shards, the group
+    on the second: every peer queues its writes from its shard's loop, and
+    the lanes call back there, not to the servers' first loop."""
+    from ratis_tpu.server.shards import LoopShardPool
+    from tests.minicluster import fast_properties
+
+    monkeypatch.setattr(LoopShardPool, "shard_of", lambda self, key: 1)
+    loops = set()
+    submit = _WriterLane.submit
+
+    def seen(self, channel, data):
+        fut = submit(self, channel, data)
+        loops.add(fut.get_loop())
+        return fut
+
+    monkeypatch.setattr(_WriterLane, "submit", seen)
+    p = fast_properties()
+    p.set("raft.tpu.server.loop-shards", "2")
+
+    async def _test(cluster):
+        await cluster.wait_for_leader()
+        payload = random.Random(6).randbytes(1 << 19)
+        async with cluster.new_client() as client:
+            out = await client.data_stream().stream(_stream_cmd("shard.bin"))
+            for i in range(0, len(payload), 64 << 10):
+                await out.write_async(payload[i:i + (64 << 10)])
+            reply = await out.close_async()
+            assert reply.success, reply.exception
+            await cluster.wait_applied(reply.log_index)
+        for div in cluster.divisions():
+            assert div.state_machine.resolve("shard.bin").read_bytes() \
+                == payload
+        shard_loops = {s.shards.loop(1) for s in cluster.servers.values()}
+        assert loops == shard_loops
+        assert asyncio.get_running_loop() not in loops
+
+    run_with_new_cluster(3, _test, sm_factory=FileStoreStateMachine,
+                         properties=p)
+
+
+def test_a_sync_packet_waits_for_its_queued_write_before_the_force(
+        monkeypatch):
+    """A SYNC packet's write is queued like any other; the force behind it
+    runs once that write has landed, so it covers the packet's bytes."""
+    forced = []
+    force = FileChunkChannel.force
+
+    async def seen(self, metadata=False):
+        forced.append(self._end)
+        await force(self, metadata)
+
+    monkeypatch.setattr(FileChunkChannel, "force", seen)
+
+    async def _test(cluster):
+        leader = await cluster.wait_for_leader()
+        rt, primary = _chain_from_leader(cluster, leader)
+        async with cluster.new_client() as client:
+            out = await client.data_stream().stream(
+                _stream_cmd("sync.bin"), routing_table=rt, primary=primary)
+            await out.write_async(b"a" * P)
+            await out.write_async(b"b" * P, sync=True)
+            await out.write_async(b"c" * P)
+            reply = await out.close_async()
+            assert reply.success, reply.exception
+            await cluster.wait_applied(reply.log_index)
+        for div in cluster.divisions():
+            assert div.state_machine.resolve("sync.bin").read_bytes() == \
+                b"a" * P + b"b" * P + b"c" * P
+        # each peer: the SYNC packet's force saw both packets landed, then
+        # the CLOSE's saw all three
+        assert sorted(forced) == [2 * P] * 3 + [3 * P] * 3
+
+    run_with_new_cluster(3, _test, sm_factory=FileStoreStateMachine)
