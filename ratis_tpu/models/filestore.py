@@ -81,7 +81,7 @@ class _WriterLane:
     the whole queue as one batch, hands each channel its items in queue
     order (``FileChunkChannel._take``) and resolves the batch with one
     ``call_soon_threadsafe`` to each loop that queued into it.  Counted:
-    ``stream.write_batches`` a pass, ``stream.write_calls`` a ``pwritev``."""
+    ``stream.write_batches`` a pass."""
 
     def __init__(self, name: str) -> None:
         self.name = name
@@ -90,7 +90,6 @@ class _WriterLane:
         self._thread: Optional[threading.Thread] = None
         # (only this lane's thread adds to them)
         self.batches = TRACER.counter("stream.write_batches", name)
-        self.calls = TRACER.counter("stream.write_calls", name)
 
     def submit(self, channel: "FileChunkChannel",
                data: Optional[bytes]) -> asyncio.Future:
@@ -276,7 +275,6 @@ class FileChunkChannel(DataChannel):
         nothing."""
         while chunks:
             n = os.pwritev(self._fd, chunks[:_IOV_MAX], self._end)
-            self._lane.calls.n += 1
             if n == 0:
                 return
             self._end += n

@@ -58,7 +58,7 @@ from ratis_tpu.server.leader import FollowerInfo, LeaderContext
 from ratis_tpu.server.log.base import DATA_CACHE_LAG
 from ratis_tpu.server.state import ServerState
 from ratis_tpu.server.statemachine import StateMachine, TransactionContext
-from ratis_tpu.trace.tracer import (STAGE_APPEND, STAGE_APPLY,
+from ratis_tpu.trace.tracer import (LAYER_SM, STAGE_APPEND, STAGE_APPLY,
                                     STAGE_APPLY_QUEUE, STAGE_FANOUT,
                                     STAGE_FLUSH_WAIT, STAGE_FOLLOWER,
                                     STAGE_QUORUM_WAIT, STAGE_REPLICATE,
@@ -2197,11 +2197,15 @@ class Division:
         return await self._query(req)
 
     async def _query(self, req: RaftClientRequest) -> RaftClientReply:
+        entered = TRACER.enter_layer(LAYER_SM) if TRACER.enabled else None
         try:
             result = await self.state_machine.query(req.message)
         except Exception as e:
             return RaftClientReply.failure_reply(
                 req, StateMachineException(str(e), cause=e))
+        finally:
+            if entered is not None:
+                TRACER.leave_layer(entered)
         return RaftClientReply.success_reply(req, message=result,
                                              log_index=self._applied_index)
 
@@ -2559,6 +2563,9 @@ class Division:
             if trx is None or trx.log_entry is None \
                     or trx.log_entry.term_index() != entry.term_index():
                 trx = TransactionContext(log_entry=entry)
+            # the link and the apply are the state machine's time on a timed
+            # loop, whichever task runs them
+            entered = TRACER.enter_layer(LAYER_SM) if TRACER.enabled else None
             # DataStream link (StateMachine.DataApi.link, §3.5): tie the
             # bytes this peer streamed to the committed entry before apply.
             # A replica that holds no local stream for a DATA_STREAM entry
@@ -2591,6 +2598,8 @@ class Division:
                 self.sm_metrics.applied_count.inc()
             except Exception as e:
                 exception = StateMachineException(str(e), cause=e)
+            if entered is not None:
+                TRACER.leave_layer(entered)
             # Populate the retry cache on EVERY role at apply time so a
             # request retried against the post-failover leader is deduped
             # (reference RetryCacheImpl failover-safe dedupe).

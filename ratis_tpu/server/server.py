@@ -31,6 +31,8 @@ from ratis_tpu.protocol.requests import (DEFERRED_REPLY, RaftClientReply,
 from ratis_tpu.protocol.termindex import TermIndex
 from ratis_tpu.server.division import Division
 from ratis_tpu.server.statemachine import StateMachine
+from ratis_tpu.trace.tracer import (LAYER_CONSENSUS, LAYER_EDGE, LAYER_READS,
+                                    LAYER_STREAM, TRACER)
 from ratis_tpu.transport.base import ServerTransport, TransportFactory
 from ratis_tpu.util.lifecycle import LifeCycle, LifeCycleState
 
@@ -1271,6 +1273,9 @@ class RaftServer:
 
     async def _handle_server_rpc(self, msg):
         from ratis_tpu.protocol.raftrpc import BulkHeartbeat
+        if TRACER.enabled:
+            # appends, heartbeats, votes: the loop's time is consensus's
+            TRACER.dispatch(LAYER_CONSENSUS)
         if isinstance(msg, AppendEnvelope):
             return await self._handle_append_envelope(msg)
         if isinstance(msg, BulkHeartbeat):
@@ -1575,10 +1580,16 @@ class RaftServer:
     async def _handle_client_request(self, request: RaftClientRequest
                                      ) -> RaftClientReply:
         from ratis_tpu.protocol.requests import RequestType
-        from ratis_tpu.trace.tracer import INGRESS_NS, STAGE_ROUTE, TRACER
+        from ratis_tpu.trace.tracer import INGRESS_NS, STAGE_ROUTE
         trace_t0 = 0
         route = None
         if TRACER.enabled:
+            # the request's task is the read path's for a read, the
+            # server edge's for anything else
+            TRACER.dispatch(
+                LAYER_READS if request.type.type in (RequestType.READ,
+                                                     RequestType.STALE_READ)
+                else LAYER_EDGE)
             # route starts at transport ingress when the transport stamped
             # it (captures the ingress->handler scheduling hop), else here
             ingress = INGRESS_NS.get()
@@ -1668,9 +1679,11 @@ class RaftServer:
         the CLOSE): ``server.route`` is the synchronous part up to the
         division's submit, and the egress mark is where the stream server's
         ``server.respond`` starts."""
-        from ratis_tpu.trace.tracer import STAGE_ROUTE, TRACER
-        route = (TRACER.begin(STAGE_ROUTE, request.trace_id)
-                 if TRACER.enabled else None)
+        from ratis_tpu.trace.tracer import STAGE_ROUTE
+        route = None
+        if TRACER.enabled:
+            TRACER.dispatch(LAYER_STREAM)
+            route = TRACER.begin(STAGE_ROUTE, request.trace_id)
         try:
             try:
                 div = self.get_division(request.group_id)

@@ -43,11 +43,17 @@ session's two ends; :meth:`Tracer.session` gives the delta.
 
 from __future__ import annotations
 
+import collections
 import contextvars
 import itertools
+import os
 import threading
 import time
 import types
+from asyncio import Task as _Task
+from asyncio import current_task as _current_task
+from asyncio.events import _get_running_loop
+from asyncio.tasks import _PyTask
 
 import numpy as np
 
@@ -187,6 +193,64 @@ STAGE_KINDS = (
 # sampling never wraps (server.route is one row per traced request and
 # keeps the plain size).
 WORK_RING_FACTOR = 4
+
+# The layers a timed loop's busy time is charged to (LoopClock, below): each
+# callback and each work span has exactly one.  docs/tracing.md, "Loop time
+# by layer", has the table.
+LAYER_NAMES = ("edge", "wire", "consensus", "log", "engine", "reads",
+               "stream", "sm", "other")
+(LAYER_EDGE, LAYER_WIRE, LAYER_CONSENSUS, LAYER_LOG, LAYER_ENGINE,
+ LAYER_READS, LAYER_STREAM, LAYER_SM, LAYER_OTHER) = range(len(LAYER_NAMES))
+
+# Module -> layer, by the longest matching prefix of the module's name;
+# every module under ratis_tpu/ falls under one (the package's own root is
+# the server's edge).  Code of no ratis_tpu module is ``other``.
+MODULE_LAYERS = (
+    ("ratis_tpu", "edge"),
+    ("ratis_tpu.transport", "wire"),
+    ("ratis_tpu.protocol", "wire"),
+    ("ratis_tpu.server.division", "consensus"),
+    ("ratis_tpu.server.leader", "consensus"),
+    ("ratis_tpu.server.replication", "consensus"),
+    ("ratis_tpu.server.election", "consensus"),
+    ("ratis_tpu.server.state", "consensus"),
+    ("ratis_tpu.server.upkeep", "consensus"),
+    ("ratis_tpu.server.watchdog", "consensus"),
+    ("ratis_tpu.conf.reconfiguration", "consensus"),
+    ("ratis_tpu.server.log", "log"),
+    ("ratis_tpu.server.storage", "log"),
+    ("ratis_tpu.engine", "engine"),
+    ("ratis_tpu.ops", "engine"),
+    ("ratis_tpu.parallel", "engine"),
+    ("ratis_tpu.server.read", "reads"),
+    ("ratis_tpu.server.serving.readbatch", "reads"),
+    ("ratis_tpu.server.datastream", "stream"),
+    ("ratis_tpu.transport.datastream", "stream"),
+    ("ratis_tpu.server.messagestream", "stream"),
+    ("ratis_tpu.server.statemachine", "sm"),
+    ("ratis_tpu.server.snapshot", "sm"),
+    ("ratis_tpu.models", "sm"),
+)
+
+# Each stage's layer: a work span switches a timed loop's clock to it for
+# its length.  loop.select, the selector's own time (``loop.select_ns``),
+# has none.
+STAGE_LAYERS = (
+    "edge", "wire", "wire", "wire",
+    "edge", "sm", "log",
+    "consensus", "sm", "edge", "edge",
+    "engine", "edge",
+    "log", "consensus", "consensus",
+    "consensus", "consensus", "consensus", "consensus",
+    "log", "log", "log",
+    "engine", "engine", "engine", "engine",
+    "wire", None, "wire",
+    "log", "sm", "sm",
+    "wire", "wire",
+    "stream", "stream", "stream", "stream", "stream", "stream",
+)
+_STAGE_LAYER = tuple(-1 if n is None else LAYER_NAMES.index(n)
+                     for n in STAGE_LAYERS)
 
 # Stages whose durations tile the per-request path (no mutual overlap):
 # these are the ones the decomposition's coverage fraction sums.
@@ -387,10 +451,19 @@ class Tracer:
         self._counters_on = {k: c.n for k, c in self._counters.items()}
         self._session = dict(_no_session(), t_on=time.monotonic_ns())
         self.enabled = True
+        clock = _hooked_clock(_get_running_loop())
+        if clock is not None:
+            # opened on a loop that is timed by layer: from now on, not from
+            # its next selector wait
+            clock.start(self._session["t_on"])
 
     def _close(self) -> None:
         self.enabled = False
         self.annotate = False
+        clock = _hooked_clock(_get_running_loop())
+        if clock is not None and clock.active:
+            # what this loop has run since its last mark is the session's
+            clock.flush()
         self._session.update(self._deltas(), t_off=time.monotonic_ns())
 
     def profile_dir_session(self, directory: str, start: bool) -> None:
@@ -494,30 +567,40 @@ class Tracer:
         """Open a work span (call only while ``enabled``).  ``trace_id`` -1
         is a process-level span, sampled by the stage's own stride unless
         ``always``; >= 0 a request's (0: not sampled, so no ring row).
-        While the profiler is on every span is annotated, sampled or not.
-        Returns what :meth:`end` takes, or None when there is nothing to
-        record."""
+        While the profiler is on every span is annotated, sampled or not;
+        on a timed loop (:class:`LoopClock`) every span charges the loop's
+        time to the stage's layer until it ends.  Returns what :meth:`end`
+        takes, or None when there is nothing to record."""
         if trace_id < 0:
             row = always or self.sample(stage)
             trace_id = 0
         else:
             row = trace_id > 0
+        clock = None
+        layer = _STAGE_LAYER[stage]
+        if layer >= 0:
+            clock = loop_clock()
+            if clock is not None:
+                clock.push(layer)
         ann = None
         if self.annotate:
             ann = self._annotation(_ANNOTATION_NAMES[stage])
             ann.__enter__()
-        elif not row:
+        elif not row and clock is None:
             return None
-        # (an annotation without a ring row needs no clock of ours)
-        return (stage, trace_id, row, ann, time.monotonic_ns() if row else 0)
+        # (an annotation without a ring row needs no timestamp of ours)
+        return (stage, trace_id, row, ann, time.monotonic_ns() if row else 0,
+                clock)
 
     def end(self, span, tag: int = 0, t0_ns: int = 0) -> int:
         """Close a work span; ``t0_ns`` moves the ring row's start (a route
         span starts at the transport's ingress stamp).  Returns the end of
         a span that has a ring row, else 0."""
-        stage, trace_id, row, ann, t0 = span
+        stage, trace_id, row, ann, t0, clock = span
         if ann is not None:
             ann.__exit__(None, None, None)
+        if clock is not None:
+            clock.pop()
         if not row:
             return 0
         t1 = time.monotonic_ns()
@@ -562,6 +645,35 @@ class Tracer:
                 except StopIteration as s:
                     return s.value
 
+    def dispatch(self, layer: int) -> None:
+        """The server's dispatch has told what a request is: the running
+        task's steps from here on, and this loop's time until it suspends,
+        belong to ``layer`` (LAYER_*).  Call only while ``enabled``."""
+        LOOP_LAYER.set((layer, id(_current_task())))
+        clock = loop_clock()
+        if clock is not None:
+            clock.switch(layer)
+
+    def enter_layer(self, layer: int):
+        """The running task's time from here to :meth:`leave_layer`, up to
+        its first suspension, belongs to ``layer``: for a call a task awaits
+        (a state machine's apply or query), which no work span may hold.
+        Returns what :meth:`leave_layer` takes, None where the running loop
+        is not timed.  Call only while ``enabled``."""
+        clock = loop_clock()
+        if clock is None:
+            return None
+        entered = (clock, clock.cur)
+        clock.switch(layer)
+        return entered
+
+    def leave_layer(self, entered) -> None:
+        """Give the task's time back to the layer it had at
+        :meth:`enter_layer` (unless the session closed meanwhile)."""
+        clock, layer = entered
+        if loop_clock() is clock:
+            clock.switch(layer)
+
     def mark_egress(self, trace_id: int) -> None:
         """Server handler is done with this request NOW; the transport pops
         the mark to record the respond span (serialize + hand-back/socket
@@ -603,6 +715,10 @@ _ANNOTATION_NAMES = tuple("ratis:" + n for n in STAGE_NAMES)
 
 TRACER = Tracer()
 
+_monotonic_ns = time.monotonic_ns
+_popleft = collections.deque.popleft
+_TASK_TYPES = frozenset((_Task, _PyTask))
+
 
 profile_dir_session = TRACER.profile_dir_session
 
@@ -639,7 +755,10 @@ def instrument_loop(loop) -> bool:
     two adds per loop iteration).  Installed once per loop, where the loop's
     owner starts on it (``RaftServer.start``, a shard's thread); a selector
     wait that may block is also the ``loop.select`` work span.  What is not
-    selector time is the loop running callbacks: its busy share.  A loop
+    selector time is the loop running callbacks: its busy share.  While a
+    session is open the loop is also timed by layer (:class:`LoopClock`):
+    the hook swaps the loop's ready queue for a timed one at the first
+    select of a session and a plain one back at the first after it.  A loop
     without a ``_selector`` (not a selector event loop) is left alone."""
     selector = getattr(loop, "_selector", None)
     inner = getattr(selector, "select", None)
@@ -649,23 +768,19 @@ def instrument_loop(loop) -> bool:
     select_ns = TRACER.counter("loop.select_ns", key)
     iterations = TRACER.counter("loop.iterations", key)
     clock = time.monotonic_ns
+    timed = LoopClock(loop, key, inner, select_ns, iterations)
 
     def select(timeout=None):
         t0 = clock()
-        if timeout != 0 and TRACER.enabled:
-            span = TRACER.begin(STAGE_SELECT)
-            try:
-                events = inner(timeout)
-            finally:
-                if span is not None:
-                    TRACER.end(span)
-        else:
-            events = inner(timeout)
+        if TRACER.enabled or timed.active:
+            return timed.select(timeout, t0)
+        events = inner(timeout)
         select_ns.n += clock() - t0
         iterations.n += 1
         return events
 
     select.ratis_timed = True
+    select.ratis_clock = timed
     try:
         selector.select = select
     except AttributeError:
@@ -683,3 +798,266 @@ def loop_key(loop=None) -> str:
         except RuntimeError:
             return ""
     return f"loop-{id(loop):x}"
+
+
+# ---------------------------------------------------- the loop's time by layer
+
+# The layer the server's dispatch named for a task (Tracer.dispatch), with
+# the id of the task it was named in: a task the handler starts inherits the
+# context but not the name.  (An id, not the task: the task's own context
+# holding the task would make every request's task cyclic garbage.)
+LOOP_LAYER: contextvars.ContextVar = contextvars.ContextVar(
+    "ratis_loop_layer", default=None)
+
+
+def _hooked_clock(loop) -> "LoopClock | None":
+    """``loop``'s layer clock where :func:`instrument_loop` hooked it."""
+    select = getattr(getattr(loop, "_selector", None), "select", None)
+    return getattr(select, "ratis_clock", None)
+
+
+def loop_clock() -> "LoopClock | None":
+    """The running loop's layer clock while its pass is timed, else None."""
+    ready = getattr(_get_running_loop(), "_ready", None)
+    return ready.clock if type(ready) is _TimedReady else None
+
+
+class _TimedReady(collections.deque):
+    """A loop's ready queue while a session is open: handing out a callback
+    charges the time since the clock's last mark to its current layer and
+    makes the callback's owner current.  asyncio's ``_run_once`` takes every
+    callback it runs, selector callbacks and timers included, with
+    ``popleft``."""
+
+    __slots__ = ("clock",)
+
+    def popleft(self):
+        handle = _popleft(self)
+        # the owner: _owner, with the cached cases inline
+        cb = handle._callback
+        obj = getattr(cb, "__self__", None)
+        if obj.__class__ in _TASK_TYPES:
+            named = handle._context.get(LOOP_LAYER)
+            if named is not None and named[1] == id(obj):
+                owner = named[0]
+            else:
+                try:
+                    owner = _BY_FILE[obj.get_coro().cr_code.co_filename]
+                except (AttributeError, KeyError):
+                    owner = _owner(cb, obj)
+        elif obj is None:
+            try:
+                owner = _BY_MODULE[cb.__module__]
+            except (AttributeError, KeyError):
+                owner = _owner(cb, obj)
+        else:
+            owner = _BY_TYPE.get(obj.__class__, -1)
+            if owner < 0:
+                owner = _owner(cb, obj)
+        c = self.clock
+        if owner != c.cur:
+            # (the clock is read only where the layer changes)
+            t = _monotonic_ns()
+            c.ns[c.cur].n += t - c.mark
+            c.mark = t
+            c.cur = owner
+        return handle
+
+
+class LoopClock:
+    """Charges one loop's busy time to the layer that owns it, while a
+    session is open: ``loop.layer_ns``, keyed ``<loop key>/<layer>``.  Each
+    callback is charged to its owner (:func:`_owner`) from the ready queue
+    handing it out to the next hand-out (the clock is read where the layer
+    changes); a work span on the loop's thread charges its stage's layer for
+    its length (:meth:`Tracer.begin`); the server's dispatch names a
+    request's layer (:meth:`Tracer.dispatch`), and a state machine's call
+    its own (:meth:`Tracer.enter_layer`); what follows a selector wait up to
+    the first callback (asyncio's own pass) is ``other``.  Over a session,
+    Σ ``loop.layer_ns`` + ``loop.select_ns`` is its length.  Only the loop's
+    own thread touches it."""
+
+    __slots__ = ("loop", "inner", "select_ns", "iterations", "ns", "cur",
+                 "mark", "stack", "active", "_stale")
+
+    def __init__(self, loop, key: str, inner, select_ns: Count,
+                 iterations: Count):
+        self.loop = loop
+        self.inner = inner          # the selector's own select
+        self.select_ns, self.iterations = select_ns, iterations
+        self.ns = [TRACER.counter("loop.layer_ns", f"{key}/{n}")
+                   for n in LAYER_NAMES]
+        self.cur = LAYER_OTHER
+        self.mark = 0
+        self.stack: list[int] = []
+        self.active = False     # timed, or a swapped-out queue to drain
+        self._stale = None
+
+    def select(self, timeout, t0: int):
+        """The selector hook's path while a session is open or was."""
+        inner = self.inner
+        stale, self._stale = self._stale, None
+        if stale:
+            _drain(stale, self.loop._ready)
+        if TRACER.enabled:
+            if type(self.loop._ready) is _TimedReady:
+                # the rest of the pass: the last callback's
+                self.ns[self.cur].n += t0 - self.mark
+                self.stack.clear()  # (no work span outlives its callback)
+            else:
+                self.start(t0)
+            if timeout != 0:
+                span = TRACER.begin(STAGE_SELECT)
+                try:
+                    events = inner(timeout)
+                finally:
+                    if span is not None:
+                        TRACER.end(span)
+            else:
+                events = inner(timeout)
+        else:
+            if type(self.loop._ready) is _TimedReady:
+                self._remove()
+            else:
+                self.active = False
+            events = inner(timeout)
+        t1 = _monotonic_ns()
+        self.select_ns.n += t1 - t0
+        self.iterations.n += 1
+        self.mark = t1
+        self.cur = LAYER_OTHER
+        return events
+
+    def start(self, t: int) -> None:
+        """Time the loop from ``t``: its ready queue becomes a timed one
+        (here, or mid-pass: ``_run_once`` takes each callback from
+        ``loop._ready`` anew)."""
+        old = self.loop._ready
+        ready = _TimedReady()
+        ready.clock = self
+        if self._stale:     # (a queue swapped out at the last close)
+            _drain(self._stale, ready)
+        _drain(old, ready)
+        self.loop._ready = ready
+        self._stale = old
+        self.stack.clear()
+        self.active = True
+        self.mark = t
+        self.cur = LAYER_OTHER
+
+    def _remove(self) -> None:
+        old = self.loop._ready
+        ready = collections.deque()
+        _drain(old, ready)
+        self.loop._ready = ready
+        self._stale = old       # drained at the next pass, then inactive
+        self.stack.clear()
+
+    def flush(self) -> None:
+        """Charge everything up to now (the session closes on this loop)."""
+        if type(self.loop._ready) is _TimedReady:
+            self.switch(self.cur)
+
+    def switch(self, layer: int) -> None:
+        """Charge up to now and make ``layer`` current."""
+        t = _monotonic_ns()
+        self.ns[self.cur].n += t - self.mark
+        self.mark = t
+        self.cur = layer
+
+    def push(self, layer: int) -> None:
+        """A work span of ``layer`` begins (the clock is read only where
+        that changes the layer)."""
+        cur = self.cur
+        self.stack.append(cur)
+        if layer != cur:
+            t = _monotonic_ns()
+            self.ns[cur].n += t - self.mark
+            self.mark = t
+            self.cur = layer
+
+    def pop(self) -> None:
+        """The innermost work span ends."""
+        if not self.stack:
+            return
+        layer = self.stack.pop()
+        cur = self.cur
+        if layer != cur:
+            t = _monotonic_ns()
+            self.ns[cur].n += t - self.mark
+            self.mark = t
+            self.cur = layer
+
+
+def _drain(src, dst) -> None:
+    """Move what ``src`` holds to ``dst``'s end, in order (one at a time: a
+    thread may still append to ``src``)."""
+    while src:
+        dst.append(_popleft(src))
+
+
+_BY_MODULE: dict = {}   # module name -> layer
+_BY_FILE: dict = {}     # a coroutine's source file -> layer
+_BY_TYPE: dict = {}     # a bound method's class -> layer (-1: a transport)
+_PACKAGE_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_MODULE_LAYERS = sorted(((m, LAYER_NAMES.index(n)) for m, n in MODULE_LAYERS),
+                        key=lambda ml: -len(ml[0]))
+
+
+def module_layer(name) -> int:
+    """The layer of the module called ``name`` (MODULE_LAYERS)."""
+    layer = _BY_MODULE.get(name)
+    if layer is None:
+        layer = LAYER_OTHER
+        if isinstance(name, str):
+            for prefix, lay in _MODULE_LAYERS:
+                if name == prefix or name.startswith(prefix + "."):
+                    layer = lay
+                    break
+        _BY_MODULE[name] = layer
+    return layer
+
+
+def _file_layer(path) -> int:
+    """The layer of the module in source file ``path``."""
+    layer = _BY_FILE.get(path)
+    if layer is None:
+        name = None
+        if isinstance(path, str) and path.endswith(".py") and \
+                path.startswith(_PACKAGE_DIR + os.sep):
+            rel = path[len(_PACKAGE_DIR) + 1:-3].replace(os.sep, ".")
+            name = "ratis_tpu." + rel.removesuffix(".__init__")
+        layer = _BY_FILE[path] = module_layer(name)
+    return layer
+
+
+def _type_layer(cls) -> int:
+    layer = _BY_TYPE.get(cls)
+    if layer is None:
+        module = getattr(cls, "__module__", None)
+        layer = module_layer(module)
+        if layer == LAYER_OTHER and isinstance(module, str) and \
+                module.startswith("asyncio.") and \
+                hasattr(cls, "get_protocol"):
+            layer = -1
+        _BY_TYPE[cls] = layer
+    return layer
+
+
+def _owner(cb, obj) -> int:
+    """The layer of the callback ``cb``, bound to ``obj`` (or None): a
+    task's step or wake-up is its task's (the dispatch's name, which
+    ``_TimedReady.popleft`` reads, else its coroutine's module); a selector
+    callback of an asyncio transport its protocol's; a bound method its
+    class's module's; a function its module's."""
+    if obj is None:
+        return module_layer(getattr(cb, "__module__", None))
+    if type(obj) in _TASK_TYPES:
+        coro = obj.get_coro()
+        code = getattr(coro, "cr_code", None) or getattr(coro, "gi_code", None)
+        return _file_layer(getattr(code, "co_filename", None))
+    layer = _type_layer(type(obj))
+    if layer < 0:
+        layer = _type_layer(type(getattr(obj, "_protocol", None)))
+        return LAYER_OTHER if layer < 0 else layer
+    return layer

@@ -49,7 +49,8 @@ from ratis_tpu.protocol.requests import (DEFERRED_REPLY, RaftClientReply,
                                          attach_reply_sink)
 from ratis_tpu.trace.tracer import (INGRESS_NS, STAGE_DECODE, STAGE_ENCODE,
                                     STAGE_RESPOND, STAGE_TCP_READ, STAGE_WIRE,
-                                    STAGE_WIRE_FLUSH, TRACER, loop_key)
+                                    STAGE_WIRE_FLUSH, TRACER, loop_clock,
+                                    loop_key)
 from ratis_tpu.transport.base import (ClientRequestHandler, ClientTransport,
                                       ServerRpcHandler, ServerTransport,
                                       TransportFactory)
@@ -551,8 +552,14 @@ class _Accepted(_FramedProtocol):
             self.fanout = _DeferredReplyFanout(self)
 
     def _frame(self, call_seq: int, kind: int, body: bytes) -> None:
+        clock = loop_clock() if TRACER.enabled else None
+        layer = clock.cur if clock is not None else 0
         t = asyncio.Task(self._server._serve_one(call_seq, kind, body, self),
                          loop=self.loop, eager_start=True)
+        if clock is not None:
+            # the handler's first step named its own layer (the server's
+            # dispatch); the rest of this read is the wire's again
+            clock.switch(layer)
         if not t.done():        # it suspended: keep it (the loop holds weakly)
             self._tasks.add(t)
             t.add_done_callback(self._tasks.discard)

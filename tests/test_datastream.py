@@ -361,8 +361,17 @@ def test_a_lane_writes_interleaved_streams_each_in_order(tmp_path):
     asyncio.run(_run())
 
 
-def test_a_lane_pass_resolves_what_it_met_with_one_loop_callback(tmp_path):
+def test_a_lane_pass_resolves_what_it_met_with_one_loop_callback(
+        tmp_path, monkeypatch):
     held, entered = threading.Event(), threading.Event()
+    pwritev, writes = filestore.os.pwritev, []
+
+    def counted_pwritev(*args):
+        if threading.current_thread().name == "filestore-test-batch":
+            writes.append(1)
+        return pwritev(*args)
+
+    monkeypatch.setattr(filestore.os, "pwritev", counted_pwritev)
 
     class HeldChannel(FileChunkChannel):
         def _append(self, chunks):
@@ -394,7 +403,7 @@ def test_a_lane_pass_resolves_what_it_met_with_one_loop_callback(tmp_path):
             closing = asyncio.create_task(chans[0].close())
             await asyncio.sleep(0)
             assert not closing.done() and chans[0]._fd >= 0
-            batches, calls = lane.batches.n, lane.calls.n
+            batches, calls = lane.batches.n, len(writes)
         finally:
             held.set()
         assert await first == 1
@@ -405,7 +414,7 @@ def test_a_lane_pass_resolves_what_it_met_with_one_loop_callback(tmp_path):
         # file, and one call back to the loop (the held pass adds its
         # pwritev and its call)
         assert lane.batches.n - batches == 1
-        assert lane.calls.n - calls == 3 + 1
+        assert len(writes) - calls == 3 + 1
         assert len(callbacks) == 2
         for k, chan in enumerate(chans):
             await chan.close()

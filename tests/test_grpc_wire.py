@@ -77,7 +77,9 @@ def test_an_unknown_rpc_type_still_raises_with_the_known_list():
 def _writes_one_after_another(rpc_type: str, n: int = WRITES) -> dict:
     """``n`` INCREMENTs to one group of a 3-peer cluster over ``rpc_type``,
     each sent when the last is acknowledged, inside one trace session whose
-    counters' deltas come back (servers and client share the process)."""
+    counters' deltas come back (servers and client share the process), with
+    the wires' rows taken at the same moment (``rows``: what the cluster
+    sends as it closes counts in neither)."""
     tracer = get_tracer()
     out = {}
 
@@ -89,6 +91,8 @@ def _writes_one_after_another(rpc_type: str, n: int = WRITES) -> dict:
             for _ in range(n):
                 assert (await client.io().send(b"INCREMENT")).success
             out.update(tracer.session()["counters"])
+            out["rows"] = {name: _rows(tracer, name) for name in
+                           ("grpc.read", "grpc.write", "wire.flush")}
 
     run_with_new_cluster(3, body, rpc_type=rpc_type)
     return out
@@ -112,9 +116,8 @@ def test_the_wire_counters_count_every_grpc_message_on_every_path():
 
 def test_a_trace_session_holds_grpc_read_and_write_rows_on_the_loop():
     import threading
-    tracer = get_tracer()
     c = _writes_one_after_another("GRPC")
-    reads, writes = _rows(tracer, "grpc.read"), _rows(tracer, "grpc.write")
+    reads, writes = c["rows"]["grpc.read"], c["rows"]["grpc.write"]
     # every stream message has a row at sample-every 1 (unary ones have none)
     assert len(writes) >= 5 * WRITES and len(reads) >= 5 * WRITES
     assert len(writes) <= c["grpc.messages_out"]
@@ -129,14 +132,13 @@ def test_a_trace_session_holds_grpc_read_and_write_rows_on_the_loop():
 
 def test_the_tcp_counters_of_the_same_writes_are_what_they_were():
     n = WRITES
-    tracer = get_tracer()
     c = _writes_one_after_another("TCP")
     assert c["wire.frames"] >= 5 * n
     assert c["wire.bytes"] >= 5 * n * len(b"INCREMENT")
     # counted at the socket write: every frame went out in a wire.flush span
-    assert sum(r[3] for r in _rows(tracer, "wire.flush")) == c["wire.frames"]
+    assert sum(r[3] for r in c["rows"]["wire.flush"]) == c["wire.frames"]
     assert not any(c.get(name) for name in GRPC_COUNTERS)
-    assert not _rows(tracer, "grpc.read") and not _rows(tracer, "grpc.write")
+    assert not c["rows"]["grpc.read"] and not c["rows"]["grpc.write"]
 
 
 def test_a_head_span_covers_the_synchronous_head_of_an_awaitable():

@@ -173,25 +173,34 @@ def test_export_helpers_on_synthetic_records():
 
 # ------------------------------------------------------------ overhead guard
 
+# alternating untraced/traced rung pairs of the overhead guard
+OVERHEAD_PAIRS = 7
+
+
 def test_tracing_overhead_within_tolerance():
     """Traced (sample-every=4) vs untraced throughput on the same small sim
     rung.  The bound is deliberately loose (50%) — the point is catching a
     pathological regression (e.g. tracing work on the untraced path), not
-    benchmarking; single-trial small rungs on shared CI scatter widely."""
+    benchmarking.  Single small rungs on a shared machine scatter widely, so
+    untraced and traced rungs alternate and their medians are compared."""
+    import statistics
+
     from ratis_tpu.tools.bench_cluster import run_bench
     tracer = get_tracer()
-    tracer.configure(enabled=False)
 
     async def rung(trace: bool):
         return await run_bench(2, 48, batched=False, concurrency=16,
                                transport="sim", warmup_writes=4,
                                trace=trace, trace_sample=4)
 
-    untraced = asyncio.run(rung(False))
-    tracer.configure(enabled=False)  # fresh state for the traced rung
-    traced = asyncio.run(rung(True))
-    assert traced["commits_per_sec"] >= untraced["commits_per_sec"] * 0.5, \
-        (traced["commits_per_sec"], untraced["commits_per_sec"])
+    rates = {False: [], True: []}
+    for _ in range(OVERHEAD_PAIRS):
+        for trace in (False, True):
+            tracer.configure(enabled=False)  # fresh state for each rung
+            rates[trace].append(asyncio.run(rung(trace))["commits_per_sec"])
+    untraced, traced = (statistics.median(rates[False]),
+                        statistics.median(rates[True]))
+    assert traced >= untraced * 0.5, rates
 
 
 # ------------------------------------------- one session, where the work is
