@@ -14,6 +14,7 @@ streamed bytes to the committed entry (FileStoreStateMachine.java:196-216).
 from __future__ import annotations
 
 import asyncio
+import collections
 import logging
 import time
 from typing import Dict, Optional, Tuple
@@ -56,6 +57,18 @@ def _consume_result(fut: asyncio.Future) -> None:
         fut.exception()
 
 
+def _reply(conn: PeerConnection, packet: Packet, ok: bool,
+           data: bytes = b"") -> None:
+    """Queue ``packet``'s ack on the connection it came by (dropped if that
+    connection has died: nobody is left to tell); while the connection's
+    writes are paused, it stops reading."""
+    if conn.dead is not None:
+        return
+    flags = packet.flags | FLAG_SUCCESS if ok else packet.flags & ~FLAG_SUCCESS
+    conn.send(Packet(KIND_REPLY, packet.stream_id, packet.offset, flags, data),
+              conn)
+
+
 class StreamInfo:
     """One receiving stream on one peer (reference StreamInfo:88-193)."""
 
@@ -69,14 +82,112 @@ class StreamInfo:
         self.bytes_written = 0
         self.closed = False
         self.touched_s = time.monotonic()
-        # in-flight packet completions (successor acks being awaited while
-        # later packets already write — the pipeline); CLOSE drains these
-        self.pending: set[asyncio.Task] = set()
+        # DATA packets whose ack is not yet sent (_PacketAck: the local write
+        # or a successor's ack outstanding — the pipeline); CLOSE drains
+        # them through ``answered``, which the last one to finish resolves
+        self.open_acks = 0
+        self._drained: Optional[asyncio.Future] = None
         self.failed: Optional[Exception] = None
         # loop shard owning this stream's handling (the owning division's
         # shard when the plane is shard-pinned; None = primary loop) —
-        # cleanup must unwind the stream's tasks/connections on this loop
+        # cleanup must unwind the stream's connections on this loop
         self.shard: Optional[int] = None
+
+    def ack_done(self) -> None:
+        self.open_acks -= 1
+        if self.open_acks == 0 and self._drained is not None:
+            drained, self._drained = self._drained, None
+            if not drained.done():
+                drained.set_result(None)
+
+    async def answered(self) -> None:
+        """Wait until every DATA packet so far has been answered."""
+        if self.open_acks > 0:
+            self._drained = asyncio.get_running_loop().create_future()
+            await self._drained
+
+    def abandon(self) -> None:
+        """Cleanup: a CLOSE that waits for the pipeline waits no more."""
+        drained, self._drained = self._drained, None
+        if drained is not None:
+            drained.cancel()
+
+
+class _PacketAck:
+    """One DATA packet in the pipeline: its parts — the local write, one ack
+    a successor, and for a SYNC packet the force — each count one down as
+    they come in (done-callbacks, no task); at zero the success ack is
+    queued on the client's connection.  The first part to fail poisons the
+    stream and queues the failure reply; what comes in after only has its
+    outcome consumed."""
+
+    __slots__ = ("mgmt", "info", "packet", "conn", "t0", "left", "write",
+                 "acks")
+
+    def __init__(self, mgmt: "DataStreamManagement", info: StreamInfo,
+                 packet: Packet, conn: PeerConnection, t0: int,
+                 write: asyncio.Future, acks: list, extra: int) -> None:
+        self.mgmt, self.info, self.packet = mgmt, info, packet
+        self.conn, self.t0 = conn, t0
+        self.left = 1 + len(acks) + extra
+        self.write, self.acks = write, acks
+        info.open_acks += 1
+        write.add_done_callback(self._write_done)
+        for fut in acks:
+            fut.add_done_callback(self._ack_done)
+
+    def _write_done(self, fut: asyncio.Future) -> None:
+        if self.left <= 0:
+            _consume_result(fut)
+            return
+        try:
+            self.mgmt._written(self.info, self.packet.data, fut.result())
+        except (Exception, asyncio.CancelledError) as e:
+            self.fail(e)
+            return
+        self.part_done()
+
+    def _ack_done(self, fut: asyncio.Future) -> None:
+        if self.left <= 0:
+            _consume_result(fut)
+            return
+        try:
+            reply = fut.result()
+            if not reply.success:
+                peer = next(r.peer_id for r, f in
+                            zip(self.info.remotes, self.acks) if f is fut)
+                raise DataStreamException(
+                    f"successor {peer} rejected stream "
+                    f"{self.packet.stream_id} offset {self.packet.offset}")
+        except (Exception, asyncio.CancelledError) as e:
+            self.fail(e)
+            return
+        self.part_done()
+
+    def part_done(self) -> None:
+        if self.left <= 0:
+            return              # (failed already)
+        self.left -= 1
+        if self.left:
+            return
+        packet, info = self.packet, self.info
+        self.mgmt.metrics.bytes_written.inc(len(packet.data))
+        _reply(self.conn, packet, True)
+        TRACER.interval(STAGE_STREAM_PACKET, self.t0, len(packet.data)
+                        if info.is_primary else -len(packet.data))
+        info.ack_done()
+
+    def fail(self, e: BaseException) -> None:
+        if self.left <= 0:
+            return
+        self.left = 0
+        # poison the stream: later packets and the CLOSE must fail
+        self.info.failed = e if isinstance(e, Exception) \
+            else DataStreamException(f"stream {self.packet.stream_id}: {e!r}")
+        LOG.warning("datastream packet failed: %s", e)
+        self.mgmt.metrics.num_failed.inc()
+        _reply(self.conn, self.packet, False)
+        self.info.ack_done()
 
 
 class _RemoteStream:
@@ -93,21 +204,34 @@ class _RemoteStream:
 
     async def forward(self, packet: Packet) -> Packet:
         """Forward and await the successor's ack."""
-        reply = await (await self.send(packet))
+        reply = await self.conn.queue(packet)
         if not reply.success:
             raise DataStreamException(
                 f"successor {self.peer_id} rejected stream "
                 f"{packet.stream_id} offset {packet.offset}")
         return reply
 
-    async def send(self, packet: Packet) -> "asyncio.Future[Packet]":
-        """Put the packet on the successor's socket NOW (ordered per
-        connection) and return the ack future — the pipelined half of
-        :meth:`forward`."""
-        return await self.conn.send(packet)
-
     async def close(self) -> None:
         await self.conn.close()
+
+
+class _Inbound:
+    """One accepted connection's packets in read order: while a HEADER,
+    CLOSE or SYNC packet's task is suspended, the packets read behind it
+    wait here, and the connection stops reading once more than a client's
+    window of them wait."""
+
+    __slots__ = ("task", "backlog", "holding")
+
+    def __init__(self) -> None:
+        self.task: Optional[asyncio.Task] = None
+        self.backlog: collections.deque = collections.deque()
+        self.holding = False
+
+
+# packets waiting behind a task before the connection stops reading: the
+# client's window (client.py DataStreamOutput)
+_BACKLOG_PACKETS = 16
 
 
 class DataStreamManagement:
@@ -141,11 +265,28 @@ class DataStreamManagement:
         self._pin_shards = (server.shards is not None
                             and getattr(server, "stream_shards", True))
         self._stream_shards: Dict[int, int] = {}
+        self._sweeps: set[asyncio.Task] = set()   # expiry sweeps under way
+        self._sweep_timer: Optional[asyncio.TimerHandle] = None
 
     async def start(self) -> None:
         await self.transport.start()
+        self._arm_sweep()
+
+    def _arm_sweep(self) -> None:
+        """The expiry sweep's timer: every tenth of the expiry, besides the
+        one a HEADER runs."""
+        if self._expiry_s > 0:
+            self._sweep_timer = asyncio.get_running_loop().call_later(
+                self._expiry_s / 10, self._on_sweep_timer)
+
+    def _on_sweep_timer(self) -> None:
+        self._expire_idle()
+        self._arm_sweep()
 
     async def close(self) -> None:
+        if self._sweep_timer is not None:
+            self._sweep_timer.cancel()
+            self._sweep_timer = None
         self.metrics.unregister()
         await self.transport.close()
         for info in list(self._streams.values()):
@@ -158,42 +299,53 @@ class DataStreamManagement:
 
     # ------------------------------------------------------------- packets
 
-    async def _expire_idle(self) -> None:
+    def _expire_idle(self) -> None:
         """Reclaim streams whose client vanished mid-stream and links whose
-        raft entry never applied (lazy sweep, cf. MessageStreamRequests)."""
+        raft entry never applied (lazy sweep, cf. MessageStreamRequests):
+        run by a HEADER and by a timer, never by a DATA packet; the cleanup
+        a task only when something is due."""
         if self._expiry_s <= 0:
             return
         now = time.monotonic()
         if now - self._last_sweep_s < self._expiry_s / 10:
-            return  # keep the per-packet hot path O(1)
+            return  # at most one sweep a tenth of the expiry
         self._last_sweep_s = now
         deadline = now - self._expiry_s
+        expired = []
         for sid in [s for s, i in self._streams.items()
                     if i.touched_s < deadline]:
-            info = self._streams.pop(sid)
+            expired.append(self._streams.pop(sid))
             self._stream_shards.pop(sid, None)
             LOG.warning("expiring abandoned datastream %s", sid)
-            await self._cleanup(info)
         for key in [k for k, (_, t) in self._links.items() if t < deadline]:
-            info, _ = self._links.pop(key)
+            expired.append(self._links.pop(key)[0])
+        if expired:
+            t = asyncio.get_running_loop().create_task(
+                self._cleanup_all(expired))
+            self._sweeps.add(t)
+            t.add_done_callback(self._sweeps.discard)
+
+    async def _cleanup_all(self, infos: "list[StreamInfo]") -> None:
+        for info in infos:
             await self._cleanup(info)
 
-    async def _on_packet(self, packet: Packet, conn: PeerConnection) -> None:
-        """Accept-loop entry: route the packet to its stream's pinned loop
-        shard (the owning division's shard) and run the real handler
-        there; unsharded servers — or packets for unknown streams, whose
-        handling is just an error reply — stay on the accept loop.  The
-        read loop awaits this per packet, so per-stream packet order is
-        preserved across the hop."""
+    def _on_packet(self, packet: Packet, conn: PeerConnection) -> None:
+        """Accept-loop entry, inside the connection's read callback: route
+        the packet to its stream's pinned loop shard (the owning division's
+        shard) and handle it there; unsharded servers — or packets for
+        unknown streams, whose handling is just an error reply — stay on
+        the accept loop.  A hop to a shard is one ``call_soon_threadsafe``
+        a packet, in read order."""
         t0 = TRACER.now() if TRACER.enabled else 0   # off the socket
-        await self._expire_idle()
+        if packet.kind == KIND_HEADER:
+            self._expire_idle()
         if self._pin_shards:
             shard = self._route_shard(packet)
             if shard is not None:
-                await self.server.shards.run_on(
-                    shard, self._handle_packet(packet, conn, t0))
+                self.server.shards.call_soon(shard, self._in_order, packet,
+                                             conn, t0)
                 return
-        await self._handle_packet(packet, conn, t0)
+        self._in_order(packet, conn, t0)
 
     def _route_shard(self, packet: Packet) -> Optional[int]:
         """Loop shard owning ``packet``'s stream: registered at HEADER
@@ -210,59 +362,108 @@ class DataStreamManagement:
             return shard
         return self._stream_shards.get(packet.stream_id)
 
-    async def _handle_packet(self, packet: Packet, conn: PeerConnection,
-                             t0: int = 0) -> None:
-        """The real packet handler (on the stream's pinned loop when
-        sharded); ``t0`` is when the packet came off the socket, on the
-        tracer's clock (0: no session).  HEADER and CLOSE are handled fully
-        inline (once per stream).  DATA is PIPELINED: the ordered work — offset check,
-        local channel write, putting the forward copies on the successor
-        sockets — happens inline (so stream order is the read-loop
-        order), but awaiting the successor acks and answering the client
-        moves to a completion task, letting the read loop pull the next
-        packet immediately.  Serialized per-packet round-trips through the
-        whole fan-out chain were the measured throughput ceiling
-        (~0.7 MB/s aggregate at 64KB packets); the reference pipelines
-        exactly this way by chaining per-stream futures
-        (DataStreamManagement.java:85 writeTo/thenCombine chains)."""
+    def _in_order(self, packet: Packet, conn: PeerConnection,
+                  t0: int) -> None:
+        """A connection's packets are handled in read order: one that comes
+        while a task of the connection (HEADER, CLOSE, SYNC) is suspended
+        waits for it."""
+        inbound = conn.inbound
+        if inbound is None:
+            inbound = conn.inbound = _Inbound()
+        if inbound.task is not None:
+            inbound.backlog.append((packet, t0))
+            if len(inbound.backlog) > _BACKLOG_PACKETS \
+                    and not inbound.holding:
+                inbound.holding = True
+                conn.hold_reading(inbound)
+            return
+        self._handle(packet, conn, t0, inbound)
+
+    def _handle(self, packet: Packet, conn: PeerConnection, t0: int,
+                inbound: _Inbound) -> None:
+        """``t0`` is when the packet came off the socket, on the tracer's
+        clock (0: no session).  DATA is handled here, in the read callback:
+        the offset checked, the local write queued, the copies put on the
+        successors' connections, and a :class:`_PacketAck` left to answer
+        the client once all of them are in.  Serialized per-packet
+        round-trips through the fan-out chain were the measured ceiling
+        (~0.7 MB/s at 64KB packets); the reference pipelines the same way
+        (DataStreamManagement.java:85 writeTo/thenCombine chains).  HEADER,
+        CLOSE and SYNC (once a stream, or rare) await: they run as a task,
+        and the connection's later packets wait for it."""
         self.metrics.num_requests.inc()
-        with self.metrics.request_timer.time():
-            reply_data, tid = b"", 0
-            try:
-                if packet.kind == KIND_HEADER:
-                    is_new = packet.stream_id not in self._streams
-                    await self._on_header(packet)
-                    if is_new:  # count only opens that actually succeeded
-                        self.metrics.streams_started.inc()
-                elif packet.kind == KIND_DATA:
-                    if not packet.is_close:
-                        await self._on_data_pipelined(packet, conn, t0)
-                        return  # completion task acks the client
-                    await self._on_close_data(packet)
-                else:
-                    raise DataStreamException(f"unexpected kind {packet.kind}")
-                if packet.is_close:
-                    # inside the try: a failing close must still answer the
-                    # client (failure reply) and count as failed
-                    reply_data, tid = await self._finish(packet, t0)
-                    self.metrics.streams_closed.inc()
-            except Exception as e:
-                LOG.warning("datastream packet failed: %s", e)
-                self.metrics.num_failed.inc()
-                await conn.send(Packet(KIND_REPLY, packet.stream_id,
-                                       packet.offset,
-                                       packet.flags & ~FLAG_SUCCESS, b""))
-                return
-            await conn.send(Packet(KIND_REPLY, packet.stream_id, packet.offset,
-                                   packet.flags | FLAG_SUCCESS, reply_data))
+        if packet.kind == KIND_DATA \
+                and not packet.flags & (FLAG_CLOSE | FLAG_SYNC):
+            with self.metrics.request_timer.time():
+                try:
+                    self._on_data(packet, conn, t0)
+                except Exception as e:
+                    self._failed(packet, conn, e)
+            return
+        inbound.task = asyncio.get_running_loop().create_task(
+            self._handle_once(packet, conn, t0, inbound))
+
+    def _resume(self, conn: PeerConnection, inbound: _Inbound) -> None:
+        """The connection's task has ended: handle what waited, in order,
+        until one starts a task of its own."""
+        inbound.task = None
+        backlog = inbound.backlog
+        while backlog and inbound.task is None:
+            packet, t0 = backlog.popleft()
+            self._handle(packet, conn, t0, inbound)
+        if inbound.holding and len(backlog) <= _BACKLOG_PACKETS:
+            inbound.holding = False
+            conn.release_reading(inbound)
+
+    def _failed(self, packet: Packet, conn: PeerConnection,
+                e: Exception) -> None:
+        LOG.warning("datastream packet failed: %s", e)
+        self.metrics.num_failed.inc()
+        _reply(conn, packet, False)
+
+    async def _handle_once(self, packet: Packet, conn: PeerConnection,
+                           t0: int, inbound: _Inbound) -> None:
+        """A HEADER, CLOSE or SYNC packet, in the connection's task; the
+        packets that waited behind it are handled when it ends."""
+        try:
+            with self.metrics.request_timer.time():
+                await self._answer_once(packet, conn, t0)
+        finally:
+            self._resume(conn, inbound)
+
+    async def _answer_once(self, packet: Packet, conn: PeerConnection,
+                           t0: int) -> None:
+        reply_data, tid = b"", 0
+        try:
             if packet.kind == KIND_HEADER:
-                TRACER.interval(STAGE_STREAM_HEADER, t0)
-            elif tid:
-                # the stream's raft request, answered in the CLOSE's ack:
-                # what a transport's respond span is to a client request
-                egress = TRACER.pop_egress(tid)
-                if egress:
-                    TRACER.record(tid, STAGE_RESPOND, egress, TRACER.now())
+                is_new = packet.stream_id not in self._streams
+                await self._on_header(packet)
+                if is_new:  # count only opens that actually succeeded
+                    self.metrics.streams_started.inc()
+            elif packet.kind == KIND_DATA:
+                if not packet.is_close:
+                    await self._on_sync(packet, conn, t0)
+                    return  # its _PacketAck acks the client
+                await self._on_close_data(packet)
+            else:
+                raise DataStreamException(f"unexpected kind {packet.kind}")
+            if packet.is_close:
+                # inside the try: a failing close must still answer the
+                # client (failure reply) and count as failed
+                reply_data, tid = await self._finish(packet, t0)
+                self.metrics.streams_closed.inc()
+        except Exception as e:
+            self._failed(packet, conn, e)
+            return
+        _reply(conn, packet, True, reply_data)
+        if packet.kind == KIND_HEADER:
+            TRACER.interval(STAGE_STREAM_HEADER, t0)
+        elif tid:
+            # the stream's raft request, answered in the CLOSE's ack:
+            # what a transport's respond span is to a client request
+            egress = TRACER.pop_egress(tid)
+            if egress:
+                TRACER.record(tid, STAGE_RESPOND, egress, TRACER.now())
 
     async def _on_header(self, packet: Packet) -> None:
         request, routing = decode_header(packet.data)
@@ -334,13 +535,12 @@ class DataStreamManagement:
         _PACKETS[info.is_primary].n += 1
         _BYTES[info.is_primary].n += len(data)
 
-    async def _on_data_pipelined(self, packet: Packet, conn: PeerConnection,
-                                 t0: int = 0) -> None:
-        """Ordered phase of a (non-close) DATA packet: validate, queue the
-        local write, put the forward copies on the wire; then hand the local
-        write and the ack-collection to a completion task so the read loop
-        pipelines (the reference's writeTo combines the local write and the
-        remote ones the same way)."""
+    def _on_data(self, packet: Packet, conn: PeerConnection, t0: int,
+                 extra: int = 0) -> _PacketAck:
+        """A (non-close) DATA packet, in read order: validate, queue the
+        local write, put the copies on the successors' connections, and
+        leave the ack to a :class:`_PacketAck` (the reference's writeTo
+        combines the local write and the remote ones the same way)."""
         info = self._info_for(packet)
         info.touched_s = time.monotonic()
         if info.failed is not None:
@@ -349,18 +549,13 @@ class DataStreamManagement:
             raise DataStreamException(
                 f"stream {packet.stream_id}: out-of-order offset "
                 f"{packet.offset}, expected {info.next_offset}")
-        ack_futs: list = []
+        acks: list = []
         write: Optional[asyncio.Future] = None
         try:
             write = self._queue_local(info, packet.data)
-            # sends happen NOW, in read-loop order (per-successor FIFO);
-            # only the ack futures move to the completion task
+            # sends happen NOW, in read order (per-successor FIFO)
             for r in info.remotes:
-                ack_futs.append(await r.send(packet))
-            if packet.is_sync:
-                await asyncio.wait((write,))    # (the force below covers it)
-        except asyncio.CancelledError:
-            raise
+                acks.append(r.conn.queue(packet, conn))
         except Exception as e:
             # Poison the stream OURSELVES (later packets and the CLOSE fail
             # fast server-side instead of relying on the client reacting to
@@ -370,59 +565,35 @@ class DataStreamManagement:
             # noise with no handler (ADVICE r5).
             info.failed = e if isinstance(e, DataStreamException) \
                 else DataStreamException(str(e))
-            for fut in ack_futs if write is None else [write, *ack_futs]:
+            for fut in acks if write is None else [write, *acks]:
                 fut.add_done_callback(_consume_result)
                 fut.cancel()
             raise
-        if packet.is_sync:
-            await info.local.channel.force()
+        return _PacketAck(self, info, packet, conn, t0, write, acks, extra)
 
-        async def complete() -> None:
-            try:
-                self._written(info, packet.data, await write)
-                replies = await asyncio.gather(*ack_futs)
-                for r, reply in zip(info.remotes, replies):
-                    if not reply.success:
-                        raise DataStreamException(
-                            f"successor {r.peer_id} rejected stream "
-                            f"{packet.stream_id} offset {packet.offset}")
-            except asyncio.CancelledError:
-                raise
-            except Exception as e:
-                # poison the stream: later packets and the CLOSE must fail
-                info.failed = e
-                for fut in ack_futs:    # (where the write failed first)
-                    fut.add_done_callback(_consume_result)
-                LOG.warning("datastream packet failed: %s", e)
-                self.metrics.num_failed.inc()
-                await conn.send(Packet(KIND_REPLY, packet.stream_id,
-                                       packet.offset,
-                                       packet.flags & ~FLAG_SUCCESS, b""))
-                return
-            self.metrics.bytes_written.inc(len(packet.data))
-            await conn.send(Packet(KIND_REPLY, packet.stream_id,
-                                   packet.offset,
-                                   packet.flags | FLAG_SUCCESS, b""))
-            TRACER.interval(STAGE_STREAM_PACKET, t0, len(packet.data)
-                            if info.is_primary else -len(packet.data))
-
-        t = asyncio.create_task(complete())
-        info.pending.add(t)
-        t.add_done_callback(info.pending.discard)
+    async def _on_sync(self, packet: Packet, conn: PeerConnection,
+                       t0: int) -> None:
+        """A SYNC packet: as any DATA packet, and its ack waits for a force
+        that runs once its queued write has landed (so it covers the
+        packet's bytes)."""
+        ack = self._on_data(packet, conn, t0, extra=1)
+        try:
+            await asyncio.wait((ack.write,))
+            await ack.info.local.channel.force()
+        except Exception as e:
+            ack.fail(e)
+            return
+        ack.part_done()
 
     async def _on_close_data(self, packet: Packet) -> None:
         """The CLOSE packet's data phase: drain the pipeline first (each
-        packet's completion task waits for its local write too, so the
-        force covers every byte acknowledged), then the fully-awaited
-        ordered path (forwarding the close to successors and forcing the
-        local channel)."""
+        packet's ack waits for its local write too, so the force covers
+        every byte acknowledged), then the fully-awaited ordered path
+        (forwarding the close to successors and forcing the local
+        channel)."""
         info = self._info_for(packet)
         info.touched_s = time.monotonic()
-        # (a task done but not yet discarded is drained: a gather of done
-        # tasks never yields, so its discard would never run)
-        while any(not t.done() for t in info.pending):
-            await asyncio.gather(*list(info.pending),
-                                 return_exceptions=True)
+        await info.answered()
         if info.failed is not None:
             raise info.failed
         if packet.offset != info.next_offset:
@@ -473,9 +644,7 @@ class DataStreamManagement:
         await self._cleanup_owned(info)
 
     async def _cleanup_owned(self, info: StreamInfo) -> None:
-        for t in list(info.pending):
-            t.cancel()
-        info.pending.clear()
+        info.abandon()
         if info.local is not None:
             try:
                 await info.local.cleanup()

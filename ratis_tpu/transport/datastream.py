@@ -12,6 +12,20 @@ submitted.  Peers forward packets to successors over the same framing.
 Frame layout (all big-endian):
     u32 total_len | u8 kind | u64 stream_id | u64 offset | u8 flags | bytes
 kind: 1=HEADER 2=DATA 3=REPLY; flags bit0=SYNC bit1=CLOSE bit2=SUCCESS.
+
+A packet costs callbacks, not tasks: every stream-plane connection — one
+accepted on a stream port, a forwarding leg to a successor, a client's — is
+one ``asyncio.Protocol`` (:class:`PeerConnection`; in Netty's terms the frame
+decoder, the flush on read-complete and auto-read).  ``data_received``
+parses every whole frame it holds and hands each to the connection's handler
+in that callback.  ``send`` only queues: what a loop pass queued on a
+connection leaves in ONE ``transport.writelines`` at the end of the pass (a
+frame's header and its bytes as two buffers, never joined, so a forwarded
+DATA frame is the frame as it came in, its bytes not copied).  Flow control:
+while a connection's transport is over its high-water mark, the connections
+that feed it stop reading, and read again once it drains (``send``'s
+``reader``).
+
 TPU-first note: this is pure host-side I/O — bulk bytes ride DCN between
 failure domains and never enter an XLA program (SURVEY.md §2.6).
 """
@@ -22,7 +36,8 @@ import asyncio
 import dataclasses
 import logging
 import struct
-from typing import Awaitable, Callable, Optional
+import weakref
+from typing import Callable, Optional
 
 from ratis_tpu.trace.tracer import TRACER
 
@@ -42,7 +57,17 @@ FLAG_SUCCESS = 4
 FLAG_PRIMARY = 8  # set by the client on the header it sends the primary
 
 _HDR = struct.Struct(">IBQQB")  # total_len, kind, stream_id, offset, flags
+_LEN = struct.Struct(">I")
+_BODY_MIN = _HDR.size - 4       # what total_len counts before the bytes
 MAX_FRAME = 64 << 20
+
+# the stream plane's socket writes and the frames they carried, by the side
+# that writes: ``server`` an accepted connection (acks), ``client`` one that
+# connected (a client's packets, a server's forwarding legs); frames a
+# write = frames_out / writes_out (docs/tracing.md)
+_SIDES = ("server", "client")
+_FRAMES_OUT = {s: TRACER.counter("stream.frames_out", s) for s in _SIDES}
+_WRITES_OUT = {s: TRACER.counter("stream.writes_out", s) for s in _SIDES}
 
 
 def encode_header(request, routing) -> bytes:
@@ -84,69 +109,236 @@ class Packet:
         return bool(self.flags & FLAG_SUCCESS)
 
 
+def _frame_head(p: Packet) -> bytes:
+    return _HDR.pack(_BODY_MIN + len(p.data), p.kind, p.stream_id, p.offset,
+                     p.flags)
+
+
 def encode_packet(p: Packet) -> bytes:
-    body_len = _HDR.size - 4 + len(p.data)
-    return _HDR.pack(body_len, p.kind, p.stream_id, p.offset,
-                     p.flags) + p.data
+    return _frame_head(p) + p.data
 
 
-async def read_packet(reader: asyncio.StreamReader) -> Optional[Packet]:
-    """Read one frame; None on clean EOF; raises on truncation/oversize."""
-    try:
-        prefix = await reader.readexactly(4)
-    except asyncio.IncompleteReadError as e:
-        if not e.partial:
-            return None
-        raise ConnectionError("truncated frame prefix") from None
-    (body_len,) = struct.unpack(">I", prefix)
-    if body_len < _HDR.size - 4 or body_len > MAX_FRAME:
-        raise ConnectionError(f"bad frame length {body_len}")
-    body = await reader.readexactly(body_len)
-    _, kind, stream_id, offset, flags = _HDR.unpack(prefix + body[:_HDR.size - 4])
-    return Packet(kind, stream_id, offset, flags, body[_HDR.size - 4:])
+PacketHandler = Callable[[Packet, "PeerConnection"], None]
 
 
-PacketHandler = Callable[[Packet, "PeerConnection"], Awaitable[None]]
+class PeerConnection(asyncio.Protocol):
+    """One stream-plane connection, either end.  ``on_packet(packet, conn)``
+    gets every frame in read order, inside the read callback; ``on_lost``
+    gets what ended the connection, once.  ``side`` is ``server`` for a
+    connection accepted on a stream port, ``client`` for one that connected.
+    Everything runs on the loop that made the connection; a send or a hold
+    from another loop (a stream pinned to a loop shard,
+    raft.tpu.replication.stream-shards) is carried there with
+    ``call_soon_threadsafe``."""
 
+    def __init__(self, label: str, on_packet: PacketHandler,
+                 on_lost: Optional[Callable[[Exception], None]] = None,
+                 side: str = "client") -> None:
+        self.label = label
+        self._on_packet = on_packet
+        self._on_lost = on_lost
+        self.side = side
+        self._frames_out = _FRAMES_OUT[side]
+        self._writes_out = _WRITES_OUT[side]
+        self.loop: Optional[asyncio.AbstractEventLoop] = None  # when made
+        self._transport: Optional[asyncio.Transport] = None
+        self._rbuf = bytearray()        # the bytes of a frame not yet whole
+        self._out: list = []            # frame heads and bytes of this pass
+        self._frames = 0                # frames in ``_out``
+        self._flush_armed = False
+        self.paused = False             # the transport is over its high water
+        self._throttled: set = set()    # readers held until this one drains
+        self._holds: set = set()        # what holds this one's reading
+        self._closed: Optional[asyncio.Future] = None
+        self.dead: Optional[Exception] = None
+        self.inbound = None             # the handler's own per-connection state
 
-class PeerConnection:
-    """One accepted connection; the handler replies via :meth:`send`.
+    # -- the transport's callbacks -----------------------------------------
 
-    Loop-aware: with the DataStream plane pinned to division loop shards
-    (raft.tpu.replication.stream-shards) the packet handlers — and their
-    reply sends — run on shard loops while the accepted socket lives on
-    the accept loop; a cross-loop send hops back to the owner (StreamWriter
-    is loop-affine).  Single-loop servers take the direct path."""
+    def connection_made(self, transport) -> None:
+        self.loop = asyncio.get_running_loop()
+        self._transport = transport
+        self._closed = self.loop.create_future()
+        if self.side == "server":
+            _CONNECTS_ACCEPTED.n += 1
 
-    def __init__(self, reader: asyncio.StreamReader,
-                 writer: asyncio.StreamWriter) -> None:
-        self.reader = reader
-        self.writer = writer
-        self._send_lock = asyncio.Lock()
-        self._loop = asyncio.get_running_loop()
+    def data_received(self, data) -> None:
+        buf = self._rbuf
+        if buf:                 # a frame straddles reads: parse the joined bytes
+            buf += data
+            data = buf
+        pos, end = 0, len(data)
+        view = memoryview(data)
+        try:
+            while end - pos >= 4:
+                (body_len,) = _LEN.unpack_from(data, pos)
+                if body_len < _BODY_MIN or body_len > MAX_FRAME:
+                    self._abort(ConnectionError(
+                        f"{self.label}: bad frame length {body_len}"))
+                    return
+                nxt = pos + 4 + body_len
+                if nxt > end:
+                    break
+                _, kind, stream_id, offset, flags = _HDR.unpack_from(data, pos)
+                packet = Packet(kind, stream_id, offset, flags,
+                                bytes(view[pos + _HDR.size:nxt]))
+                pos = nxt
+                self._on_packet(packet, self)
+                if self.dead is not None:
+                    return
+            if data is not buf and pos < end:
+                buf += view[pos:]
+        finally:
+            view.release()
+        if data is buf:
+            del buf[:pos]
 
-    async def send(self, packet: Packet) -> None:
-        if asyncio.get_running_loop() is not self._loop:
-            await asyncio.wrap_future(asyncio.run_coroutine_threadsafe(
-                self._send_owned(packet), self._loop))
+    def eof_received(self):
+        if self._rbuf:
+            self._fail(ConnectionError(f"{self.label}: truncated frame"))
+        elif self._out:
+            self._flush()       # queued replies reach a half-closed peer
+        return False            # and the transport closes
+
+    def pause_writing(self) -> None:
+        self.paused = True
+
+    def resume_writing(self) -> None:
+        self.paused = False
+        readers, self._throttled = self._throttled, set()
+        for reader in readers:
+            reader.release_reading(self)
+
+    def connection_lost(self, exc) -> None:
+        self._fail(ConnectionError(f"{self.label} lost: {exc}" if exc
+                                   else f"{self.label} closed"))
+        if not self._closed.done():
+            self._closed.set_result(None)
+
+    # -- write -------------------------------------------------------------
+
+    def _owned(self, fn, *args) -> bool:
+        """Run ``fn(*args)`` now when called on the connection's loop and
+        return True; from another loop, carry it there and return False."""
+        try:
+            here = asyncio.get_running_loop() is self.loop
+        except RuntimeError:
+            here = False
+        if here:
+            fn(*args)
+            return True
+        try:
+            self.loop.call_soon_threadsafe(fn, *args)
+        except RuntimeError:
+            pass                # that loop has closed: the connection with it
+        return False
+
+    def send(self, packet: Packet,
+             reader: Optional["PeerConnection"] = None) -> None:
+        """Queue ``packet`` for this loop pass's one write, in call order;
+        raises what killed the connection.  ``reader`` is the connection
+        whose packet this one answers or carries on: while this one's
+        transport is over its high-water mark, ``reader`` stops reading, and
+        it reads again once this one drains (or dies).  From another loop
+        the packet is carried to the connection's, and dropped there if it
+        has died."""
+        if self.dead is not None:
+            raise self.dead
+        self._owned(self._queue, packet, reader)
+
+    def _queue(self, packet: Packet,
+               reader: Optional["PeerConnection"]) -> None:
+        if self.dead is not None:
+            return              # (carried from another loop: it died since)
+        self._out.append(_frame_head(packet))
+        if packet.data:
+            self._out.append(packet.data)
+        self._frames += 1
+        if not self._flush_armed:
+            self._flush_armed = True
+            self.loop.call_soon(self._flush)
+        if self.paused and reader is not None \
+                and reader not in self._throttled:
+            self._throttled.add(reader)
+            reader.hold_reading(self)
+
+    def _flush(self) -> None:
+        self._flush_armed = False
+        out, frames = self._out, self._frames
+        if not out or self.dead is not None:
             return
-        await self._send_owned(packet)
+        self._out, self._frames = [], 0
+        if self._transport.is_closing():
+            return              # connection_lost follows and fails the rest
+        try:
+            self._transport.writelines(out)
+        except Exception as e:
+            # some of the batch may be on the wire: poison, never raise into
+            # the loop
+            err = ConnectionError(f"{self.label} write failed: {e!r}")
+            err.__cause__ = e
+            self._abort(err)
+            return
+        self._writes_out.n += 1
+        self._frames_out.n += frames
 
-    async def _send_owned(self, packet: Packet) -> None:
-        async with self._send_lock:
-            self.writer.write(encode_packet(packet))
-            await self.writer.drain()
+    # -- reading held by other connections ---------------------------------
+
+    def hold_reading(self, holder) -> None:
+        """Stop reading until every holder has let go."""
+        self._owned(self._hold_owned, holder)
+
+    def _hold_owned(self, holder) -> None:
+        if not self._holds and self._transport is not None:
+            self._transport.pause_reading()
+        self._holds.add(holder)
+
+    def release_reading(self, holder) -> None:
+        self._owned(self._release_owned, holder)
+
+    def _release_owned(self, holder) -> None:
+        if holder not in self._holds:
+            return
+        self._holds.discard(holder)
+        if not self._holds and self._transport is not None:
+            self._transport.resume_reading()
+
+    # -- failure and close -------------------------------------------------
+
+    def _fail(self, exc: Exception) -> None:
+        if self.dead is not None:
+            return
+        self.dead = exc
+        self._out.clear()
+        self._frames = 0
+        self.resume_writing()   # held readers go on
+        if self._on_lost is not None:
+            self._on_lost(exc)
+
+    def _abort(self, exc: Exception) -> None:
+        self._fail(exc)
+        self._transport.abort()
+
+    def close_nowait(self) -> None:
+        """Write what is queued and close; ``connection_lost`` follows."""
+        if self._transport is None:
+            return
+        if self._out and self.dead is None:
+            self._flush()
+        self._fail(ConnectionError(f"{self.label} closed"))
+        self._transport.close()
 
     async def close(self) -> None:
-        self.writer.close()
-        try:
-            await self.writer.wait_closed()
-        except (ConnectionError, OSError):
-            pass
+        if self.loop is None:
+            return              # never made (a TLS handshake that failed)
+        if self._owned(self.close_nowait):
+            await self._closed
 
 
 class DataStreamServer:
-    """Accept loop dispatching packets to a handler (NettyServerStreamRpc)."""
+    """A stream port: every accepted connection hands its packets to
+    ``handler`` in read order, inside its read callback
+    (NettyServerStreamRpc)."""
 
     def __init__(self, address: str, handler: PacketHandler,
                  tls=None) -> None:
@@ -154,13 +346,15 @@ class DataStreamServer:
         self.handler = handler
         self.tls = tls  # transport.tcp.TcpTlsConfig (same surface)
         self._server: Optional[asyncio.AbstractServer] = None
-        self._conns: set[PeerConnection] = set()
+        # (weak: a connection whose TLS handshake fails is never made, and
+        # is never lost either)
+        self._conns: "weakref.WeakSet[PeerConnection]" = weakref.WeakSet()
 
     async def start(self) -> None:
         host, port = self.address.rsplit(":", 1)
         ssl_ctx = self.tls.server_context() if self.tls is not None else None
-        self._server = await asyncio.start_server(self._on_connect, host,
-                                                  int(port), ssl=ssl_ctx)
+        self._server = await asyncio.get_running_loop().create_server(
+            self._accept, host, int(port), ssl=ssl_ctx)
 
     @property
     def bound_port(self) -> Optional[int]:
@@ -168,32 +362,23 @@ class DataStreamServer:
             return self._server.sockets[0].getsockname()[1]
         return None
 
-    async def _on_connect(self, reader: asyncio.StreamReader,
-                          writer: asyncio.StreamWriter) -> None:
-        conn = PeerConnection(reader, writer)
+    def _accept(self) -> PeerConnection:
+        conn = PeerConnection(f"datastream {self.address} accepted",
+                              self._on_packet, side="server")
         self._conns.add(conn)
-        _CONNECTS_ACCEPTED.n += 1
+        return conn
+
+    def _on_packet(self, packet: Packet, conn: PeerConnection) -> None:
         try:
-            while True:
-                packet = await read_packet(reader)
-                if packet is None:
-                    break
-                try:
-                    await self.handler(packet, conn)
-                except Exception:
-                    LOG.exception("datastream handler failed")
-                    await conn.send(Packet(KIND_REPLY, packet.stream_id,
-                                           packet.offset, packet.flags & ~FLAG_SUCCESS,
-                                           b""))
-        except (ConnectionError, OSError):
-            pass
-        finally:
-            self._conns.discard(conn)
-            await conn.close()
+            self.handler(packet, conn)
+        except Exception:
+            LOG.exception("datastream handler failed")
+            if conn.dead is None:
+                conn.send(Packet(KIND_REPLY, packet.stream_id, packet.offset,
+                                 packet.flags & ~FLAG_SUCCESS, b""))
 
     async def close(self) -> None:
-        # connections first: wait_closed() (3.12+) waits for every handler,
-        # and handlers block in read_packet until their connection dies
+        # connections first: wait_closed() (3.12+) waits for every one
         for conn in list(self._conns):
             await conn.close()
         if self._server is not None:
@@ -204,72 +389,57 @@ class DataStreamServer:
 class DataStreamConnection:
     """Client/forwarder side: one connection with per-packet ack futures
     keyed by (stream_id, offset, close-flag) — the sliding-window analog of
-    OrderedStreamAsync."""
+    OrderedStreamAsync.  A REPLY resolves its future inside the read
+    callback; a lost connection, a clean EOF included, fails every one
+    outstanding."""
 
     def __init__(self, address: str, tls=None) -> None:
         self.address = address
         self.tls = tls
-        self._reader: Optional[asyncio.StreamReader] = None
-        self._writer: Optional[asyncio.StreamWriter] = None
+        self.conn: Optional[PeerConnection] = None
         self._pending: dict[tuple, asyncio.Future] = {}
-        self._recv_task: Optional[asyncio.Task] = None
-        self._send_lock = asyncio.Lock()
-        self._dead: Optional[Exception] = None
 
     async def connect(self) -> None:
         host, port = self.address.rsplit(":", 1)
         ssl_ctx = self.tls.client_context() if self.tls is not None else None
-        self._reader, self._writer = await asyncio.open_connection(
+        _, self.conn = await asyncio.get_running_loop().create_connection(
+            lambda: PeerConnection(f"datastream connection to {self.address}",
+                                   self._on_reply, self._lost),
             host, int(port), ssl=ssl_ctx)
-        self._recv_task = asyncio.create_task(
-            self._recv_loop(), name=f"datastream-recv-{self.address}")
 
-    async def _recv_loop(self) -> None:
-        cause: Exception = ConnectionError(
-            f"datastream connection to {self.address} closed")
-        try:
-            while True:
-                packet = await read_packet(self._reader)
-                if packet is None:
-                    break  # clean EOF still fails whatever is outstanding
-                key = (packet.stream_id, packet.offset, packet.is_close)
-                fut = self._pending.pop(key, None)
-                if fut is not None and not fut.done():
-                    fut.set_result(packet)
-        except (ConnectionError, OSError, asyncio.CancelledError) as e:
-            cause = ConnectionError(f"datastream connection lost: {e}")
-        finally:
-            self._dead = cause
-            for fut in self._pending.values():
-                if not fut.done():
-                    fut.set_exception(cause)
-            self._pending.clear()
+    def _on_reply(self, packet: Packet, conn: PeerConnection) -> None:
+        fut = self._pending.pop(
+            (packet.stream_id, packet.offset, packet.is_close), None)
+        if fut is not None and not fut.done():
+            fut.set_result(packet)
 
-    async def send(self, packet: Packet) -> "asyncio.Future[Packet]":
-        """Send one packet; returns the future of its REPLY packet."""
-        if self._dead is not None:
-            raise self._dead
+    def _lost(self, exc: Exception) -> None:
+        pending, self._pending = self._pending, {}
+        for fut in pending.values():
+            if not fut.done():
+                fut.set_exception(exc)
+
+    def queue(self, packet: Packet, reader: Optional[PeerConnection] = None
+              ) -> "asyncio.Future[Packet]":
+        """Queue one packet for this loop pass's write; returns the future
+        of its REPLY packet.  The caller's order is the wire order;
+        ``reader`` as for :meth:`PeerConnection.send`."""
+        if self.conn.dead is not None:
+            raise self.conn.dead
         key = (packet.stream_id, packet.offset, packet.is_close)
         if key in self._pending:
             raise ConnectionError(
                 f"duplicate in-flight packet key {key} (zero-length data?)")
-        fut = asyncio.get_running_loop().create_future()
+        fut = self.conn.loop.create_future()
         self._pending[key] = fut
-        async with self._send_lock:
-            self._writer.write(encode_packet(packet))
-            await self._writer.drain()
+        self.conn.send(packet, reader)
         return fut
 
+    async def send(self, packet: Packet) -> "asyncio.Future[Packet]":
+        """Send one packet; returns the future of its REPLY packet
+        (:meth:`queue` from a coroutine: nothing is awaited)."""
+        return self.queue(packet)
+
     async def close(self) -> None:
-        if self._recv_task is not None:
-            self._recv_task.cancel()
-            try:
-                await self._recv_task
-            except asyncio.CancelledError:
-                pass
-        if self._writer is not None:
-            self._writer.close()
-            try:
-                await self._writer.wait_closed()
-            except (ConnectionError, OSError):
-                pass
+        if self.conn is not None:
+            await self.conn.close()

@@ -16,10 +16,13 @@ from ratis_tpu.models.filestore import (FileChunkChannel,
 from ratis_tpu.server.statemachine import DataChannel, DataStream
 from ratis_tpu.protocol.ids import RaftPeerId
 from ratis_tpu.protocol.routing import RoutingTable
+from ratis_tpu.trace.tracer import TRACER
 from ratis_tpu.transport.datastream import (FLAG_CLOSE, FLAG_PRIMARY,
-                                            FLAG_SYNC, KIND_DATA,
-                                            KIND_HEADER, Packet,
-                                            encode_packet, read_packet)
+                                            FLAG_SUCCESS, FLAG_SYNC,
+                                            KIND_DATA, KIND_HEADER,
+                                            KIND_REPLY, MAX_FRAME,
+                                            DataStreamConnection, Packet,
+                                            PeerConnection, encode_packet)
 from tests.minicluster import run_with_new_cluster
 
 
@@ -27,16 +30,55 @@ def _pid(s):
     return RaftPeerId.value_of(s)
 
 
+class _Wire(asyncio.Transport):
+    """A stand-in socket for one :class:`PeerConnection`: keeps what each
+    write carried and whether the connection reads."""
+
+    def __init__(self) -> None:
+        super().__init__()
+        self.writes: list[bytes] = []
+        self.reading = True
+        self.closed = self.aborted = False
+
+    def writelines(self, data) -> None:
+        self.writes.append(b"".join(data))
+
+    def is_closing(self) -> bool:
+        return self.closed or self.aborted
+
+    def pause_reading(self) -> None:
+        self.reading = False
+
+    def resume_reading(self) -> None:
+        self.reading = True
+
+    def close(self) -> None:
+        self.closed = True
+
+    def abort(self) -> None:
+        self.aborted = True
+
+
+def _made(on_packet=None, on_lost=None, side="client"):
+    """A connection on the running loop over a :class:`_Wire`, with the
+    packets it read."""
+    got: list = []
+    conn = PeerConnection("test", on_packet or (lambda p, c: got.append(p)),
+                          on_lost, side)
+    wire = _Wire()
+    conn.connection_made(wire)
+    return conn, wire, got
+
+
 def test_packet_roundtrip():
     async def _run():
         p = Packet(KIND_DATA, 12345, 678, FLAG_SYNC | FLAG_CLOSE, b"payload")
-        reader = asyncio.StreamReader()
-        reader.feed_data(encode_packet(p))
-        reader.feed_eof()
-        q = await read_packet(reader)
-        assert q == p
-        assert q.is_sync and q.is_close
-        assert await read_packet(reader) is None  # clean EOF
+        conn, wire, got = _made()
+        conn.data_received(encode_packet(p))
+        assert got == [p]
+        assert got[0].is_sync and got[0].is_close
+        assert conn.eof_received() is False     # clean EOF: the transport closes
+        assert conn.dead is None and not wire.aborted
 
     asyncio.run(_run())
 
@@ -45,11 +87,116 @@ def test_packet_truncation_raises():
     async def _run():
         p = Packet(KIND_HEADER, 1, 0, FLAG_PRIMARY, b"x" * 100)
         raw = encode_packet(p)
-        reader = asyncio.StreamReader()
-        reader.feed_data(raw[:len(raw) - 5])
-        reader.feed_eof()
-        with pytest.raises((ConnectionError, asyncio.IncompleteReadError)):
-            await read_packet(reader)
+        conn, _, got = _made()
+        conn.data_received(raw[:len(raw) - 5])
+        conn.eof_received()
+        assert got == []
+        assert isinstance(conn.dead, ConnectionError)
+        assert "truncated" in str(conn.dead)
+
+    asyncio.run(_run())
+
+
+def _split_at_every_byte(data):
+    """Two frames, cut in two reads at every byte: each read hands on every
+    whole frame it completes, once, in order."""
+    frames = [Packet(KIND_DATA, 7, 0, 0, data),
+              Packet(KIND_REPLY, 7, len(data), FLAG_SUCCESS, b"")]
+    raw = b"".join(encode_packet(p) for p in frames)
+    first = len(encode_packet(frames[0]))
+    for cut in range(len(raw) + 1):
+        conn, _, got = _made()
+        conn.data_received(raw[:cut])
+        assert got == frames[:(cut >= first) + (cut == len(raw))], cut
+        conn.data_received(raw[cut:])
+        assert got == frames and not conn._rbuf, cut
+
+
+def _several_in_one_read(_):
+    """Five whole frames and the start of a sixth in one read: the five in
+    order, the rest kept; the sixth whole once its bytes come."""
+    frames = [Packet(KIND_DATA, 3, k * 10, 0, bytes([k]) * 10)
+              for k in range(6)]
+    raw = [encode_packet(p) for p in frames]
+    conn, _, got = _made()
+    conn.data_received(b"".join(raw[:5]) + raw[5][:9])
+    assert got == frames[:5]
+    assert bytes(conn._rbuf) == raw[5][:9]
+    conn.data_received(raw[5][9:])
+    assert got == frames and not conn._rbuf
+
+
+def _bad_length(length):
+    """A length below the frame's head or above MAX_FRAME aborts the
+    connection, fails it once, and a later send raises."""
+    lost: list = []
+    conn, wire, got = _made(on_lost=lost.append)
+    conn.data_received(length.to_bytes(4, "big") + bytes(30))
+    assert got == [] and wire.aborted
+    assert isinstance(conn.dead, ConnectionError) and lost == [conn.dead]
+    with pytest.raises(ConnectionError):
+        conn.send(Packet(KIND_REPLY, 1, 0, 0, b""))
+
+
+@pytest.mark.parametrize("case, arg", [
+    (_split_at_every_byte, b""),
+    (_split_at_every_byte, b"q" * 37),
+    (_several_in_one_read, None),
+    (_bad_length, 0),
+    (_bad_length, 17),
+    (_bad_length, MAX_FRAME + 1),
+], ids=["split-empty", "split-37", "several", "bad-0", "bad-17", "bad-max"])
+def test_the_frame_parser(case, arg):
+    async def _run():
+        case(arg)
+
+    asyncio.run(_run())
+
+
+def test_a_clean_eof_fails_what_is_outstanding_on_a_connection():
+    """The stream port reads one whole packet and closes cleanly: both
+    packets' futures fail, and a later send raises."""
+    async def _run():
+        first = Packet(KIND_DATA, 9, 0, 0, b"a" * 100)
+
+        async def serve(reader, writer):
+            await reader.readexactly(len(encode_packet(first)))
+            writer.close()
+
+        srv = await asyncio.start_server(serve, "127.0.0.1", 0)
+        port = srv.sockets[0].getsockname()[1]
+        conn = DataStreamConnection(f"127.0.0.1:{port}")
+        await conn.connect()
+        futs = [await conn.send(first),
+                conn.queue(Packet(KIND_DATA, 9, 100, 0, b"b" * 100))]
+        for fut in futs:
+            with pytest.raises(ConnectionError):
+                await asyncio.wait_for(fut, 10)
+        with pytest.raises(ConnectionError):
+            conn.queue(Packet(KIND_DATA, 9, 200, 0, b"c"))
+        await conn.close()
+        srv.close()
+        await srv.wait_closed()
+
+    asyncio.run(_run())
+
+
+def test_what_one_pass_queues_leaves_in_one_write():
+    """Three packets sent in one loop pass: one ``writelines`` carrying the
+    three frames byte for byte, counted as one write of three frames."""
+    async def _run():
+        conn, wire, _ = _made()
+        writes = TRACER.counter("stream.writes_out", "client").n
+        frames = TRACER.counter("stream.frames_out", "client").n
+        packets = [Packet(KIND_REPLY, 5, k * 4, FLAG_SUCCESS, b"") for k in
+                   range(2)] + [Packet(KIND_DATA, 5, 8, 0, b"four")]
+        for p in packets:
+            conn.send(p)
+        assert wire.writes == []
+        await asyncio.sleep(0)
+        assert wire.writes == [b"".join(encode_packet(p) for p in packets)]
+        assert TRACER.counter("stream.writes_out", "client").n == writes + 1
+        assert TRACER.counter("stream.frames_out", "client").n == frames + 3
 
     asyncio.run(_run())
 
@@ -515,32 +662,272 @@ def test_a_failed_local_write_poisons_the_stream(how):
     run_with_new_cluster(3, _test, sm_factory=_failing_store(how))
 
 
-def test_a_close_drains_a_packet_done_but_not_yet_discarded(monkeypatch):
-    """The CLOSE may run in the loop pass after a packet's completion task
-    finished and before its done-callback took it out of ``pending``: the
-    drain takes it as done, and does not spin on a gather that never
-    yields (a hang, seen beside a shard-pinned stream)."""
+class _HeldChannel(DataChannel):
+    """A channel whose writes the test completes: each ``submit_write`` is a
+    future of the running loop, kept in ``writes``."""
+
+    def __init__(self) -> None:
+        self.writes: list = []
+        self.forced = 0
+
+    def submit_write(self, data):
+        fut = asyncio.get_running_loop().create_future()
+        self.writes.append((fut, len(data)))
+        return fut
+
+    async def force(self, metadata=False):
+        self.forced += 1
+
+
+def _bare_plane(n_successors=0):
+    """A stream server's packet handler with no server behind it: stream 7
+    open at offset 0 on a :class:`_HeldChannel`, with ``n_successors``
+    forwarding legs over stand-in sockets; the accepted connection that
+    feeds it and the legs' sockets."""
+    from ratis_tpu.metrics import DataStreamMetrics
     from ratis_tpu.server import datastream
 
-    gather, calls = asyncio.gather, []
+    mgmt = datastream.DataStreamManagement.__new__(
+        datastream.DataStreamManagement)
+    mgmt._links, mgmt._stream_shards, mgmt._sweeps = {}, {}, set()
+    mgmt._expiry_s, mgmt._pin_shards = 0, False
+    mgmt.metrics = DataStreamMetrics(f"test-{random.random()}")
+    remotes, legs = [], []
+    for k in range(n_successors):
+        r = datastream._RemoteStream(_pid(f"s{k}"), "127.0.0.1:1")
+        r.conn.conn, wire, _ = _made(r.conn._on_reply, r.conn._lost)
+        remotes.append(r)
+        legs.append(wire)
+    info = datastream.StreamInfo(None, True, DataStream(_HeldChannel()),
+                                 remotes)
+    mgmt._streams = {7: info}
+    upstream, wire, _ = _made(mgmt._on_packet, side="server")
+    return mgmt, info, upstream, wire, legs
 
-    def counted(*futs, **kwargs):
-        calls.append(1)
-        if len(calls) > 100:
-            raise AssertionError("the drain spins")
-        return gather(*futs, **kwargs)
+
+def _replies(wire):
+    """The packets a stand-in socket carried."""
+    conn, _, got = _made()
+    for data in wire.writes:
+        conn.data_received(data)
+    return got
+
+
+def test_a_close_drains_a_packet_done_but_not_yet_discarded():
+    """The CLOSE may run in the loop pass in which the last packet's write
+    has landed and before its completion record has counted it: the drain
+    waits for that record, and for nothing else (no future left that
+    nobody resolves)."""
+    async def _run():
+        mgmt, info, upstream, wire, _ = _bare_plane()
+        upstream.data_received(encode_packet(Packet(KIND_DATA, 7, 0, 0,
+                                                    b"x" * 10)))
+        (write, n), = info.local.channel.writes
+        assert info.open_acks == 1
+        write.set_result(n)          # landed; its record not yet called back
+        await asyncio.wait_for(mgmt._on_close_data(
+            Packet(KIND_DATA, 7, 10, FLAG_CLOSE, b"")), 5)
+        assert info.open_acks == 0 and info.local.channel.forced == 1
+        assert [p.success for p in _replies(wire)] == [True]
+        # nothing open: the drain does not wait at all
+        await asyncio.wait_for(info.answered(), 0.5)
+        mgmt.metrics.unregister()
+
+    asyncio.run(_run())
+
+
+@pytest.mark.parametrize("last", ["write", "successor"])
+def test_a_packets_ack_waits_for_its_write_and_every_successor(last):
+    """Two successors: the copy leaves at once, and the client's ack leaves
+    only once the local write and both successors' acks are in, whichever
+    comes last."""
+    async def _run():
+        mgmt, info, upstream, wire, legs = _bare_plane(2)
+        p = Packet(KIND_DATA, 7, 0, 0, b"y" * 64)
+        upstream.data_received(encode_packet(p))
+        await asyncio.sleep(0)
+        # each copy is the frame as it came in, byte for byte
+        assert [leg.writes for leg in legs] == [[encode_packet(p)]] * 2
+        (write, n), = info.local.channel.writes
+        ack = encode_packet(Packet(KIND_REPLY, 7, 0, FLAG_SUCCESS, b""))
+        steps = [lambda: write.set_result(n)] + [
+            lambda r=r: r.conn.conn.data_received(ack) for r in info.remotes]
+        if last == "write":
+            steps.append(steps.pop(0))
+        for step in steps:
+            assert _replies(wire) == []
+            step()
+            for _ in range(3):
+                await asyncio.sleep(0)
+        assert [(q.offset, q.success) for q in _replies(wire)] == [(0, True)]
+        assert info.open_acks == 0 and info.bytes_written == 64
+        mgmt.metrics.unregister()
+
+    asyncio.run(_run())
+
+
+def test_acks_of_one_pass_leave_in_one_write():
+    """Five packets' writes land in one lane pass: their five acks go to
+    the client in one socket write, counted as one write of five frames on
+    the server's side."""
+    async def _run():
+        mgmt, info, upstream, wire, _ = _bare_plane()
+        writes = TRACER.counter("stream.writes_out", "server").n
+        frames = TRACER.counter("stream.frames_out", "server").n
+        upstream.data_received(b"".join(
+            encode_packet(Packet(KIND_DATA, 7, k * 8, 0, b"z" * 8))
+            for k in range(5)))
+        for write, n in info.local.channel.writes:
+            write.set_result(n)
+        for _ in range(3):
+            await asyncio.sleep(0)
+        assert len(wire.writes) == 1
+        assert [q.offset for q in _replies(wire)] == [0, 8, 16, 24, 32]
+        assert TRACER.counter("stream.writes_out", "server").n == writes + 1
+        assert TRACER.counter("stream.frames_out", "server").n == frames + 5
+        mgmt.metrics.unregister()
+
+    asyncio.run(_run())
+
+
+def test_packets_behind_a_suspended_header_are_handled_after_it_in_order():
+    """A HEADER whose handling is suspended holds the packets read behind it
+    on its connection; once it ends they are handled in read order, and
+    the connection, which stopped reading while more than a window's worth
+    waited, reads again."""
+    from ratis_tpu.server import datastream
 
     async def _run():
-        mgmt = datastream.DataStreamManagement.__new__(
-            datastream.DataStreamManagement)
-        info = datastream.StreamInfo(None, True, DataStream(DataChannel()),
-                                     [])
-        done = asyncio.get_running_loop().create_future()
-        done.set_result(None)
-        info.pending.add(done)      # finished; its discard not yet run
-        mgmt._streams = {7: info}
-        monkeypatch.setattr(datastream.asyncio, "gather", counted)
-        await mgmt._on_close_data(Packet(KIND_DATA, 7, 0, FLAG_CLOSE, b""))
+        mgmt, info, upstream, wire, _ = _bare_plane()
+        mgmt._streams = {}
+        go = asyncio.Event()
+
+        async def on_header(packet):
+            await go.wait()
+            mgmt._streams[packet.stream_id] = info
+
+        mgmt._on_header = on_header
+        n = datastream._BACKLOG_PACKETS + 1
+        upstream.data_received(
+            encode_packet(Packet(KIND_HEADER, 7, 0, FLAG_PRIMARY, b"h"))
+            + b"".join(encode_packet(Packet(KIND_DATA, 7, k, 0, b"d"))
+                       for k in range(n)))
+        await asyncio.sleep(0)
+        assert info.local.channel.writes == [] and _replies(wire) == []
+        assert not wire.reading         # more than a window waits
+        go.set()
+        for _ in range(3):
+            await asyncio.sleep(0)
+        assert wire.reading
+        assert [q.kind for q in _replies(wire)] == [KIND_REPLY]   # HEADER's
+        assert info.next_offset == n and len(info.local.channel.writes) == n
+        for write, k in info.local.channel.writes:
+            write.set_result(k)
+        for _ in range(3):
+            await asyncio.sleep(0)
+        assert [(q.offset, q.success) for q in _replies(wire)] == \
+            [(0, True)] + [(k, True) for k in range(n)]
+        mgmt.metrics.unregister()
+
+    asyncio.run(_run())
+
+
+def test_a_paused_successor_leg_pauses_reading_upstream():
+    """While a successor's socket is over its high-water mark, the
+    connection whose packets it carries stops reading; it reads again when
+    that socket drains."""
+    async def _run():
+        mgmt, info, upstream, wire, legs = _bare_plane(1)
+        leg = info.remotes[0].conn.conn
+        leg.pause_writing()
+        upstream.data_received(encode_packet(Packet(KIND_DATA, 7, 0, 0, b"a")))
+        assert not wire.reading
+        upstream.data_received(encode_packet(Packet(KIND_DATA, 7, 1, 0, b"b")))
+        assert info.next_offset == 2 and not wire.reading
+        leg.resume_writing()
+        assert wire.reading
+        # a dying leg lets its readers go too
+        leg.pause_writing()
+        upstream.data_received(encode_packet(Packet(KIND_DATA, 7, 2, 0, b"c")))
+        assert not wire.reading
+        leg.connection_lost(None)
+        assert wire.reading
+        mgmt.metrics.unregister()
+
+    asyncio.run(_run())
+
+
+def test_a_successor_lost_mid_stream_fails_its_acks_and_poisons_the_stream():
+    """Two packets out to the one successor, their writes landed, the first
+    one acknowledged: the successor's connection dies.  The second packet
+    gets a failure reply, the stream is poisoned, and a later packet fails
+    at once without a write or a copy."""
+    async def _run():
+        mgmt, info, upstream, wire, legs = _bare_plane(1)
+        leg = info.remotes[0].conn.conn
+        upstream.data_received(b"".join(
+            encode_packet(Packet(KIND_DATA, 7, k * 4, 0, b"w" * 4))
+            for k in range(2)))
+        for write, n in info.local.channel.writes:
+            write.set_result(n)
+        leg.data_received(encode_packet(
+            Packet(KIND_REPLY, 7, 0, FLAG_SUCCESS, b"")))
+        await asyncio.sleep(0)
+        leg.connection_lost(ConnectionResetError("reset by peer"))
+        for _ in range(3):
+            await asyncio.sleep(0)
+        assert [(q.offset, q.success) for q in _replies(wire)] == \
+            [(0, True), (4, False)]
+        assert isinstance(info.failed, ConnectionError)
+        assert info.open_acks == 0
+        upstream.data_received(encode_packet(Packet(KIND_DATA, 7, 8, 0, b"x")))
+        await asyncio.sleep(0)
+        assert [(q.offset, q.success) for q in _replies(wire)][2:] == \
+            [(8, False)]
+        assert len(info.local.channel.writes) == 2
+        assert len(legs[0].writes) == 1         # the two copies, one write
+        assert mgmt.metrics.num_failed.count == 2
+        mgmt.metrics.unregister()
+
+    asyncio.run(_run())
+
+
+def test_the_expiry_sweep_runs_on_a_header_and_a_timer_never_on_data():
+    """An abandoned stream is reclaimed by the sweep: DATA packets never
+    run it; a HEADER does, and so does the timer a started plane arms."""
+    from ratis_tpu.server import datastream
+
+    async def _run():
+        mgmt, info, upstream, wire, _ = _bare_plane()
+        mgmt._expiry_s, mgmt._sweep_timer = 10.0, None
+        cleaned: list = []
+
+        async def cleanup(i):
+            cleaned.append(i)
+
+        mgmt._cleanup = cleanup
+        info.touched_s = mgmt._last_sweep_s = time.monotonic() - 20
+        upstream.data_received(encode_packet(Packet(KIND_DATA, 8, 0, 0, b"d")))
+        assert 7 in mgmt._streams and not mgmt._sweeps
+        mgmt._on_packet(Packet(KIND_HEADER, 9, 0, 0, b"not a header"),
+                        upstream)
+        assert 7 not in mgmt._streams and len(mgmt._sweeps) == 1
+        await asyncio.gather(*mgmt._sweeps)
+        assert cleaned == [info]
+        # the timer: armed by start, and again by each sweep it runs
+        mgmt._expiry_s = 0.05
+        mgmt._streams[7] = info
+        info.touched_s = mgmt._last_sweep_s = time.monotonic() - 1
+        mgmt._arm_sweep()
+        for _ in range(100):
+            await asyncio.sleep(0.01)
+            if len(cleaned) == 2:
+                break
+        assert cleaned == [info, info]
+        assert mgmt._sweep_timer is not None \
+            and not mgmt._sweep_timer.cancelled()
+        mgmt._sweep_timer.cancel()
+        mgmt.metrics.unregister()
 
     asyncio.run(_run())
 
@@ -644,8 +1031,9 @@ def test_a_channel_with_only_a_write_streams_through_the_default_submit():
 
 def test_the_copy_leaves_before_the_local_write_completes(monkeypatch):
     """With every lane held shut, all four packets reach both successors
-    (their offsets move) while no peer's write has landed and the client
-    has no ack; let go, the stream completes and every file is whole."""
+    (their offsets move) while no peer's write has landed, each packet's
+    completion record waits on every peer and the client has no ack; let
+    go, the stream completes and every file is whole."""
     held = threading.Event()
     append = FileChunkChannel._append
 
@@ -676,6 +1064,9 @@ def test_the_copy_leaves_before_the_local_write_completes(monkeypatch):
                              for info in s.datastream._streams.values()]
                 assert sum(not i.is_primary for i in infos) == 2
                 assert all(i.local.channel._end == 0 for i in infos)
+                # every packet's completion record is open on every peer,
+                # its write the part still out
+                assert all(i.open_acks == 4 for i in infos)
                 assert not any(f.done() for f in out._acks)
             finally:
                 held.set()

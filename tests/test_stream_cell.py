@@ -409,6 +409,16 @@ def test_a_traced_stream_counts_its_bytes_packets_and_connections(
     busy = [k for k, n in batches.items() if n]
     assert len(busy) == 1
     assert 0 < batches[busy[0]] <= 48 + 3
+    # the stream plane's socket writes, by the side that writes: 18 frames
+    # (HEADER, 16 packets, CLOSE) down each of the client's connection and
+    # the two forwarding legs, 18 acks back up each of the three accepted
+    # ones; fewer writes than frames (what a loop pass queues leaves in one)
+    out = {(n, k): keyed.pop((n, k)) for n, k in list(keyed)
+           if n in ("stream.frames_out", "stream.writes_out")}
+    for side in ("server", "client"):
+        assert out[("stream.frames_out", side)] == 3 * 18
+        assert 0 < out[("stream.writes_out", side)] \
+            < out[("stream.frames_out", side)]
     assert keyed == {
         ("stream.streams", ""): 1,
         ("stream.packets", "primary"): 16,
