@@ -17,6 +17,7 @@ import importlib
 import os
 import random
 import resource
+import shutil
 import socket
 import time
 import uuid
@@ -43,6 +44,31 @@ def load_object(spec: str):
     """``package.module:Name`` -> the object."""
     module, _, name = spec.partition(":")
     return getattr(importlib.import_module(module), name)
+
+
+def run_storage_dir(checkout: str, config: dict) -> Optional[str]:
+    """A run's own storage directory, ``<checkout>/<storage dir>/run-<pid>``:
+    runs of one checkout side by side (the tests' rehearsals) never share
+    one.  None where the configuration names no storage directory."""
+    where = config.get("storage", {}).get("dir")
+    return os.path.join(checkout, where, f"run-{os.getpid()}") \
+        if where else None
+
+
+def remove_dead_runs(parent: str) -> None:
+    """Storage that runs whose process is gone left under ``parent`` (a run
+    ended at its deadline removes nothing)."""
+    if not os.path.isdir(parent):
+        return
+    for name in os.listdir(parent):
+        if not name.startswith("run-"):
+            continue
+        try:
+            os.kill(int(name[4:]), 0)
+        except ProcessLookupError:
+            shutil.rmtree(os.path.join(parent, name), ignore_errors=True)
+        except (ValueError, PermissionError):
+            pass
 
 
 def _ephemeral_port() -> int:
@@ -141,8 +167,7 @@ class Cluster:
         self.durable = not RaftServerConfigKeys.Log.use_memory(self.properties)
         self.storage_dir: Optional[str] = None
         if self.durable:
-            self.storage_dir = os.path.join(
-                checkout, config["storage"]["dir"])
+            self.storage_dir = run_storage_dir(checkout, config)
             RaftServerConfigKeys.set_storage_dir(self.properties,
                                                  self.storage_dir)
         self.factory = TransportFactory.get(config["transport"])
@@ -230,11 +255,15 @@ class Cluster:
             self.servers[self.leader_server(i)].bootstrap_division(
                 self.groups[i].group_id) for i in batch))
 
-    def _ready(self, i: int) -> bool:
-        d = self.servers[self.leader_server(i)].divisions.get(
-            self.groups[i].group_id)
+    @staticmethod
+    def leads(d) -> bool:
+        """``d`` is its group's leader, its startup entry committed."""
         return (d is not None and d.is_leader() and d.leader_ctx is not None
                 and d.leader_ctx.leader_ready.done())
+
+    def _ready(self, i: int) -> bool:
+        return self.leads(self.servers[self.leader_server(i)].divisions.get(
+            self.groups[i].group_id))
 
     async def _wait_ready(self, batch: list[int],
                           timeout: float = 120.0) -> None:
@@ -309,24 +338,38 @@ class Cluster:
         except AttributeError:
             return None
 
-    def leader_terms(self) -> list[int]:
-        return [self.servers[self.leader_server(i)]
-                .divisions[g.group_id].state.current_term
-                for i, g in enumerate(self.groups)]
+    def standing(self, group: int, server: int) -> dict:
+        """The group's division on ``server``: its engine slot, term, role,
+        whether it leads (``leads``) and its log's last index (-1: empty)."""
+        d = self.servers[server].divisions[self.groups[group].group_id]
+        ti = d.state.log.get_last_entry_term_index()
+        return {"server": server, "slot": d.engine_slot,
+                "term": d.state.current_term, "role": d.role.name,
+                "leads": self.leads(d),
+                "last_index": -1 if ti is None else ti.index}
 
-    def leader_last_index(self) -> list[int]:
-        """The last index of each appointed leader's log (-1: empty)."""
+    def leaders_now(self) -> list[dict]:
+        """Each group's ready leader, on whichever server it is (of two
+        that lead, the one of the higher term), as ``standing`` gives it;
+        the appointee's standing where no server leads the group."""
         out = []
-        for i, g in enumerate(self.groups):
-            ti = self.servers[self.leader_server(i)].divisions[
-                g.group_id].state.log.get_last_entry_term_index()
-            out.append(-1 if ti is None else ti.index)
+        for i in range(self.groups_n):
+            rows = [self.standing(i, s) for s in range(self.peers_n)]
+            leading = [r for r in rows if r["leads"]]
+            out.append(max(leading, key=lambda r: r["term"]) if leading
+                       else rows[self.leader_server(i)])
         return out
 
-    def leader_slots(self) -> list[int]:
-        return [self.servers[self.leader_server(i)]
-                .divisions[g.group_id].engine_slot
-                for i, g in enumerate(self.groups)]
+    async def leaders_after(self, grace_s: float) -> list[dict]:
+        """``leaders_now`` once every group has a ready leader, or after
+        ``grace_s`` at the most: an election in flight has its winner."""
+        deadline = time.monotonic() + grace_s
+        while True:
+            leaders = self.leaders_now()
+            if all(r["leads"] for r in leaders) \
+                    or time.monotonic() > deadline:
+                return leaders
+            await asyncio.sleep(0.25)
 
     def replica_values(self, group: int) -> list:
         """What each replica's state machine holds for the group: the

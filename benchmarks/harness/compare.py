@@ -9,7 +9,7 @@ rest.  Every comparison is exact, so every limit is 0."""
 from __future__ import annotations
 
 import importlib
-from typing import Optional, Sequence
+from typing import Sequence
 
 
 def load_reference(name: str):
@@ -66,22 +66,36 @@ def window_entries(answers: dict, requests: dict, groups: int) -> list[int]:
     return out
 
 
-def check_device(ref, snapshots: Sequence[dict], leader_server: Sequence[int],
-                 leader_slot: Sequence[int],
-                 commit_baseline: Optional[Sequence[int]],
-                 acked_since_baseline: Sequence[int],
-                 term_unchanged: Sequence[bool]) -> dict:
+FAILING_SHOWN = 8   # groups a failing device comparison describes
+
+
+def check_device(ref, snapshots: Sequence[dict], at_start: Sequence[dict],
+                 at_close: Sequence[dict], appointees: Sequence[int],
+                 acked_since_baseline: Sequence[int]) -> dict:
     """The device state after a drained dispatch, three ways:
 
     - ``device_rows_differing``: active rows where the device arrays differ
       from the host mirror (the program's two implementations of one step);
-    - ``device_quorum_rows_wrong``: leader rows whose device commit index is
-      not what the reference's commit rule gives from the device's own match
-      and flush indexes;
-    - ``device_commit_advance_wrong``: groups whose leader row's commit
-      index is not the baseline (where the leader's log ended before the
-      window) plus exactly the writes acknowledged since (groups whose term
-      moved since then carry an extra entry and are skipped)."""
+    - ``device_quorum_rows_wrong``: groups whose compared row's device commit
+      index is not what the reference's commit rule gives from the device's
+      own match and flush indexes;
+    - ``device_commit_advance_wrong``: groups whose compared row's commit
+      index is not the baseline (where that server's log ended before the
+      window) plus exactly the writes acknowledged since.
+
+    A group's compared row is that of the server that led it when the window
+    opened (``at_start[g]``: ``server``, ``slot``, ``term``, ``role``,
+    ``leads``, ``last_index``), not its appointee's: a leadership that an
+    election moved during the warm-up is held where it is.  ``at_close[g]``
+    is the same server's standing at the drained snapshot.  A group is
+    skipped on both (``device_commit_skipped``) only where nobody led it at
+    the start, or where that server no longer leads it in the same term: a
+    new term carries an extra entry, and a new leader keeps the commit index
+    it had while its followers' match indexes start again from nothing, so
+    the commit rule holds for it only once it has committed in its term; a
+    server that stepped down learns of the last commit only with the next
+    append.  Up to ``FAILING_SHOWN`` groups that fail either of the last two
+    are described under ``device_groups_failing``."""
     import numpy as np
     differing = 0
     for snap in snapshots:
@@ -92,29 +106,44 @@ def check_device(ref, snapshots: Sequence[dict], leader_server: Sequence[int],
             bad |= np.any((d[act] != h[act]).reshape(act.size, -1), axis=1)
         differing += int(bad.sum())
     quorum_wrong = advance_wrong = skipped = 0
-    commits = []
-    for g, (srv, slot) in enumerate(zip(leader_server, leader_slot)):
-        dev = snapshots[srv]["device"]
+    failing = []
+    for g, (start, close) in enumerate(zip(at_start, at_close)):
+        if not (start["leads"] and close["leads"]
+                and close["term"] == start["term"]):
+            skipped += 1
+            continue
+        dev = snapshots[start["server"]]["device"]
+        slot = start["slot"]
         commit = int(dev["commit_index"][slot])
-        commits.append(commit)
         self_slot = int(np.argmax(dev["self_mask"][slot]))
         rule = ref.leader_commit(dev["match_index"][slot].tolist(), self_slot,
                                  int(dev["flush_index"][slot]),
                                  dev["conf_cur"][slot].tolist())
-        if rule != commit:
-            quorum_wrong += 1
-        if commit_baseline is not None:
-            if not term_unchanged[g]:
-                skipped += 1
-            elif commit - commit_baseline[g] != acked_since_baseline[g]:
-                advance_wrong += 1
+        quorum = rule != commit
+        advance = commit - start["last_index"] != acked_since_baseline[g]
+        quorum_wrong += quorum
+        advance_wrong += advance
+        if (quorum or advance) and len(failing) < FAILING_SHOWN:
+            failing.append({
+                "group": g, "appointee": appointees[g],
+                "server": start["server"],
+                "at_start": [start["role"], start["term"]],
+                "at_close": [close["role"], close["term"]],
+                "last_index_at_start": start["last_index"],
+                "device_commit": commit, "quorum_rule": rule,
+                "acked_in_window": acked_since_baseline[g]})
     return {"device_rows_differing": differing,
             "device_quorum_rows_wrong": quorum_wrong,
             "device_commit_advance_wrong": advance_wrong,
             "device_commit_skipped": skipped,
+            "leaders_away_at_window_start": sum(
+                1 for s, a in zip(at_start, appointees)
+                if s["leads"] and s["server"] != a),
+            "leaderless_at_window_start": sum(
+                1 for s in at_start if not s["leads"]),
             "device_rows_compared": sum(int(s["active"].size)
                                         for s in snapshots),
-            "leader_commit_index": commits}
+            "device_groups_failing": failing}
 
 
 def verdict(numbers: dict) -> tuple[bool, dict]:

@@ -2,9 +2,13 @@
 configuration states and has to come out as not correct; a fault breaks the
 timed path underneath the harness.  Neither is part of a benchmark run: they
 are switched on by ``--control`` / ``--fault`` (tests, and the chip runs that
-set the limits)."""
+set the limits).  One fault, ``leader-moved``, is no fault of the program: a
+run with it has to come out correct."""
 
 from __future__ import annotations
+
+import asyncio
+import time
 
 from ratis_tpu.models.counter import CounterStateMachine
 
@@ -86,3 +90,41 @@ def freeze_device_step(engines) -> None:
 
     for e in engines:
         e._fast_kernel = lambda: stale_step
+
+
+async def move_leader(cluster, group: int, seed: int,
+                      timeout_s: float = 30.0) -> None:
+    """The fault "a leadership moved before the window": the group's
+    leadership goes from its appointee to the next peer through the
+    program's own transfer (a client's TRANSFER_LEADERSHIP), and this waits
+    until the new leader is ready.  Nothing is wrong with the program here:
+    the check has to hold the group on the row of the server that leads it
+    when the window opens, and come out correct."""
+    from benchmarks.harness.cluster import seeded_ids
+    from ratis_tpu.client import RaftClient
+    from ratis_tpu.protocol.ids import ClientId
+    g = cluster.groups[group]
+    source = cluster.leader_server(group)
+    target = (source + 1) % cluster.peers_n
+    client = (RaftClient.builder().set_raft_group(g)
+              .set_client_id(ClientId.value_of(
+                  seeded_ids(seed, 1, "fault")[0]))
+              .set_leader_id(g.peers[source].id)
+              .set_transport(cluster.factory.new_client_transport(
+                  cluster.properties))
+              .set_properties(cluster.properties).build())
+    try:
+        reply = await client.admin().transfer_leadership(
+            g.peers[target].id, timeout_ms=timeout_s * 1e3)
+    finally:
+        await client.close()
+    if not reply.success:
+        raise RuntimeError(f"leader-moved: transfer refused: "
+                           f"{reply.exception!r}")
+    division = cluster.servers[target].divisions[g.group_id]
+    deadline = time.monotonic() + timeout_s
+    while not cluster.leads(division):
+        if time.monotonic() > deadline:
+            raise RuntimeError(f"leader-moved: s{target} not ready "
+                               f"after {timeout_s}s")
+        await asyncio.sleep(0.01)

@@ -2,10 +2,10 @@
 accepted on their stream ports during the trace session, per acknowledged
 stream: the counter ``stream.connects`` (keys ``opened``,
 server/datastream.py:_RemoteStream.connect, and ``accepted``,
-transport/datastream.py:DataStreamServer._on_connect) over the window's
-acknowledged streams.  A stream down a chain of three is 5 today (the
-client's, and each of two legs at both its ends); 0 once connections are
-kept."""
+transport/datastream.py:PeerConnection.connection_made on its ``server``
+side) over the window's acknowledged streams.  A stream down a chain of
+three is 5 today (the client's, and each of two legs at both its ends); 0
+once connections are kept."""
 
 
 def read(ctx):

@@ -2,8 +2,9 @@
 off the socket to its ack written to the client's connection: the local
 channel write, the copy sent down the chain, and the successors' acks back.
 The mean of the ``stream.packet`` rows whose tag (the packet's bytes) is
-positive (server/datastream.py:_on_data_pipelined; a successor's rows carry
-the tag negated)."""
+positive: from ``_on_data``, inside the connection's read callback, to
+``_PacketAck.part_done``, where the last of the packet's parts queues its
+ack (server/datastream.py; a successor's rows carry the tag negated)."""
 
 
 def read(ctx):

@@ -1,7 +1,8 @@
 """Stream plane: what one packet's write into the peer's own file takes as
-the loop sees it, from ``channel.write`` called to its return: the hop to a
-thread, the write, the hop back.  The mean of the ``stream.write`` rows, all
-peers alike (server/datastream.py:_write_local)."""
+the loop sees it, from the packet's bytes queued to its stream's writer lane
+(``channel.submit_write``) to the write's completion seen on the loop, by the
+callback of the lane's pass that wrote it.  The mean of the ``stream.write``
+rows, all peers alike (server/datastream.py:_queue_local)."""
 
 
 def read(ctx):

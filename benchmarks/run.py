@@ -45,7 +45,7 @@ if CHECKOUT not in sys.path:
 
 DEADLINE_S = 345          # a run exits within 360 s, whatever happens
 REPLICA_WAIT_S = 60.0     # an answer that comes late is late, not wrong
-READY_GRACE_S = 30.0      # for a leadership that an election took to return
+READY_GRACE_S = 30.0      # for an election's winner (bring-up, window start)
 PIPE_LIMIT = 1 << 28      # the generator's result is one long line
 # the configuration's keys that a client reads: the generator's gets them all
 CLIENT_KEY_PREFIXES = ("raft.tpu.tcp.", "raft.grpc.", "raft.tpu.grpc.",
@@ -287,7 +287,8 @@ async def bring_up(args, resolved: dict, compiles):
     has just ended).  Returns the cluster, the configuration as run, and
     what the prewarm loaded."""
     from benchmarks.harness import faults
-    from benchmarks.harness.cluster import Cluster, raise_nofile
+    from benchmarks.harness.cluster import (Cluster, raise_nofile,
+                                            remove_dead_runs)
     config = resolved["config"]
     if args.groups:
         config = dict(config, groups=args.groups)
@@ -301,6 +302,7 @@ async def bring_up(args, resolved: dict, compiles):
             or sm_factory
     cluster = Cluster(config, args.seed, CHECKOUT, overrides, sm_factory)
     if cluster.storage_dir:
+        remove_dead_runs(os.path.dirname(cluster.storage_dir))
         shutil.rmtree(cluster.storage_dir, ignore_errors=True)
         os.makedirs(cluster.storage_dir)
     before = compiles.mark()
@@ -326,7 +328,7 @@ async def bring_up(args, resolved: dict, compiles):
 
 async def run_cell(args, resolved: dict, manifest: dict, device: dict,
                    compiles) -> dict:
-    from benchmarks.harness import compare, trace_reduce
+    from benchmarks.harness import compare, faults, trace_reduce
     from benchmarks.harness.cluster import drained_device_state
     traffic = dict(resolved["traffic"])
     cell = resolved["cell"]["name"]
@@ -339,20 +341,31 @@ async def run_cell(args, resolved: dict, manifest: dict, device: dict,
         shutil.rmtree(trace_dir, ignore_errors=True)
         annotate_dispatches(cluster.engines)
 
+    start: dict = {}
+
     async def baseline() -> dict:
-        """After the warm-up, before the window: where each leader's log
-        ends (whatever of it is committed yet), to hold the window's advance
-        on the device against."""
-        return {"last_index": cluster.leader_last_index(),
-                "terms": cluster.leader_terms()}
+        """After the warm-up, before the window: which server leads each
+        group and where its log ends (whatever of it is committed yet), to
+        hold the window's advance on the device against.  Where an election
+        is in flight the window waits for its winner, ``READY_GRACE_S`` at
+        the most (set-up)."""
+        if args.fault == "leader-moved":
+            await faults.move_leader(cluster, 0, args.seed)
+        t = time.monotonic()
+        start["leaders"] = await cluster.leaders_after(READY_GRACE_S)
+        start["wait_s"] = time.monotonic() - t
+        return start
 
     async def snapshots() -> dict:
         """At the window's close, before the settle round: acks only ever
         raise a match index, so a round of fresh acks to every group would
-        paper over any that the window's own dispatches lost."""
-        return {"snaps": [await drained_device_state(e, f"engine {i}")
-                          for i, e in enumerate(cluster.engines)],
-                "terms": cluster.leader_terms()}
+        paper over any that the window's own dispatches lost.  Then where
+        each group's leader at the start stands now."""
+        snaps = [await drained_device_state(e, f"engine {i}")
+                 for i, e in enumerate(cluster.engines)]
+        return {"snaps": snaps,
+                "standing": [cluster.standing(g, r["server"])
+                             for g, r in enumerate(start["leaders"])]}
 
     w = await drive_window(cluster, traffic, args.seed, args.seconds,
                            trace_dir, compiles, on_ready=baseline,
@@ -389,14 +402,13 @@ async def run_cell(args, resolved: dict, manifest: dict, device: dict,
     short_seen = [{"group": g, "replicas": values[g], "acked": acked[g],
                    "submitted": submitted[g], "unsettled": unsettled[g]}
                   for g in short[:8]]
-    snaps, base = w["on_drained"]["snaps"], w["on_ready"]
+    snaps = w["on_drained"]["snaps"]
     in_window = compare.window_entries(answers, w["requests"],
                                        cluster.groups_n)
     dev = compare.check_device(
-        ref, snaps, [cluster.leader_server(g)
-                     for g in range(cluster.groups_n)],
-        cluster.leader_slots(), base["last_index"], in_window,
-        [a == b for a, b in zip(w["on_drained"]["terms"], base["terms"])])
+        ref, snaps, start["leaders"], w["on_drained"]["standing"],
+        [cluster.leader_server(g) for g in range(cluster.groups_n)],
+        in_window)
     numbers = {
         "never_answered": (answers["never_answered"], 0),
         "answers_wrong": (answers["answers_wrong"], 0),
@@ -434,7 +446,6 @@ async def run_cell(args, resolved: dict, manifest: dict, device: dict,
             "replica_wait_s": replica_wait_s, "groups_short": short_seen,
             "elections_at_end": cluster.counters()["elections"],
             "device_rows_compared": dev["device_rows_compared"],
-            "device_commit_skipped": dev["device_commit_skipped"],
             "answer_samples": answers["samples"],
             "generator_imported_jax": w["generator_imported_jax"],
             "control": args.control, "fault": args.fault,
@@ -478,14 +489,28 @@ async def run_cell(args, resolved: dict, manifest: dict, device: dict,
                               "device_planes")}
     result["device"] = device
     result["compared"] = compared
+    # the window's start and the device check come last on the line: its
+    # end is what the record of a run that was not correct keeps
+    seen.update(window_start_wait_s=start["wait_s"],
+                leaders_away_at_window_start=dev[
+                    "leaders_away_at_window_start"],
+                leaderless_at_window_start=dev["leaderless_at_window_start"],
+                device_commit_skipped=dev["device_commit_skipped"],
+                device_groups_failing=dev["device_groups_failing"])
     return {"result": result, "seen": seen}
 
 
 def remove_storage(config: dict) -> None:
-    """A durable run's storage directory goes when the run ends."""
-    where = config.get("storage", {}).get("dir")
-    if where:
-        shutil.rmtree(os.path.join(CHECKOUT, where), ignore_errors=True)
+    """A durable run's storage directory goes when the run ends, and the
+    directory above it once no other run keeps one there."""
+    from benchmarks.harness.cluster import run_storage_dir
+    path = run_storage_dir(CHECKOUT, config)
+    if path:
+        shutil.rmtree(path, ignore_errors=True)
+        try:
+            os.rmdir(os.path.dirname(path))
+        except OSError:
+            pass
 
 
 def print_result(result: dict) -> None:
