@@ -156,15 +156,19 @@ def find_shared_shard_dirs(storage_root: "pathlib.Path | str"
 
 def truncate_shared_log_tail(shard_dir: "pathlib.Path | str",
                              records: int) -> int:
-    """Drop the last ``records`` records off a CLOSED server's shared
+    """Drop the last ``records`` log records off a CLOSED server's shared
     (interleaved) log shard on disk — the same lost-write-back-cache
     crash as :func:`truncate_log_tail`, but against the one per-shard
     segment sequence every co-located group appends into.  The chopped
     tail interleaves MANY groups' entries and control records, so one
     fault rewinds an arbitrary subset of the shard's groups at once.
-    Only whole records go — recovery sees a short stream, not a torn
-    one."""
-    from ratis_tpu.server.log.segmented import MAGIC, _REC_HDR, read_records
+    A group's hard state (its META, CONF and REMOVE records) stays, as a
+    per-group ``raft-meta`` beside a truncated segment does.  Only whole
+    records go — recovery sees a short stream, not a torn one."""
+    from ratis_tpu.server.log.segmented import (MAGIC, encode_record,
+                                                read_records)
+    from ratis_tpu.server.log.shared import (REC_CONF, REC_META, REC_REMOVE,
+                                             decode_shared)
     d = pathlib.Path(shard_dir)
     segs = []
     for f in d.iterdir():
@@ -177,16 +181,16 @@ def truncate_shared_log_tail(shard_dir: "pathlib.Path | str",
         if removed >= records:
             break
         payloads, _good = read_records(path)
-        keep = max(0, len(payloads) - (records - removed))
-        removed += len(payloads) - keep
-        if keep == 0:
+        keep = list(payloads)
+        i = len(keep)
+        while i > 0 and removed < records:
+            i -= 1
+            if decode_shared(keep[i])[3] in (REC_META, REC_CONF, REC_REMOVE):
+                continue
+            del keep[i]
+            removed += 1
+        if not keep:
             path.unlink()
-            continue
-        data = path.read_bytes()
-        off = len(MAGIC)
-        for _ in range(keep):
-            ln, _crc = _REC_HDR.unpack_from(data, off)
-            off += _REC_HDR.size + ln
-        with open(path, "r+b") as fh:
-            fh.truncate(off)
+        elif len(keep) < len(payloads):
+            path.write_bytes(MAGIC + b"".join(map(encode_record, keep)))
     return removed

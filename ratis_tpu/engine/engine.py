@@ -38,6 +38,10 @@ from ratis_tpu.trace.tracer import (STAGE_ACK, STAGE_COLLECT, STAGE_ENGINE,
 # keep in sync with ops.quorum.PACK_SENTINEL (not imported here: engine
 # import must not eagerly pull in jax)
 _PACK_SENTINEL = -(2 ** 31)
+# a dispatch that judges nothing (a backlog's chunks before the last): a
+# ``now`` before every deadline, a leadership timeout no gap exceeds
+_JUDGE_NOTHING_NOW = -(2 ** 31)
+_NO_TIMEOUT_MS = 2 ** 31 - 1
 
 LOG = logging.getLogger(__name__)
 
@@ -1216,17 +1220,20 @@ class QuorumEngine:
         cap = min(self._MAX_EVENT_BUCKET, self._event_bucket_cap)
         if len(acks) + len(self._slot_updates) <= cap:
             return self._tick_batched_pass(acks, now)
-        # Pathological backlog (the loop was stalled long enough for >16k
-        # events to queue): run bounded chunks through the same kernels.
-        # Duplicate commit events self-suppress in _collect_changed (device
-        # value vs mirror) and deadline disarms persist on device, so the
-        # chunk merge is a plain concatenation.
+        # Backlog over the largest compiled bucket (at 10k groups a server
+        # a heartbeat round's re-arms and acks alone): run bounded chunks
+        # through the same kernels.  Only the last chunk judges deadlines
+        # and staleness, once every chunk's events are on the device: an
+        # earlier one would fire the stale deadline of a follower whose
+        # re-arm waits in a later chunk (and judge a leader by acks not yet
+        # applied).  Duplicate commit events self-suppress in
+        # _collect_changed (device value vs mirror), so the chunk merge is
+        # a plain concatenation.
         changed: list[tuple[int, str, int]] = []
         updates_all, self._slot_updates = self._slot_updates, {}
         idx = 0
-        first = True
-        while first or idx < len(acks) or updates_all:
-            first = False
+        last = False
+        while not last:
             chunk = acks[idx:idx + cap]
             idx += cap
             room = cap - len(chunk)
@@ -1236,10 +1243,18 @@ class QuorumEngine:
                 upd[k] = v
                 room -= 1
             self._slot_updates = upd
-            changed.extend(self._tick_batched_pass(chunk, now))
+            last = idx >= len(acks) and not updates_all
+            # (the judging switch rides ``now``: a traced run wraps
+            # _tick_batched_pass as (acks, now))
+            changed.extend(self._tick_batched_pass(
+                chunk, now if last else _JUDGE_NOTHING_NOW))
         return changed
 
-    def _tick_batched_pass(self, acks, now: int) -> list[tuple[int, str, int]]:
+    def _tick_batched_pass(self, acks, now: int
+                           ) -> list[tuple[int, str, int]]:
+        """``now`` ``_JUDGE_NOTHING_NOW``: apply the events and the commit
+        math, fire no election timeout and no staleness (a ``now`` before
+        every deadline, and a leadership timeout no gap exceeds)."""
         # dispatch-latency timer: host -> XLA -> host wall for this sweep
         # (pack + upload + kernel + output download), recorded even on an
         # exception path so a wedged backend shows up in the p99
@@ -1258,6 +1273,10 @@ class QuorumEngine:
                 return self._tick_batched_dispatch(acks, now, tiles.part)
             finally:
                 tiles.close(tag=len(acks))
+
+    def _leadership_timeout_for(self, now: int) -> int:
+        return (_NO_TIMEOUT_MS if now == _JUDGE_NOTHING_NOW
+                else self.leadership_timeout_ms)
 
     def _tick_batched_dispatch(self, acks, now: int, part=_no_part
                                ) -> list[tuple[int, str, int]]:
@@ -1294,7 +1313,8 @@ class QuorumEngine:
             part(STAGE_LAUNCH)
             res = step(self._dev, jnp.asarray(ev),
                        jnp.asarray(np.array(
-                           [now, self.leadership_timeout_ms], np.int32)))
+                           [now, self._leadership_timeout_for(now)],
+                           np.int32)))
             self._dev = res.state
             part(STAGE_FETCH)
             out = np.asarray(res.out)
@@ -1341,7 +1361,7 @@ class QuorumEngine:
             jnp.asarray(s.election_deadline_ms[gi]),
             jnp.asarray(evg), jnp.asarray(evp), jnp.asarray(evm),
             jnp.asarray(evt), jnp.asarray(evv),
-            jnp.int32(now), jnp.int32(self.leadership_timeout_ms))
+            jnp.int32(now), jnp.int32(self._leadership_timeout_for(now)))
         self._dev = res.state
         part(STAGE_FETCH)
 

@@ -65,6 +65,25 @@ class Counter:
         return self._value
 
 
+class TallyCounter(Counter):
+    """A counter that also counts into ``total``: a server's sum of its
+    divisions' counts, read without walking them."""
+
+    __slots__ = ("_total",)
+
+    def __init__(self, total: Counter) -> None:
+        super().__init__()
+        self._total = total
+
+    def inc(self, n: int = 1) -> None:
+        self._value += n
+        self._total._value += n
+
+    def dec(self, n: int = 1) -> None:
+        self._value -= n
+        self._total._value -= n
+
+
 def labeled(name: str, **labels: str) -> str:
     """Canonical registry name for a labeled metric: ``name{k="v",...}``
     with keys sorted.  The Prometheus renderer splits this form back into
@@ -168,9 +187,15 @@ class RatisMetricRegistry:
         self._gauges: Dict[str, Callable[[], object]] = {}
         self._lock = threading.Lock()
 
-    def counter(self, name: str) -> Counter:
+    def counter(self, name: str, total: Optional[Counter] = None
+                ) -> Counter:
+        """``total``: a counter this one also counts into."""
         with self._lock:
-            return self._counters.setdefault(name, Counter())
+            c = self._counters.get(name)
+            if c is None:
+                c = self._counters[name] = (Counter() if total is None
+                                            else TallyCounter(total))
+            return c
 
     def timer(self, name: str) -> Timekeeper:
         with self._lock:

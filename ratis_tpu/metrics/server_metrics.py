@@ -18,7 +18,8 @@ from __future__ import annotations
 import time
 from typing import Callable, Optional
 
-from ratis_tpu.metrics.registry import (MetricRegistries, MetricRegistryInfo,
+from ratis_tpu.metrics.registry import (Counter, MetricRegistries,
+                                        MetricRegistryInfo,
                                         RatisMetricRegistry)
 
 RATIS_APPLICATION_NAME = "ratis"
@@ -74,11 +75,15 @@ class LeaderElectionMetrics(_MetricsBase):
     component = "leader_election"
     name = "leader_election"
 
-    def __init__(self, member_id) -> None:
+    def __init__(self, member_id, activity: Optional[Counter] = None
+                 ) -> None:
+        """``activity``: the server's count of its divisions' elections
+        and election timeouts together (the watchdog's churn input)."""
         super().__init__(member_id)
         r = self.registry
-        self.election_count = r.counter("electionCount")
-        self.timeout_count = r.counter("timeoutCount")  # election timeouts
+        self.election_count = r.counter("electionCount", activity)
+        # election timeouts
+        self.timeout_count = r.counter("timeoutCount", activity)
         self.election_timer = r.timer("electionTime")
         self.transfer_count = r.counter("transferLeadershipCount")
         # timeout_count ← Division.on_election_timeout;

@@ -27,8 +27,9 @@ from typing import Optional
 import numpy as np
 
 from ratis_tpu.conf.keys import RaftServerConfigKeys
-from ratis_tpu.engine.state import (ROLE_CANDIDATE, ROLE_FOLLOWER,
-                                    ROLE_LEADER, ROLE_LISTENER)
+from ratis_tpu.engine.state import (NO_DEADLINE, ROLE_CANDIDATE,
+                                    ROLE_FOLLOWER, ROLE_LEADER,
+                                    ROLE_LISTENER)
 from ratis_tpu.protocol.exceptions import (LeaderNotReadyException,
                                            LeaderSteppingDownException,
                                            NotLeaderException, RaftException,
@@ -41,7 +42,9 @@ from ratis_tpu.protocol.logentry import (LogEntry, LogEntryKind,
                                          make_transaction_entry)
 from ratis_tpu.protocol.message import Message
 from ratis_tpu.protocol.peer import RaftPeer, RaftPeerRole
-from ratis_tpu.protocol.raftrpc import (AppendEntriesReply,
+from ratis_tpu.protocol.raftrpc import (BULK_HB_HIBERNATED,
+                                        BULK_HB_NOT_LEADER, BULK_HB_OK,
+                                        AppendEntriesReply,
                                         AppendEntriesRequest, AppendResult,
                                         RaftRpcHeader, RequestVoteReply,
                                         RequestVoteRequest)
@@ -75,13 +78,13 @@ class Division:
         self.server = server
         self.group_id: RaftGroupId = group.group_id
         self.member_id = RaftGroupMemberId(server.peer_id, group.group_id)
-        self.storage = storage  # RaftStorageDirectory | None
-        metadata_io = None
-        if storage is not None:
-            from ratis_tpu.server.storage import FileMetadataIO
-            metadata_io = FileMetadataIO(storage)
+        # RaftStorageDirectory | SharedGroupStorage | None
+        self.storage = storage
+        # (term, votedFor) and the configuration entry, each storage's own
+        self.metadata_io = (storage.metadata_io() if storage is not None
+                            else None)
         self.state = ServerState(self.member_id, group, log=log,
-                                 metadata_io=metadata_io)
+                                 metadata_io=self.metadata_io)
         self.state_machine = state_machine
         state_machine.member_id = self.member_id
         # Per-entry SM notification is only dispatched when the app actually
@@ -244,7 +247,8 @@ class Division:
         from ratis_tpu.metrics import (LeaderElectionMetrics,
                                        RaftServerMetrics, StateMachineMetrics)
         self.metrics = RaftServerMetrics(self.member_id)
-        self.election_metrics = LeaderElectionMetrics(self.member_id)
+        self.election_metrics = LeaderElectionMetrics(
+            self.member_id, getattr(server, "election_activity", None))
         self.sm_metrics = StateMachineMetrics(self.member_id)
         self.sm_metrics.add_applied_index_gauge(lambda: self._applied_index)
         self.metrics.add_commit_info_gauge(
@@ -514,21 +518,19 @@ class Division:
         if self.storage is not None:
             # RECOVER path (reference ServerState.initialize:134): reload
             # (term, votedFor), init the SM (restores its latest snapshot),
-            # then open the segmented log above the snapshot.
-            # (file reads, off the loop as the directories' making is)
-            term, voted_for = await asyncio.to_thread(
-                self.storage.load_metadata)
+            # then open the log above the snapshot.
+            meta = self.metadata_io
+            term, voted_for = await meta.load()
             self.state.current_term = term
             self.state.voted_for = voted_for
-            conf_entry = await asyncio.to_thread(
-                self.storage.load_conf_entry)
+            conf_entry = await meta.load_conf()
             if conf_entry is not None:
                 self.state.apply_log_entry_configuration(conf_entry)
             else:
                 # First boot: record the bootstrap conf so a restart with an
                 # empty log still knows the group membership.
-                boot = self.state.configuration.to_entry(0, -1)
-                await asyncio.to_thread(self.storage.persist_conf_entry, boot)
+                await meta.persist_conf(
+                    self.state.configuration.to_entry(0, -1))
             await self.state_machine.initialize(
                 self.server, self.group_id, self.storage.root)
             snap = self.state_machine.get_latest_snapshot()
@@ -649,6 +651,15 @@ class Division:
     async def on_election_timeout(self) -> None:
         if not self._running or not self.is_follower():
             return
+        if self.engine_slot >= 0:
+            # The engine marks a deadline it fired NO_DEADLINE until this
+            # division re-arms it; one re-armed since (a heartbeat taken in
+            # while the tick's earlier callbacks were awaited) makes this
+            # timeout stale, and a healthy group would hold an election
+            engine = self.server.engine
+            deadline = int(engine.state.election_deadline_ms[self.engine_slot])
+            if deadline != NO_DEADLINE and deadline > engine.clock.now_ms():
+                return
         if self._election_paused \
                 or self.state.log.failed \
                 or not self.state.configuration.contains_voting(
@@ -1299,23 +1310,53 @@ class Division:
             return await self._on_bulk_heartbeat_locked(
                 leader_id, term, leader_commit, commit_term, hibernate)
 
+    def bulk_heartbeat_now(self, leader_id: RaftPeerId, term: int,
+                           leader_commit: int, commit_term: int,
+                           hibernate: bool = False
+                           ) -> Optional[tuple[int, int, int, int, int]]:
+        """``on_bulk_heartbeat`` without a wait, where it needs none: the
+        append lock free and no role to change (the idle happy path of
+        every item of a sweep).  It runs to its end without yielding, so
+        no append can interleave: the lock's guarantee without taking it.
+        None: the item needs ``on_bulk_heartbeat``."""
+        if self._append_lock.locked():
+            return None
+        if term < self.state.current_term:
+            return self._bulk_not_leader()
+        if self._bulk_role_change_due(leader_id, term):
+            return None
+        return self._bulk_heartbeat_accept(leader_commit, commit_term,
+                                           hibernate)
+
     async def _on_bulk_heartbeat_locked(self, leader_id: RaftPeerId,
                                         term: int, leader_commit: int,
                                         commit_term: int,
                                         hibernate: bool = False
                                         ) -> tuple[int, int, int, int, int]:
-        from ratis_tpu.protocol.raftrpc import (BULK_HB_HIBERNATED,
-                                                BULK_HB_NOT_LEADER,
-                                                BULK_HB_OK)
-        state = self.state
-        log = state.log
-        if term < state.current_term:
-            return (BULK_HB_NOT_LEADER, state.current_term, log.next_index,
-                    log.get_last_committed_index(), log.flush_index)
-        if term > state.current_term or not self.is_follower() \
-                or state.leader_id != leader_id:
+        if term < self.state.current_term:
+            return self._bulk_not_leader()
+        if self._bulk_role_change_due(leader_id, term):
             await self.change_to_follower(term, leader_id,
                                           reason="bulk heartbeat from leader")
+        return self._bulk_heartbeat_accept(leader_commit, commit_term,
+                                           hibernate)
+
+    def _bulk_not_leader(self) -> tuple[int, int, int, int, int]:
+        log = self.state.log
+        return (BULK_HB_NOT_LEADER, self.state.current_term, log.next_index,
+                log.get_last_committed_index(), log.flush_index)
+
+    def _bulk_role_change_due(self, leader_id: RaftPeerId,
+                              term: int) -> bool:
+        state = self.state
+        return (term > state.current_term or not self.is_follower()
+                or state.leader_id != leader_id)
+
+    def _bulk_heartbeat_accept(self, leader_commit: int, commit_term: int,
+                               hibernate: bool
+                               ) -> tuple[int, int, int, int, int]:
+        state = self.state
+        log = state.log
         self._last_heard_leader_s = asyncio.get_running_loop().time()
         self.reset_election_deadline()
         if commit_term > 0 and leader_commit > log.get_last_committed_index():
@@ -1337,7 +1378,6 @@ class Division:
             if log.get_last_committed_index() >= leader_commit \
                     and log.flush_index >= leader_commit \
                     and self.engine_slot >= 0:
-                from ratis_tpu.engine.state import NO_DEADLINE
                 if self._hibernate_backstop_s > 0:
                     # clamp: the engine's deadline array is int32 ms, and a
                     # "30d" backstop must degrade to the sentinel (full
@@ -1765,7 +1805,6 @@ class Division:
                 self.role = RaftPeerRole.LISTENER
                 self._engine_set_role(ROLE_LISTENER)
                 if self.engine_slot >= 0:
-                    from ratis_tpu.engine.state import NO_DEADLINE
                     self.server.engine.state.election_deadline_ms[
                         self.engine_slot] = NO_DEADLINE
                     self.server.engine.state.mark_dirty(self.engine_slot)
@@ -2614,8 +2653,8 @@ class Division:
                     message=reply_message or Message.EMPTY,
                     log_index=entry.index))
         elif entry.kind == LogEntryKind.CONFIGURATION:
-            if self.storage is not None:
-                await asyncio.to_thread(self.storage.persist_conf_entry, entry)
+            if self.metadata_io is not None:
+                await self.metadata_io.persist_conf(entry)
             await sm.notify_configuration_changed(
                 entry.term, entry.index, self.state.configuration)
             await self._on_conf_entry_applied(entry)

@@ -476,14 +476,16 @@ class LogAppender:
         await self._on_reply(request, reply, epoch)
         self.notify()
 
-    def heartbeat_item(self, now: float,
+    def heartbeat_item(self, now: float, fresh_for: float,
                        hibernate: bool = False) -> Optional[tuple]:
         """Contribute this follower's compact item to the sweep's
         BulkHeartbeat toward its destination server, or None when not due
         (recent traffic doubles as a heartbeat, exactly like the unary
         path).  Also doubles as the periodic fill-retry waker.  With
         ``hibernate`` the item carries the hibernate flag, asking the follower
-        to disarm its election timer (idle-group quiescence)."""
+        to disarm its election timer (idle-group quiescence).
+        ``fresh_for``: how recent a contact lets the sweep skip the follower
+        (the sweep's own reckoning, ``HeartbeatScheduler.fresh_for_s``)."""
         div = self.division
         if not self._running or not div.is_leader():
             return None
@@ -519,7 +521,7 @@ class LogAppender:
         # wake sets it: "next sweep heartbeats immediately").
         hb = self.heartbeat_interval_s
         if self._last_send_s:
-            if self._contact_fresh(now):
+            if self._contact_fresh(now, fresh_for):
                 return None  # follower demonstrably fresh (recent reply)
             if now - self._last_send_s < hb * 0.45:
                 return None  # give the in-flight contact a chance to land
@@ -557,9 +559,10 @@ class LogAppender:
                    + hb * 0.9,
                    self._last_send_s + hb * 0.45)
 
-    def _contact_fresh(self, now: float) -> bool:
-        """The follower has heard from this leader within 0.9 x the
-        heartbeat interval, for all the leader can tell: a reply came in
+    def _contact_fresh(self, now: float, fresh_for: float) -> bool:
+        """The follower has heard from this leader within ``fresh_for``
+        (the sweep's reckoning, ``HeartbeatScheduler.fresh_for_s``), for all
+        the leader can tell: a reply came in
         that lately AND it answered something sent that lately.  The reply
         alone is no proof: it says when the follower's answer got here, not
         when the follower last heard.  A heartbeat whose reply came 0.3 s
@@ -568,42 +571,41 @@ class LogAppender:
         nothing for two intervals, which is the shortest election timeout:
         every stall of the loop over 0.1 x the interval cost healthy
         leaders elections (PERF.md §7)."""
-        fresh_for = self.heartbeat_interval_s * 0.9
         return (now - self.follower.last_rpc_response_s < fresh_for
                 and now - self._last_send_s < fresh_for)
 
-    async def on_bulk_reply(self, code: int, term: int, next_index: int,
-                            follower_commit: int, flush_index: int,
-                            ack_sink: Optional[list] = None) -> None:
+    def on_bulk_reply(self, code: int, term: int, next_index: int,
+                      follower_commit: int, flush_index: int,
+                      ack_sink: Optional[list] = None) -> Optional[int]:
         """Dispatch one aligned BulkHeartbeatReply item.  Happy path keeps
         the follower fresh (staleness + watch frontiers); any anomaly
         escalates to a full AppendEntries probe on the data path, which
-        carries the prev check the compact item omits."""
+        carries the prev check the compact item omits.  Returns the
+        follower's term where it is higher than ours: the caller then steps
+        the division down (the one part that waits)."""
         from ratis_tpu.protocol.raftrpc import (BULK_HB_HIBERNATED,
                                                 BULK_HB_OK,
                                                 BULK_HB_UNKNOWN_GROUP)
         div = self.division
         if not self._running or not div.is_leader():
-            return
+            return None
         if code == BULK_HB_UNKNOWN_GROUP:
-            return  # peer doesn't host this group (e.g. mid group-add)
+            return None  # peer doesn't host this group (e.g. mid group-add)
         if term > div.state.current_term:
-            await div.change_to_follower(
-                term, None, reason="higher term in bulk heartbeat reply")
-            return
+            return term
         if code == BULK_HB_HIBERNATED:
             # follower disarmed its election timer: this channel may sleep
             self.hibernate_acked = True
             f = self.follower
             f.last_rpc_response_s = time.monotonic()
             div.on_follower_heartbeat_ack(f, ack_sink)
-            return
+            return None
         self.hibernate_acked = False  # any other reply: timer is armed
         if code != BULK_HB_OK:
             # stale NOT_LEADER at <= our term, or BUSY (the item was skipped
             # because our own in-flight append holds the division's lock —
             # that append doubles as the heartbeat): ignore, retry next sweep
-            return
+            return None
         f = self.follower
         f.last_rpc_response_s = time.monotonic()
         if follower_commit > f.commit_index:
@@ -621,6 +623,7 @@ class LogAppender:
             self.sender.mark(self)
         elif log.next_index > f.next_index:
             self.sender.mark(self)  # data pending: wake the fill path
+        return None
 
     async def _on_reply(self, request: AppendEntriesRequest,
                         reply: AppendEntriesReply, epoch: int,
@@ -686,13 +689,13 @@ class LogAppender:
 
     # ----------------------------------------------------------- heartbeats
 
-    def on_heartbeat_sweep(self, now: float) -> None:
+    def on_heartbeat_sweep(self, now: float, fresh_for: float) -> None:
         """One iteration of the unary dedicated heartbeat channel, driven by
         the SERVER-level sweep (server.HeartbeatScheduler) when bulk
         coalescing is disabled.  Semantics match the reference's dedicated
         heartbeat stream: an empty AppendEntries goes out whenever nothing
         else has been sent for an interval, regardless of window occupancy
-        (GrpcLogAppender.java:172)."""
+        (GrpcLogAppender.java:172).  ``fresh_for``: as ``heartbeat_item``'s."""
         div = self.division
         if not self._running or not div.is_leader():
             return
@@ -706,7 +709,7 @@ class LogAppender:
             f = self.follower
             interval = self.heartbeat_interval_s
             if self._last_send_s:
-                if self._contact_fresh(now):
+                if self._contact_fresh(now, fresh_for):
                     return  # follower demonstrably fresh (recent reply)
                 if now - self._last_send_s < interval * 0.45:
                     return

@@ -178,6 +178,17 @@ class LogWorker:
         # files a failed data write left with a hole, which take no record
         # any more (the thread's)
         self._dead_files: dict[object, BaseException] = {}
+        # (fsyncs, groups they made durable) of a shared log plane's worker:
+        # count_groups
+        self._group_counts = None
+
+    def count_groups(self, key: str) -> None:
+        """Count this worker's fsyncs (``log.shared.syncs``) and the
+        distinct logs whose records each one made durable
+        (``log.shared.sync_groups``), under ``key``: a worker of the shared
+        log plane, whose one file carries many groups."""
+        self._group_counts = (TRACER.counter("log.shared.syncs", key),
+                              TRACER.counter("log.shared.sync_groups", key))
 
     @property
     def metrics(self) -> dict:
@@ -346,6 +357,8 @@ class LogWorker:
         now = TRACER.now() if tracing else 0
         dead = self._dead_files
         by_file: dict[object, list[bytes]] = {}
+        counts = self._group_counts
+        synced = set() if counts is not None else None
         for rec in batch:
             if rec.t_submit:
                 # log.queue: submit -> the batch holding it taken
@@ -371,6 +384,8 @@ class LogWorker:
                 by_file[rec.fileobj] = [rec.data]
             else:
                 chunks.append(rec.data)
+            if synced is not None and rec.log is not None:
+                synced.add((rec.fileobj, rec.log))
         if not by_file:
             return
         self._writes.inc(sum(map(len, by_file.values())))
@@ -388,6 +403,9 @@ class LogWorker:
         with self.registry_metrics.flush_timer.time():
             self._do_io(by_file, tracing)
         self.registry_metrics.flush_count.inc()
+        if counts is not None:
+            counts[0].n += len(by_file)
+            counts[1].n += len(synced)
 
     def _do_io(self, by_file: dict, tracing: bool) -> None:
         # log.write / log.fsync: work spans on this thread (tag = distinct

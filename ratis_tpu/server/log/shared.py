@@ -13,12 +13,20 @@ of groups land in ONE file: the per-device LogWorker issues one buffered
 write + one fsync per drain regardless of group count (fsyncs/commit
 ~1/groups instead of ~1).
 
-Layout (under the peer's storage root, sibling of the per-group dirs —
-``scan_group_dirs`` skips it because the name is not a group uuid)::
+Layout (under the peer's storage root)::
 
     <root>/_sharedlog/shard-<k>/
         shared_<n>              sealed segments, n monotonic
         shared_inprogress_<n>   the open segment (at most one)
+        in_use.lock             exclusive-use marker while the store is open
+
+Nothing else of a group's is on disk: its term, vote and configuration are
+records of the same sequence, so adding a group makes no directory and no
+file (a group directory appears only when something of the group's own
+needs one, a state-machine snapshot).  Upstream Ratis keeps a ``raft-meta``
+and ``raft-meta.conf`` per group directory (RaftStorageDirectoryImpl), as the
+per-group layout here still does; a store that hosts thousands of groups
+keeps their hard state in its one log, as dense multi-Raft stores do.
 
 Record format — the segmented store's CRC frame with a shared header::
 
@@ -31,6 +39,18 @@ Record format — the segmented store's CRC frame with a shared header::
     rtype 2 PURGE      group drops entries <= group_index (term records the
                        boundary so recovery can restore the below-start
                        TermIndex after a full purge)
+    rtype 3 META       the group's (term, votedFor): term in the header,
+                       body = the voted-for peer id, UTF-8 (empty: none)
+    rtype 4 CONF       the group's latest configuration entry: the header
+                       holds its index and term, body = LogEntry msgpack
+    rtype 5 REMOVE     the group is gone: every earlier record of it is dead
+
+A META or CONF persist completes when the batch that carries it is fsynced,
+as a per-group ``raft-meta`` write does with its tmp + fsync + rename; the
+one forward scan at recovery takes for each group the highest term (a stale
+persist never regresses it), the configuration of the highest index, and
+finds the groups a server hosts: those with a CONF record and no REMOVE
+after it.
 
 A follower rewind (the windowed-rewind path) therefore never rewrites
 shared bytes: truncate appends a tombstone and drops in-memory tail state;
@@ -50,9 +70,9 @@ Compaction: tombstones/purges/overwrites mark the victim records' bytes
 dead per segment.  When a sealed segment's dead ratio crosses the
 configured threshold it is rewritten in place (tmp + rename) keeping live
 entries and all control records — dropping a tombstone would let the
-stale entries it killed in an *earlier* segment resurrect on replay, so
-control records (a few dozen bytes each) are retained until their segment
-retires entirely.
+stale entries it killed in an *earlier* segment resurrect on replay, and
+a group's META and CONF records are its hard state — so control records (a
+few dozen bytes each) are retained until their segment retires entirely.
 """
 
 from __future__ import annotations
@@ -74,12 +94,22 @@ from ratis_tpu.protocol.termindex import INVALID_LOG_INDEX, TermIndex
 from ratis_tpu.server.log.base import RaftLog
 from ratis_tpu.server.log.segmented import (MAGIC, _REC_HDR, LogWorker,
                                             encode_record, read_records)
+from ratis_tpu.trace.tracer import TRACER
 
 _SH_HDR = struct.Struct("<16sqqB")
+
+LOCK_FILE = "in_use.lock"
 
 REC_ENTRY = 0
 REC_TOMBSTONE = 1
 REC_PURGE = 2
+REC_META = 3
+REC_CONF = 4
+REC_REMOVE = 5
+RECORD_KINDS = ("entry", "tombstone", "purge", "meta", "conf", "remove")
+# records queued on any shard, by kind
+_RECORDS = tuple(TRACER.counter("log.shared.records", k)
+                 for k in RECORD_KINDS)
 
 _SEALED_RE = re.compile(r"^shared_(\d+)$")
 _OPEN_RE = re.compile(r"^shared_inprogress_(\d+)$")
@@ -99,6 +129,28 @@ def encode_shared(gid: bytes, index: int, term: int, rtype: int,
 def decode_shared(payload: bytes) -> tuple[bytes, int, int, int, bytes]:
     gid, index, term, rtype = _SH_HDR.unpack_from(payload, 0)
     return gid, index, term, rtype, payload[_SH_HDR.size:]
+
+
+class HardState:
+    """A group's term, vote and latest configuration entry, as its META and
+    CONF records hold them (``conf``: the entry's bytes, None before the
+    group's first CONF record)."""
+
+    __slots__ = ("term", "voted_for", "conf_index", "conf")
+
+    def __init__(self) -> None:
+        self.term = 0
+        self.voted_for: Optional[str] = None
+        self.conf_index = INVALID_LOG_INDEX - 1
+        self.conf: Optional[bytes] = None
+
+    def set_meta(self, term: int, voted_for: Optional[str]) -> None:
+        if term >= self.term:
+            self.term, self.voted_for = term, voted_for
+
+    def set_conf(self, index: int, conf: bytes) -> None:
+        if index >= self.conf_index:
+            self.conf_index, self.conf = index, conf
 
 
 class _GroupState:
@@ -121,6 +173,24 @@ class _GroupState:
     @property
     def last(self) -> int:
         return self.first + len(self.terms) - 1
+
+    def loc_at(self, index: int) -> Optional[tuple[int, int]]:
+        """(segment, offset) of a live entry, for compaction liveness."""
+        i = index - self.first
+        if 0 <= i < len(self.terms):
+            seg_n, off, _ = self.locs[i]
+            return seg_n, off
+        return None
+
+    def relocate(self, index: int, seg_n: int, old_off: int, new_off: int,
+                 rec_len: int) -> bool:
+        """Post-compaction pointer fixup; False if the entry died."""
+        i = index - self.first
+        if 0 <= i < len(self.terms) \
+                and self.locs[i] == (seg_n, old_off, rec_len):
+            self.locs[i] = (seg_n, new_off, rec_len)
+            return True
+        return False
 
 
 class _ScanState:
@@ -176,6 +246,8 @@ class SharedLogStore:
         self._sealing_seg = -1                # mid-seal: compaction keep-out
         self._recovered: dict[bytes, _GroupState] = {}
         self._groups: dict[bytes, "SharedGroupLog"] = {}
+        # every hosted group's hard state: from the scan, then each persist
+        self._hard: dict[bytes, HardState] = {}
         self._roll_lock = asyncio.Lock()
         self._compact_task: Optional[asyncio.Task] = None
         import threading
@@ -186,20 +258,43 @@ class SharedLogStore:
         self.metrics.add_store_gauges(
             lambda: self.total_bytes,
             lambda: len(self.worker._queue))
+        # what one fsync of this shard makes durable: log.shared.syncs and
+        # log.shared.sync_groups (LogWorker._write)
+        worker.count_groups(name)
 
     # ------------------------------------------------------------ lifecycle
 
-    def acquire(self, glog: "SharedGroupLog") -> None:
-        self._refs += 1
-        self._groups[glog.gid] = glog
+    def open(self) -> None:
+        """Start the worker and run the recovery scan, once (the first
+        acquire does, or a server's boot scan before any group is added)."""
         if not self._opened:
+            # one server a shard, as per-group storage has one a directory
+            # (storage is imported here: it imports this module)
+            from ratis_tpu.server.storage import lock_in_use
+            self.dir.mkdir(parents=True, exist_ok=True)
+            lock_in_use(self.dir / LOCK_FILE)
             self._opened = True
             self.worker.acquire()
             self._recover()
 
+    def acquire(self, glog: "SharedGroupLog") -> None:
+        self._refs += 1
+        self._groups[glog.gid] = glog
+        self.open()
+
     async def release(self, glog: "SharedGroupLog") -> None:
         self._groups.pop(glog.gid, None)
         self._refs -= 1
+        if self._refs > 0:
+            if not glog.removed:
+                # the group may be added again while the store stays open
+                self._recovered[glog.gid] = glog._st
+            return
+        await self.close_if_idle()
+
+    async def close_if_idle(self) -> None:
+        """Close the store if it is open and no group holds it (a boot scan
+        that found no group leaves it so)."""
         if self._refs > 0 or not self._opened:
             return
         self._opened = False
@@ -219,6 +314,7 @@ class SharedLogStore:
                 os.close(fd)
             self._fds.clear()
         await self.worker.release()
+        (self.dir / LOCK_FILE).unlink(missing_ok=True)
         self.metrics.unregister()
         if self._on_final_release is not None:
             self._on_final_release()
@@ -233,8 +329,19 @@ class SharedLogStore:
     def take_recovered(self, gid: bytes) -> _GroupState:
         return self._recovered.pop(gid, None) or _GroupState()
 
+    def hard_state(self, gid: bytes) -> HardState:
+        """The group's hard state (zero term, no vote, no configuration for
+        a group this store has no record of)."""
+        return self._hard.get(gid) or HardState()
+
+    def hosted_groups(self) -> list[bytes]:
+        """The groups recovery found: each with a configuration record and
+        no removal after it."""
+        return [gid for gid, h in self._hard.items() if h.conf is not None]
+
     def _recover(self) -> None:
         self.dir.mkdir(parents=True, exist_ok=True)
+        self._recovered, self._hard = {}, {}
         found: list[tuple[int, bool, pathlib.Path]] = []
         for f in self.dir.iterdir():
             m = _SEALED_RE.match(f.name)
@@ -293,6 +400,17 @@ class SharedLogStore:
         stream has been applied.
         """
         gid, index, term, rtype, body = decode_shared(payload)
+        if rtype == REC_META:
+            self._hard.setdefault(gid, HardState()).set_meta(
+                term, body.decode() if body else None)
+            return
+        if rtype == REC_CONF:
+            self._hard.setdefault(gid, HardState()).set_conf(index, body)
+            return
+        if rtype == REC_REMOVE:
+            states.pop(gid, None)
+            self._hard.pop(gid, None)
+            return
         st = states.get(gid)
         if st is None:
             st = states[gid] = _ScanState()
@@ -413,6 +531,7 @@ class SharedLogStore:
         worker's awaitable record, segment, offset, length)."""
         self._ensure_open()
         rec = encode_shared(gid, index, term, rtype, body)
+        _RECORDS[rtype].n += 1
         off = self._open_size
         queued = self.worker.submit(
             self._open_file, rec, glog,
@@ -508,6 +627,12 @@ class SharedLogStore:
             LOG.exception("%s: compaction of segment %d failed",
                           self.name, seg_n)
 
+    def _index_of(self, gid: bytes) -> Optional[_GroupState]:
+        """A group's entry index: its open log's, or the one kept for it
+        since the boot scan or its close (a re-add takes it up)."""
+        glog = self._groups.get(gid)
+        return glog._st if glog is not None else self._recovered.get(gid)
+
     async def _compact_impl(self, seg_n: int) -> None:
         """Rewrite sealed segment ``seg_n`` keeping live entries and all
         control records.  Appends continue concurrently (they only touch
@@ -535,8 +660,8 @@ class SharedLogStore:
             gid, index, _, rtype, _ = decode_shared(rec[_REC_HDR.size:])
             keep = True
             if rtype == REC_ENTRY:
-                glog = self._groups.get(gid)
-                keep = glog is None or glog.loc_at(index) == (seg_n, off)
+                st = self._index_of(gid)
+                keep = st is None or st.loc_at(index) == (seg_n, off)
             if keep:
                 new_off = len(out)
                 out += rec
@@ -561,9 +686,9 @@ class SharedLogStore:
         self._sizes[seg_n] = len(out)
         dead = 0
         for gid, index, old_off, new_off, rec_len in moves:
-            glog = self._groups.get(gid)
-            if glog is not None and glog.relocate(index, seg_n, old_off,
-                                                  new_off, rec_len):
+            st = self._index_of(gid)
+            if st is not None and st.relocate(index, seg_n, old_off,
+                                              new_off, rec_len):
                 continue
             dead += rec_len  # died while we were rewriting
         self._dead[seg_n] = dead
@@ -587,23 +712,75 @@ class SharedGroupLog(RaftLog):
         self.gid = gid
         self._st = _GroupState()
         self._entries: dict[int, LogEntry] = {}
+        self._attached = False
+        # set by the group's removal: close() writes its REMOVE record
+        self.removed = False
         from ratis_tpu.metrics import SegmentedRaftLogMetrics
         self.metrics = SegmentedRaftLogMetrics(name)
 
     # ------------------------------------------------------------ open/close
 
+    def attach(self) -> None:
+        """Hold the store (open and recovered) from the group's first use
+        of it, the read of its hard state, to its log's close."""
+        if not self._attached:
+            self._attached = True
+            self.store.acquire(self)
+
     async def open(self, last_index_on_snapshot: int = INVALID_LOG_INDEX) -> None:
         await super().open(last_index_on_snapshot)
-        self.store.acquire(self)
+        self.attach()
         self._st = self.store.take_recovered(self.gid)
         self._flush_index = self.next_index - 1
         # whatever the shard file gives back holds no state-machine data
         self._data_released = self._flush_index
 
     async def close(self) -> None:
-        await self.store.release(self)
+        if self._attached:
+            self._attached = False
+            if self.removed:
+                await self._write_removal()
+            await self.store.release(self)
         self.metrics.unregister()
         await super().close()
+
+    async def _write_removal(self) -> None:
+        """The group's last record: everything of it before is dead."""
+        queued, *_ = self.store.submit_record(self.gid, INVALID_LOG_INDEX, 0,
+                                              REC_REMOVE)
+        self.store._hard.pop(self.gid, None)
+        self._st = _GroupState()
+        self._entries.clear()
+        await queued
+
+    # ------------------------------------------------------------ hard state
+
+    def hard_state(self) -> HardState:
+        self.attach()
+        return self.store.hard_state(self.gid)
+
+    def persist_meta(self, term: int, voted_for: Optional[str]):
+        """Queue the group's (term, votedFor) as a META record; returns what
+        to await for it to be on the disk.  Submitted in call order (no
+        await before the queue), so a later persist is a later record."""
+        self.attach()
+        store = self.store
+        store._hard.setdefault(self.gid, HardState()).set_meta(term,
+                                                               voted_for)
+        return store.submit_record(
+            self.gid, INVALID_LOG_INDEX, term, REC_META,
+            b"" if voted_for is None else voted_for.encode(), glog=self)[0]
+
+    def persist_conf(self, entry: LogEntry):
+        """Queue the group's configuration entry as a CONF record; returns
+        what to await for it to be on the disk."""
+        self.attach()
+        store = self.store
+        body = entry.to_bytes()
+        store._hard.setdefault(self.gid, HardState()).set_conf(entry.index,
+                                                               body)
+        return store.submit_record(self.gid, entry.index, entry.term,
+                                   REC_CONF, body, glog=self)[0]
 
     # --------------------------------------------------------------- indices
 
@@ -612,6 +789,17 @@ class SharedGroupLog(RaftLog):
         st = self._st
         if st.count:
             return st.first
+        if st.below_start is not None:
+            return st.below_start.index + 1
+        return 0
+
+    @property
+    def next_index(self) -> int:
+        # the base's, without a TermIndex a call: every heartbeat item and
+        # its reply read it
+        st = self._st
+        if st.terms:
+            return st.first + len(st.terms)
         if st.below_start is not None:
             return st.below_start.index + 1
         return 0
@@ -634,26 +822,6 @@ class SharedGroupLog(RaftLog):
         if st.below_start is not None and index == st.below_start.index:
             return st.below_start
         return None
-
-    def loc_at(self, index: int) -> Optional[tuple[int, int]]:
-        """(segment, offset) of a live entry, for compaction liveness."""
-        st = self._st
-        i = index - st.first
-        if st.count and 0 <= i < st.count:
-            seg_n, off, _ = st.locs[i]
-            return seg_n, off
-        return None
-
-    def relocate(self, index: int, seg_n: int, old_off: int, new_off: int,
-                 rec_len: int) -> bool:
-        """Post-compaction pointer fixup; False if the entry died."""
-        st = self._st
-        i = index - st.first
-        if st.count and 0 <= i < st.count \
-                and st.locs[i] == (seg_n, old_off, rec_len):
-            st.locs[i] = (seg_n, new_off, rec_len)
-            return True
-        return False
 
     # ----------------------------------------------------------------- reads
 

@@ -32,7 +32,7 @@ from ratis_tpu.protocol.termindex import TermIndex
 from ratis_tpu.server.division import Division
 from ratis_tpu.server.statemachine import StateMachine
 from ratis_tpu.trace.tracer import (LAYER_CONSENSUS, LAYER_EDGE, LAYER_READS,
-                                    LAYER_STREAM, TRACER)
+                                    LAYER_STREAM, STAGE_GROUP_ADD, TRACER)
 from ratis_tpu.transport.base import ServerTransport, TransportFactory
 from ratis_tpu.util.lifecycle import LifeCycle, LifeCycleState
 
@@ -54,6 +54,10 @@ class HeartbeatScheduler:
     without it, each appender sends its own unary AppendEntries heartbeat
     (the reference's cost shape)."""
 
+    # the longest contact a sweep may skip a follower for, as a share of
+    # the interval: the bound of an idle loop
+    FRESH_SHARE = 0.9
+
     def __init__(self, server: "RaftServer", interval_s: float,
                  shard: Optional[int] = None, service=None):
         self.server = server
@@ -69,6 +73,10 @@ class HeartbeatScheduler:
         # array mode (raft.tpu.upkeep.enabled): this shard's UpkeepPlane;
         # None keeps the legacy per-division walk below bit-for-bit
         self.plane = None
+        # how long ago a leader may have last sent to a follower and still
+        # skip it: what the last sweep's period and length leave of the
+        # followers' shortest election timeout (``_fresh_for``)
+        self.fresh_for_s = self.FRESH_SHARE * interval_s
 
     def start(self) -> None:
         self._running = True
@@ -102,66 +110,94 @@ class HeartbeatScheduler:
 
     async def _run(self) -> None:
         import time as _time
+        # Fixed rate: a sweep is due an interval after the last one was due,
+        # not after it ended, so its own length does not stretch every
+        # follower's gap (at 10,240 groups a server it is a large part of a
+        # second on a busy loop); one that ends late is followed after
+        # half an interval at the least.
+        due = _time.monotonic() + self.interval_s
+        last = None
         while self._running:
-            await asyncio.sleep(self.interval_s)
+            await asyncio.sleep(max(0.0, due - _time.monotonic()))
             now = _time.monotonic()
+            due = max(due + self.interval_s, now + self.interval_s / 2)
             self._sweep_seq += 1
             if self.plane is not None:
                 await self._sweep_plane(now)
                 continue
-            coalesce = self.server.heartbeat_coalescing
-            # destination -> ([bulk items], [appenders], aligned)
-            bulk: dict[RaftPeerId, tuple[list, list]] = {}
-            sweep = 0
-            for i, div in enumerate(list(self.server.divisions.values())):
-                if self.shard is not None \
-                        and self.server.shard_of_group(div.group_id) \
-                        != self.shard:
-                    continue  # another shard's scheduler owns this division
-                # One division's failure must never kill the single
-                # server-wide heartbeat task — that silently collapses every
-                # leadership on the server with no recovery path.
-                try:
-                    if not div.is_leader() or div.leader_ctx is None:
-                        continue
-                    if (self._sweep_seq + i) % 4 == 0:
-                        # priority-yield scan is O(followers) python; its
-                        # urgency is seconds, so a quarter-rate phase-spread
-                        # scan keeps the sweep cheap at thousands of leaders
-                        div.check_yield_to_higher_priority()
-                    hib = (div.hibernate_sweep(now) if coalesce
-                           else "awake")
-                    if hib == "asleep":
-                        continue  # hibernated: the group costs nothing
-                    for appender in list(div.leader_ctx.appenders.values()):
-                        sweep += 1
-                        if coalesce:
-                            item = appender.heartbeat_item(
-                                now, hibernate=(hib == "request"))
-                            if item is not None:
-                                b = bulk.setdefault(
-                                    appender.follower.peer_id, ([], []))
-                                b[0].append(item)
-                                b[1].append(appender)
-                        else:
-                            appender.on_heartbeat_sweep(now)
-                        if sweep % 1024 == 0:
-                            # Yield so the sweep never stalls the loop for
-                            # one giant synchronous burst — but COARSELY: on
-                            # a saturated loop every yield waits out the
-                            # whole ready backlog, and at 40960 items a
-                            # per-256 cadence stretched the sweep past the
-                            # election timeout (followers of healthy
-                            # leaders heard 16s+ of silence and deposed
-                            # them).  1024 items ≈ tens of ms per stretch.
-                            await asyncio.sleep(0)
-                except asyncio.CancelledError:
-                    raise
-                except Exception:
-                    LOG.exception("heartbeat sweep failed for %s",
-                                  div.member_id)
-            for to, (items, appenders) in bulk.items():
-                self.service.submit(to, items, appenders)
+            if last is not None:
+                self.fresh_for_s = self._fresh_for(now - last[0], last[1])
+            await self._sweep(now)
+            last = (now, _time.monotonic() - now)
+
+    def _fresh_for(self, period_s: float, length_s: float) -> float:
+        """How long after its last send to a follower a leader may skip it
+        in this sweep.  A follower skipped now hears next in the next sweep:
+        after what is left of this window, a period and a sweep's length,
+        taken as the last ones were.  That has to fall inside its shortest
+        election timeout (two intervals: the interval is half of it) with a
+        tenth of an interval to spare; never longer than ``FRESH_SHARE``
+        of an interval, the bound of an idle loop."""
+        hb = self.interval_s
+        return max(0.0, min(self.FRESH_SHARE * hb,
+                            1.9 * hb - period_s - length_s))
+
+    async def _sweep(self, now: float) -> None:
+        """The legacy walk: every leader division's appenders."""
+        coalesce = self.server.heartbeat_coalescing
+        # destination -> ([bulk items], [appenders], aligned)
+        bulk: dict[RaftPeerId, tuple[list, list]] = {}
+        sweep = 0
+        for i, div in enumerate(list(self.server.divisions.values())):
+            if self.shard is not None \
+                    and self.server.shard_of_group(div.group_id) \
+                    != self.shard:
+                continue  # another shard's scheduler owns this division
+            # One division's failure must never kill the single
+            # server-wide heartbeat task — that silently collapses every
+            # leadership on the server with no recovery path.
+            try:
+                if not div.is_leader() or div.leader_ctx is None:
+                    continue
+                if (self._sweep_seq + i) % 4 == 0:
+                    # priority-yield scan is O(followers) python; its
+                    # urgency is seconds, so a quarter-rate phase-spread
+                    # scan keeps the sweep cheap at thousands of leaders
+                    div.check_yield_to_higher_priority()
+                hib = (div.hibernate_sweep(now) if coalesce
+                       else "awake")
+                if hib == "asleep":
+                    continue  # hibernated: the group costs nothing
+                for appender in list(div.leader_ctx.appenders.values()):
+                    sweep += 1
+                    if coalesce:
+                        item = appender.heartbeat_item(
+                            now, self.fresh_for_s,
+                            hibernate=(hib == "request"))
+                        if item is not None:
+                            b = bulk.setdefault(
+                                appender.follower.peer_id, ([], []))
+                            b[0].append(item)
+                            b[1].append(appender)
+                    else:
+                        appender.on_heartbeat_sweep(now, self.fresh_for_s)
+                    if sweep % 1024 == 0:
+                        # Yield so the sweep never stalls the loop for
+                        # one giant synchronous burst — but COARSELY: on
+                        # a saturated loop every yield waits out the
+                        # whole ready backlog, and at 40960 items a
+                        # per-256 cadence stretched the sweep past the
+                        # election timeout (followers of healthy
+                        # leaders heard 16s+ of silence and deposed
+                        # them).  1024 items ≈ tens of ms per stretch.
+                        await asyncio.sleep(0)
+            except asyncio.CancelledError:
+                raise
+            except Exception:
+                LOG.exception("heartbeat sweep failed for %s",
+                              div.member_id)
+        for to, (items, appenders) in bulk.items():
+            self.service.submit(to, items, appenders)
 
     async def _sweep_plane(self, now: float) -> None:
         """Array-mode sweep: ONE vectorized due-scan over the shard's
@@ -231,14 +267,14 @@ class HeartbeatScheduler:
                 sweep += 1
                 if coalesce:
                     item = appender.heartbeat_item(
-                        now, hibernate=(hib == "request"))
+                        now, self.fresh_for_s, hibernate=(hib == "request"))
                     if item is not None:
                         b = bulk.setdefault(
                             appender.follower.peer_id, ([], []))
                         b[0].append(item)
                         b[1].append(appender)
                 else:
-                    appender.on_heartbeat_sweep(now)
+                    appender.on_heartbeat_sweep(now, self.fresh_for_s)
                 if sweep % 1024 == 0:
                     # same coarse yield discipline as the legacy walk
                     await asyncio.sleep(0)
@@ -316,7 +352,11 @@ class BulkHeartbeatService:
                     else None)
         for appender, item in zip(appenders, reply.items):
             try:
-                await appender.on_bulk_reply(*item, ack_sink=ack_rows)
+                higher = appender.on_bulk_reply(*item, ack_sink=ack_rows)
+                if higher is not None:
+                    await appender.division.change_to_follower(
+                        higher, None,
+                        reason="higher term in bulk heartbeat reply")
             except asyncio.CancelledError:
                 raise
             except Exception:
@@ -488,6 +528,10 @@ class RaftServer:
         # unset no listener socket is ever opened.
         self.metrics_http = None
         self.watchdog = None
+        # every division's elections and election timeouts, counted as
+        # they happen (the watchdog's churn input, read without a walk)
+        from ratis_tpu.metrics.registry import Counter
+        self.election_activity = Counter()
         # Continuous telemetry (raft.tpu.telemetry.*): the background
         # time-series sampler + flight recorder, created in start() only
         # when enabled — off is zero-cost, identical paths.
@@ -739,14 +783,10 @@ class RaftServer:
         # (reference RaftServerProxy.initGroups:257-288).
         root = self._storage_root()
         if root is not None:
-            from ratis_tpu.server.storage import (RaftStorageDirectory,
-                                                  scan_group_dirs)
             from ratis_tpu.server.config import RaftConfiguration
-            for gid in scan_group_dirs(root):
+            for gid, conf_entry in await self._stored_groups(root):
                 if gid in self.divisions:
                     continue
-                sd = RaftStorageDirectory(root, gid)
-                conf_entry = sd.load_conf_entry()
                 if conf_entry is None:
                     LOG.warning("%s: storage for %s has no conf; skipping",
                                 self.peer_id, gid)
@@ -762,6 +802,41 @@ class RaftServer:
             await self.datastream.start()
             self._datastream_started = True
         self.life_cycle.transition(LifeCycleState.RUNNING)
+
+    async def _stored_groups(self, root: str) -> list:
+        """(group id, its configuration entry or None) of every group kept
+        under ``root``: by its directory in the per-group layout, by its
+        records in the shared one (the shard scans that find them are the
+        stores' own recovery, which the groups' divisions then take up)."""
+        if not RaftServerConfigKeys.TpuLog.shared(self.properties):
+            from ratis_tpu.server.storage import (RaftStorageDirectory,
+                                                  scan_group_dirs)
+            return [(gid, RaftStorageDirectory(root, gid).load_conf_entry())
+                    for gid in scan_group_dirs(root)]
+        from ratis_tpu.protocol.logentry import LogEntry
+        from ratis_tpu.server.log.shared import SHARED_DIR
+        import pathlib
+        found = []
+        base = pathlib.Path(root) / SHARED_DIR
+        n = self.shards.n if self.shards is not None else 1
+        for shard in range(n):
+            if not (base / f"shard-{shard}").is_dir():
+                continue
+            store = self._shared_log_store(root, shard)
+
+            async def scan(store=store):
+                store.open()
+                groups = [(gid, store.hard_state(gid).conf)
+                          for gid in store.hosted_groups()]
+                if not groups:
+                    await store.close_if_idle()
+                return groups
+
+            groups = (await scan() if self.shards is None
+                      else await self.shards.run_on(shard, scan()))
+            found += [(RaftGroupId.value_of(gid), LogEntry.from_bytes(conf))
+                      for gid, conf in groups]
+        return found
 
     async def close(self) -> None:
         if not self.life_cycle.compare_and_transition(
@@ -913,6 +988,11 @@ class RaftServer:
             raise AlreadyExistsException(f"{self.peer_id} already hosts {group.group_id}")
         self._adding.add(group.group_id)
         try:
+            if TRACER.enabled:
+                # server.group_add: the loop's part of adding a group, up to
+                # its first wait
+                return await TRACER.head(STAGE_GROUP_ADD,
+                                         self._add_reserved_division(group))
             return await self._add_reserved_division(group)
         finally:
             self._adding.discard(group.group_id)
@@ -938,6 +1018,17 @@ class RaftServer:
                     "log_factory cannot be combined with durable storage; "
                     "set raft.server.log.use.memory=true")
             log = self._log_factory(self, group)
+        elif root is not None \
+                and RaftServerConfigKeys.TpuLog.shared(self.properties):
+            # the shard's one log holds the group's entries and hard state:
+            # no directory, no lock file, no file of the group's own
+            from ratis_tpu.server.log.shared import SharedGroupLog
+            from ratis_tpu.server.storage import SharedGroupStorage
+            store = self._shared_log_store(
+                root, self.shard_of_group(group.group_id))
+            log = SharedGroupLog(f"log-{self.peer_id}-{group.group_id}",
+                                 group.group_id.to_bytes(), store)
+            storage = SharedGroupStorage(root, group.group_id, log)
         elif root is not None:
             from ratis_tpu.server.log.segmented import LogWorker, SegmentedRaftLog
             from ratis_tpu.server.storage import RaftStorageDirectory
@@ -951,22 +1042,13 @@ class RaftServer:
             # directories as one stall of seconds, which costs every group
             # this server already hosts its heartbeats (PERF.md §7)
             await asyncio.to_thread(format_and_lock)
-            if RaftServerConfigKeys.TpuLog.shared(self.properties):
-                from ratis_tpu.server.log.shared import SharedGroupLog
-                store = self._shared_log_store(root,
-                                               self.shard_of_group(
-                                                   group.group_id))
-                log = SharedGroupLog(
-                    f"log-{self.peer_id}-{group.group_id}",
-                    group.group_id.to_bytes(), store)
-            else:
-                log = SegmentedRaftLog(
-                    f"log-{self.peer_id}-{group.group_id}", storage.current,
-                    worker=LogWorker.shared(f"{self.peer_id}:{root}"),
-                    segment_size_max=RaftServerConfigKeys.Log
-                    .segment_size_max(self.properties),
-                    cache_segments_max=RaftServerConfigKeys.Log
-                    .segment_cache_num_max(self.properties))
+            log = SegmentedRaftLog(
+                f"log-{self.peer_id}-{group.group_id}", storage.current,
+                worker=LogWorker.shared(f"{self.peer_id}:{root}"),
+                segment_size_max=RaftServerConfigKeys.Log
+                .segment_size_max(self.properties),
+                cache_segments_max=RaftServerConfigKeys.Log
+                .segment_cache_num_max(self.properties))
         div = Division(self, group, sm, log=log, storage=storage)
         self.divisions[group.group_id] = div
         if self._gc_disciplined:
@@ -999,6 +1081,10 @@ class RaftServer:
             gcdiscipline.note_mutation()
         await div.state_machine.notify_group_remove()
         storage = div.storage
+        from ratis_tpu.server.storage import SharedGroupStorage
+        if delete_directory and isinstance(storage, SharedGroupStorage):
+            # the shared layout: the log's close writes the group's REMOVE
+            storage.mark_removed()
         await self._run_on_division_loop(group_id, div.close())
         if delete_directory and storage is not None:
             import shutil
@@ -1548,9 +1634,13 @@ class RaftServer:
                     results[n] = busy
                 else:
                     try:
-                        results[n] = await div.on_bulk_heartbeat(
-                            src, term, commit, commit_term,
-                            hibernate=hibernate)
+                        r = div.bulk_heartbeat_now(src, term, commit,
+                                                   commit_term, hibernate)
+                        if r is None:
+                            r = await div.on_bulk_heartbeat(
+                                src, term, commit, commit_term,
+                                hibernate=hibernate)
+                        results[n] = r
                     except Exception:
                         LOG.exception("%s bulk heartbeat item failed",
                                       self.peer_id)

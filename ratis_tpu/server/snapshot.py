@@ -123,6 +123,7 @@ class SnapshotInstaller:
                     else pathlib.Path("/tmp"))
             # Promote ONLY files completed and MD5-verified in this install;
             # leftovers from aborted installs stay out of sm/.
+            sm_dir.mkdir(parents=True, exist_ok=True)
             for name in self._verified:
                 tmp = base / (name + ".install")
                 if tmp.exists():

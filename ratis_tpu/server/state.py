@@ -119,12 +119,19 @@ class ServerState:
 
 
 class MetadataIO:
-    """Abstract (term, votedFor) persistence; storage milestone supplies the
-    atomic-file implementation (cf. raft-meta,
-    RaftStorageDirectoryImpl.java:41)."""
+    """Abstract persistence of (term, votedFor) and the configuration entry:
+    per group directory the atomic files (``storage.FileMetadataIO``, cf.
+    raft-meta, RaftStorageDirectoryImpl.java:41), on the shared log plane the
+    shard's records (``storage.SharedMetadataIO``)."""
 
     async def persist(self, term: int, voted_for: Optional[RaftPeerId]) -> None:
         pass
 
     async def load(self) -> tuple[int, Optional[RaftPeerId]]:
         return 0, None
+
+    async def persist_conf(self, entry) -> None:
+        """The latest configuration entry (cf. raft-meta.conf)."""
+
+    async def load_conf(self):
+        return None

@@ -72,8 +72,9 @@ class StateMachineStorage:
         self._dir: Optional[pathlib.Path] = None
 
     def init(self, sm_dir: "str | pathlib.Path") -> None:
+        """Snapshots go to ``sm_dir``, made with the first of them (a group
+        on the shared log plane has no directory until then)."""
         self._dir = pathlib.Path(sm_dir)
-        self._dir.mkdir(parents=True, exist_ok=True)
 
     @property
     def directory(self) -> Optional[pathlib.Path]:
@@ -82,6 +83,7 @@ class StateMachineStorage:
     def snapshot_path(self, term: int, index: int) -> pathlib.Path:
         # file pattern snapshot.<term>_<index>, cf. SimpleStateMachineStorage
         assert self._dir is not None, "storage not initialized"
+        self._dir.mkdir(parents=True, exist_ok=True)
         return self._dir / f"{self.SNAPSHOT_PREFIX}.{term}_{index}"
 
     def find_latest_snapshot(self) -> Optional[SnapshotInfo]:
@@ -105,7 +107,7 @@ class StateMachineStorage:
                             (SnapshotFileInfo(str(best[2])),))
 
     def clean_old_snapshots(self, retention: int) -> None:
-        if self._dir is None or retention < 0:
+        if self._dir is None or retention < 0 or not self._dir.exists():
             return
         snaps = []
         for f in self._dir.iterdir():

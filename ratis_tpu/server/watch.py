@@ -64,11 +64,16 @@ class _Queue:
 class WatchRequests:
     def __init__(self, timeout_s: float = 10.0, element_limit: int = 65536):
         self.queues = {lvl: _Queue(lvl) for lvl in ReplicationLevel}
+        self._heaps = [q.heap for q in self.queues.values()]
         self.timeout_s = timeout_s
         self.element_limit = element_limit
 
     def pending_count(self) -> int:
-        return sum(len(q.heap) for q in self.queues.values())
+        # (every heartbeat reply asks: no generator)
+        n = 0
+        for h in self._heaps:
+            n += len(h)
+        return n
 
     async def watch(self, index: int, level: ReplicationLevel,
                     call_id: int = 0) -> int:

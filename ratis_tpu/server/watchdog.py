@@ -3,9 +3,10 @@
 No reference analog — the reference leaves "is the cluster making
 progress?" to external alerting over its metrics; the multi-raft host
 here can answer it locally, cheaply, from state it already maintains.  A
-single per-server sampling task (``raft.tpu.watchdog.*``) walks the
-division fleet every interval and journals structured events for three
-failure shapes the perf rounds have actually hit:
+single per-server sampling task (``raft.tpu.watchdog.*``) reads the lag
+ledger's one pass over the engine's arrays every interval (it walks no
+division) and journals structured events for the failure shapes the perf
+rounds have actually hit:
 
 - **commit-stall**: a leader's commitIndex is flat across consecutive
   samples while client requests are pending — the shape of a lost quorum
@@ -122,7 +123,8 @@ class StallWatchdog:
         self.on_event = None
         self._task: Optional[asyncio.Task] = None
         self._running = False
-        # group -> (last commitIndex, consecutive flat-with-pending rounds)
+        # the last sample's per-slot commit, generation and leader mask,
+        # and each slot's consecutive flat-with-pending rounds
         self._stall: dict = {}
         # groups currently inside a reported stall / lag episode: one event
         # per episode, not one per sample
@@ -239,46 +241,18 @@ class StallWatchdog:
                               self.server.peer_id)
 
     def sample(self) -> None:
-        """One detection pass over the division fleet (synchronous reads
-        only).  Public so tests and harnesses can force a pass."""
-        elections = 0
-        seen = set()
-        for div in list(self.server.divisions.values()):
-            gid = str(div.group_id)
-            seen.add(gid)
-            em = div.election_metrics
-            elections += em.timeout_count.count + em.election_count.count
-            if not div.is_leader() or div.leader_ctx is None:
-                self._stall.pop(gid, None)
-                self._stalled.discard(gid)
-                continue
-            commit = int(div.state.log.get_last_committed_index())
-            pending = len(div.leader_ctx.pending)
-            last_commit, rounds = self._stall.get(gid, (None, 0))
-            if pending > 0 and commit == last_commit:
-                rounds += 1
-            else:
-                rounds = 0
-                self._stalled.discard(gid)
-            self._stall[gid] = (commit, rounds)
-            if rounds >= _STALL_ROUNDS and gid not in self._stalled:
-                self._stalled.add(gid)
-                self.emit(KIND_COMMIT_STALL, gid,
-                          f"commitIndex flat at {commit} for "
-                          f"{rounds * self.interval_s:.1f}s with "
-                          f"{pending} pending request(s)")
-        # drop bookkeeping for removed groups
-        for gid in list(self._stall):
-            if gid not in seen:
-                self._stall.pop(gid, None)
-        self._stalled &= seen
-        # follower lag + grey detection read the lag ledger (one fused
-        # pass + one fetch) instead of walking leader_ctx.followers
+        """One detection pass (synchronous reads only).  Public so tests
+        and harnesses can force a pass.  Nothing here walks the division
+        fleet: commit stalls, follower lag and grey followers read one
+        lag-ledger pass (one fused pass + one fetch), election churn the
+        server's own count."""
         led = self._ledger_sample()
         if led is not None:
+            self._check_commit_stall(led)
             self._check_follower_lag(led)
             self._check_grey(led)
         # election churn: rate of new election activity per interval
+        elections = self.server.election_activity.count
         if self._last_elections is not None:
             delta = elections - self._last_elections
             if delta >= self.churn_threshold:
@@ -289,6 +263,40 @@ class StallWatchdog:
         self._last_elections = elections
         self._check_stuck_lanes()
         self._check_overload()
+
+    def _check_commit_stall(self, s) -> None:
+        """Leaders whose commit index stayed flat with requests pending for
+        ``_STALL_ROUNDS`` samples in a row, from the ledger's per-slot
+        commit, pending depth and allocation generation: python touches
+        only the stalled slots.  A slot counts a round only if it led, in
+        the same allocation, at the last sample too."""
+        import numpy as np
+        watched = s.leader_mask & (s.pending > 0)
+        last = self._stall
+        if last and len(last["commit"]) == s.capacity:
+            flat = (watched & last["leader"] & (s.gen == last["gen"])
+                    & (s.commit == last["commit"]))
+            rounds = np.where(flat, last["rounds"] + 1, 0)
+        else:
+            rounds = np.zeros(s.capacity, np.int64)
+        self._stall = {"commit": s.commit, "gen": s.gen,
+                       "leader": s.leader_mask, "rounds": rounds}
+        engine = self.server.engine
+        current: set = set()
+        for slot in np.nonzero(rounds >= _STALL_ROUNDS)[0]:
+            listener = engine._listeners.get(int(slot))
+            if listener is None:
+                continue  # detached mid-pass
+            gid = str(listener.group_id)
+            current.add(gid)
+            if gid in self._stalled:
+                continue
+            self._stalled.add(gid)
+            self.emit(KIND_COMMIT_STALL, gid,
+                      f"commitIndex flat at {int(s.commit[slot])} for "
+                      f"{int(rounds[slot]) * self.interval_s:.1f}s with "
+                      f"{int(s.pending[slot])} pending request(s)")
+        self._stalled &= current
 
     def _ledger_sample(self):
         """One lag-ledger pass (engine/ledger.py); None if the engine is

@@ -54,7 +54,7 @@ SCANNED = (
 
 # (file, qualified function) -> why this per-group walk is allowed to stay.
 ALLOWLIST: dict[tuple[str, str], str] = {
-    ("ratis_tpu/server/server.py", "HeartbeatScheduler._run"):
+    ("ratis_tpu/server/server.py", "HeartbeatScheduler._sweep"):
         "legacy-mode sweep (raft.tpu.upkeep.enabled unset)",
     ("ratis_tpu/server/server.py", "HeartbeatScheduler._plane_resync"):
         "low-rate O(G) re-arm backstop (raft.tpu.upkeep.resync-sweeps)",
@@ -64,8 +64,6 @@ ALLOWLIST: dict[tuple[str, str], str] = {
         "error-path message formatting",
     ("ratis_tpu/server/server.py", "RaftServer.divisions_info"):
         "GET /divisions introspection endpoint",
-    ("ratis_tpu/server/watchdog.py", "StallWatchdog.sample"):
-        "watchdog cadence is seconds, not the sweep tick",
     ("ratis_tpu/server/pause_monitor.py",
      "PauseMonitor._step_down_leaders"):
         "pause recovery, runs only after a detected stall",

@@ -150,7 +150,13 @@ STAGE_STREAM_FORCE = 39   # stream.force — a channel's force, on the forcing
                           # thread (tag = bytes since the last force)
 STAGE_STREAM_LINK = 40    # stream.link — take_link -> data_link returned, at
                           # apply
-NUM_STAGES = 41
+# A group's hard state on the shared log plane, and a group's bring-up.
+STAGE_LOG_META = 41       # log.meta — one persist of a group's term and vote
+                          # or configuration, from its call to its record's
+                          # fsync seen on the loop (tag = record kind)
+STAGE_GROUP_ADD = 42      # server.group_add — one group added to a server:
+                          # its storage, its Division built and started
+NUM_STAGES = 43
 
 STAGE_NAMES = (
     "client.send", "codec.encode", "codec.decode", "wire.rtt",
@@ -166,6 +172,7 @@ STAGE_NAMES = (
     "grpc.read", "grpc.write",
     "stream.header", "stream.packet", "stream.write", "stream.close",
     "stream.force", "stream.link",
+    "log.meta", "server.group_add",
 )
 
 # W = work span: a stretch that is synchronous on one thread by construction
@@ -186,6 +193,7 @@ STAGE_KINDS = (
     "I", "W", "W",
     "W", "W",
     "I", "I", "I", "I", "W", "I",
+    "I", "W",
 )
 
 # Work spans happen once per batch, several batches per commit: their rings
@@ -248,6 +256,7 @@ STAGE_LAYERS = (
     "log", "sm", "sm",
     "wire", "wire",
     "stream", "stream", "stream", "stream", "stream", "stream",
+    "log", "edge",
 )
 _STAGE_LAYER = tuple(-1 if n is None else LAYER_NAMES.index(n)
                      for n in STAGE_LAYERS)

@@ -583,6 +583,52 @@ def test_sweep_gate_does_not_delay_election_timeout():
     asyncio.run(run())
 
 
+@pytest.mark.parametrize("wrapped", [False, True])
+def test_a_backlog_in_chunks_fires_no_deadline_a_later_chunk_rearms(wrapped):
+    """A backlog over the largest compiled bucket goes to the device in
+    chunks; deadlines are judged once every chunk is on the device.  A
+    follower re-armed in time never fires because its re-arm sat in a
+    later chunk, and one that did expire still fires, once.  ``wrapped``:
+    each chunk goes through ``_tick_batched_pass(acks, now)``, the call a
+    traced benchmark run wraps (benchmarks/run.py:annotate_dispatches)."""
+    async def run():
+        e = _mk_engine(use_device=True, max_groups=256)
+        e._event_bucket_cap = 64
+        if wrapped:
+            inner = e._tick_batched_pass
+
+            def two_arguments(acks, now):
+                return inner(acks, now)
+            e._tick_batched_pass = two_arguments
+        recs = [Recorder() for _ in range(150)]
+        slots = [e.attach(r) for r in recs]
+        s = e.state
+        cur = np.zeros(s.max_peers, bool)
+        cur[:3] = True
+        for slot in slots:
+            s.set_conf(slot, 0, cur, np.zeros(s.max_peers, bool),
+                       np.zeros(s.max_peers, np.int32), 0)
+            s.role[slot] = ROLE_FOLLOWER
+            s.mark_dirty(slot)
+            e.on_deadline(slot, 500)
+        await e.tick()  # upload, every deadline armed on the device
+        e.clock.t = 400
+        for slot in slots[:-1]:     # 149 re-arms: three chunks of 64
+            e.on_deadline(slot, 900)
+        e.clock.t = 600
+        await e.tick()
+        fired = [i for i, r in enumerate(recs) if r.events]
+        assert fired == [len(slots) - 1]
+        assert recs[-1].events == ["timeout"]
+        assert all(s.election_deadline_ms[slot] == 900
+                   for slot in slots[:-1])
+        e.clock.t = 901
+        await e.tick()
+        assert all(r.events == ["timeout"] for r in recs)
+
+    asyncio.run(run())
+
+
 def test_sweep_gate_ships_backlog_before_staleness_check():
     """Accumulated (gated) acks must reach the device BEFORE the staleness
     sweep evaluates — a leader steadily receiving acks during the gated

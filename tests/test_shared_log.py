@@ -190,6 +190,28 @@ class TestSharedStoreBasics:
 
         run(body())
 
+    def test_a_second_store_on_an_open_shard_is_refused(self, tmp_path):
+        """One server a shard: a second store opening a shard that another
+        holds open is refused (the per-group layout's in_use.lock), and
+        takes it once the first has closed."""
+        from ratis_tpu.protocol.exceptions import RaftException
+
+        async def body():
+            store = make_store(tmp_path, "wl1")
+            la = SharedGroupLog("ga", GID_A, store)
+            await la.open()
+            assert (tmp_path / "in_use.lock").exists()
+            other = make_store(tmp_path, "wl2")
+            with pytest.raises(RaftException, match="locked"):
+                other.open()
+            await la.close()
+            assert not (tmp_path / "in_use.lock").exists()
+            other.open()
+            assert other.take_recovered(GID_A).count == 0
+            await other.close_if_idle()
+
+        run(body())
+
     def test_corrupt_sealed_segment_raises(self, tmp_path):
         async def body():
             store = make_store(tmp_path, "ws", segment_size_max=256)
@@ -533,3 +555,342 @@ class TestSharedDurableCluster:
                 await cluster.close()
 
         run(body_shared())
+
+
+class TestSharedHardState:
+    """A group's term, vote and configuration as records of its shard."""
+
+    def test_a_stale_persist_does_not_overwrite_a_newer_term(self, tmp_path):
+        from ratis_tpu.protocol.ids import RaftPeerId
+        from ratis_tpu.server.storage import SharedMetadataIO
+
+        async def body():
+            store = make_store(tmp_path, "wm1")
+            la = SharedGroupLog("ga", GID_A, store)
+            meta = SharedMetadataIO(la)
+            await meta.persist(5, RaftPeerId.value_of("s1"))
+            await meta.persist(3, RaftPeerId.value_of("s2"))  # dropped
+            # a record written late by another writer of the same group
+            # regresses nothing at recovery either
+            await la.persist_meta(4, "s2")
+            await meta.persist(5, None)   # same term: the later one stands
+            assert await meta.load() == (5, None)
+            await la.close()
+
+            store2 = make_store(tmp_path, "wm2")
+            lb = SharedGroupLog("ga", GID_A, store2)
+            h = lb.hard_state()
+            assert (h.term, h.voted_for) == (5, None)
+            await lb.close()
+
+        run(body())
+
+    def test_compaction_keeps_a_groups_latest_metadata_record(self,
+                                                             tmp_path):
+        from ratis_tpu.protocol.ids import RaftPeerId
+        from ratis_tpu.server.config import RaftConfiguration
+        from ratis_tpu.server.storage import SharedMetadataIO
+        from tests.minicluster import MiniCluster as MC
+
+        conf = RaftConfiguration.from_peers(MC(3).group.peers)
+
+        async def body():
+            store = make_store(tmp_path, "wc1", segment_size_max=2048,
+                               compaction_dead_ratio=0.3)
+            la = SharedGroupLog("ga", GID_A, store)
+            lb = SharedGroupLog("gb", GID_B, store)
+            meta = SharedMetadataIO(la)
+            await meta.persist_conf(conf.to_entry(0, -1))
+            await meta.persist(1, RaftPeerId.value_of("s1"))
+            await meta.persist(2, RaftPeerId.value_of("s2"))
+            await la.open()
+            await lb.open()
+            for i in range(60):
+                await la.append_entry(entry(1, i, size=64))
+                await lb.append_entry(entry(1, i, size=64))
+            first_segment = min(store._sizes)
+            size_before = store._sizes[first_segment]
+            await la.purge(55)
+            # compaction takes the worst segment at a time: until the first,
+            # which holds the group's hard state, has been rewritten
+            for _ in range(100):
+                if store._compact_task is not None:
+                    await store._compact_task
+                if store._sizes[first_segment] < size_before:
+                    break
+                store.maybe_compact()
+                await asyncio.sleep(0)
+            assert store._sizes[first_segment] < size_before
+            await la.close()
+            await lb.close()
+
+            store2 = make_store(tmp_path, "wc2")
+            la2 = SharedGroupLog("ga", GID_A, store2)
+            meta2 = SharedMetadataIO(la2)
+            assert await meta2.load() == (2, RaftPeerId.value_of("s2"))
+            loaded = await meta2.load_conf()
+            assert loaded is not None and loaded.index == -1
+            assert RaftConfiguration.from_entry(loaded).all_peers() == \
+                conf.all_peers()
+            assert sorted(store2.hosted_groups()) == [GID_A]
+            await la2.open()
+            assert la2.start_index == 56 and la2.next_index == 60
+            await la2.close()
+
+        run(body())
+
+    def test_a_removed_group_does_not_come_back(self, tmp_path):
+        from ratis_tpu.server.config import RaftConfiguration
+        from tests.minicluster import MiniCluster as MC
+
+        boot = RaftConfiguration.from_peers(MC(3).group.peers).to_entry(0, -1)
+
+        async def body():
+            store = make_store(tmp_path, "wr1")
+            la = SharedGroupLog("ga", GID_A, store)
+            lb = SharedGroupLog("gb", GID_B, store)
+            for lg in (la, lb):
+                await lg.persist_conf(boot)
+                await lg.persist_meta(3, "s0")
+                await lg.open()
+                for i in range(5):
+                    await lg.append_entry(entry(3, i))
+            la.removed = True
+            await la.close()
+            # re-added while the store is open: a fresh group
+            la_again = SharedGroupLog("ga", GID_A, store)
+            assert la_again.hard_state().term == 0
+            await la_again.open()
+            assert la_again.next_index == 0
+            await la_again.close()
+            await lb.close()
+
+            store2 = make_store(tmp_path, "wr2")
+            store2.open()
+            assert store2.hosted_groups() == [GID_B]
+            assert store2.hard_state(GID_A).term == 0
+            assert store2.take_recovered(GID_A).count == 0
+            assert store2.take_recovered(GID_B).count == 5
+            await store2.close_if_idle()
+
+        run(body())
+
+
+    def test_a_group_closed_and_added_again_keeps_its_log(self, tmp_path):
+        """Closed without removal while another group holds the store open,
+        a group added again finds its entries and hard state where they
+        were."""
+        async def body():
+            store = make_store(tmp_path, "wa1")
+            la = SharedGroupLog("ga", GID_A, store)
+            lb = SharedGroupLog("gb", GID_B, store)
+            await la.persist_meta(2, "s1")
+            await la.open()
+            await lb.open()
+            for i in range(5):
+                await la.append_entry(entry(2, i))
+            await la.close()
+            again = SharedGroupLog("ga", GID_A, store)
+            await again.open()
+            assert again.next_index == 5 and again.flush_index == 4
+            again.evict_cache(5)
+            assert again.get(3).index == 3
+            assert again.hard_state().term == 2
+            await again.close()
+            await lb.close()
+
+        run(body())
+
+
+    def test_a_closed_groups_entries_move_with_a_compaction(self,
+                                                            tmp_path):
+        """A compaction while a group is closed (and the store open) moves
+        the entries its kept index points at: added again, the group reads
+        them from where they now are."""
+        async def body():
+            store = make_store(tmp_path, "wm1", segment_size_max=2048,
+                               compaction_dead_ratio=0.3)
+            la = SharedGroupLog("ga", GID_A, store)
+            lb = SharedGroupLog("gb", GID_B, store)
+            await la.open()
+            await lb.open()
+            for i in range(30):
+                await la.append_entry(entry(1, i, size=64))
+                await lb.append_entry(entry(1, i, size=64))
+            await la.close()
+            first_segment = min(store._sizes)
+            size_before = store._sizes[first_segment]
+            await lb.purge(25)
+            for _ in range(100):
+                if store._compact_task is not None:
+                    await store._compact_task
+                if store._sizes[first_segment] < size_before:
+                    break
+                store.maybe_compact()
+                await asyncio.sleep(0)
+            assert store._sizes[first_segment] < size_before
+            again = SharedGroupLog("ga", GID_A, store)
+            await again.open()
+            assert again.next_index == 30
+            for i in range(30):
+                e = again.get(i)
+                assert e is not None and e.index == i and e.term == 1
+            await again.close()
+            await lb.close()
+
+        run(body())
+
+
+def _peer_root(tmp_path, peer_id) -> "os.PathLike":
+    # the server roots storage at <dir>/<peer_id>, and the cluster's dir is
+    # already <tmp>/<peer_id>
+    return tmp_path / str(peer_id) / str(peer_id)
+
+
+def _bare_server(cluster, peer_id):
+    """A server of the cluster's peer that is told of no group: what it
+    hosts, it finds in its storage."""
+    from ratis_tpu.conf import RaftServerConfigKeys
+    from ratis_tpu.server.server import RaftServer
+    props = cluster.properties.clone()
+    RaftServerConfigKeys.set_storage_dir(
+        props, f"{cluster.storage_root}/{peer_id}")
+    return RaftServer(peer_id, cluster.group.get_peer(peer_id).address,
+                      state_machine_registry=lambda gid: cluster.sm_factory(),
+                      properties=props, transport_factory=cluster.factory)
+
+
+class TestSharedHardStateCluster:
+    def _props(self):
+        from ratis_tpu.conf import RaftServerConfigKeys
+        p = fast_properties()
+        RaftServerConfigKeys.Log.set_use_memory(p, False)
+        RaftServerConfigKeys.TpuLog.set_shared(p, True)
+        return p
+
+    def test_term_vote_and_conf_survive_restarts_with_no_group_directory(
+            self, tmp_path):
+        """A full-cluster restart and a follower's crash: each division
+        comes back with the term, vote and configuration it had, read from
+        its shard, and no peer's storage holds anything but the shards."""
+        async def body():
+            cluster = MiniCluster(3, properties=self._props(),
+                                  storage_root=str(tmp_path))
+            gid = cluster.group.group_id
+            await cluster.start()
+            try:
+                await cluster.wait_for_leader()
+                for _ in range(3):
+                    assert (await cluster.send_write()).success
+                before = {d.member_id.peer_id: (d.state.current_term,
+                                                d.state.voted_for)
+                          for d in cluster.divisions()}
+                for pid in list(cluster.servers):
+                    await cluster.kill_server(pid)
+                for pid in before:
+                    assert [p.name for p in _peer_root(tmp_path, pid)
+                            .iterdir()] == ["_sharedlog"]
+                for pid in list(cluster._stopped):
+                    await cluster.restart_server(pid)
+                for pid, (term, voted) in before.items():
+                    d = cluster.servers[pid].divisions[gid]
+                    assert d.state.current_term >= term
+                    if d.state.current_term == term:
+                        assert d.state.voted_for == voted
+                    assert d.state.configuration.all_peers() == \
+                        cluster.group.peers
+                await cluster.wait_for_leader()
+                assert (await cluster.send_write()).message.content == b"4"
+
+                follower = next(d for d in cluster.divisions()
+                                if not d.is_leader())
+                fid = follower.member_id.peer_id
+                term, voted = (follower.state.current_term,
+                               follower.state.voted_for)
+                await cluster.kill_server(fid)
+                store = SharedLogStore(shard_dir(_peer_root(tmp_path, fid), 0),
+                                       LogWorker(f"peek-{tmp_path.name}"))
+                store.open()
+                h = store.hard_state(gid.to_bytes())
+                assert (h.term, h.voted_for) == (
+                    term, None if voted is None else voted.id)
+                assert h.conf is not None
+                await store.close_if_idle()
+                await cluster.restart_server(fid)
+                d = cluster.servers[fid].divisions[gid]
+                assert d.state.current_term >= term
+                last = (await cluster.wait_for_leader()).state.log \
+                    .get_last_committed_index()
+                await cluster.wait_applied(last, divisions=[d], timeout=20.0)
+                assert d.state_machine.counter == 4
+            finally:
+                await cluster.close()
+
+        run(body())
+
+    def test_a_servers_boot_finds_its_groups_in_the_shard_alone(self,
+                                                               tmp_path):
+        """Servers told of no group find theirs in their shards; a group
+        removed with its directory is not found again."""
+        from ratis_tpu.protocol.group import RaftGroup
+        from ratis_tpu.protocol.ids import RaftGroupId
+
+        async def body():
+            cluster = MiniCluster(3, properties=self._props(),
+                                  storage_root=str(tmp_path))
+            gid = cluster.group.group_id
+            gone = RaftGroup.value_of(RaftGroupId.random_id(),
+                                      cluster.group.peers)
+            await cluster.start()
+            servers = []
+            try:
+                await cluster.wait_for_leader()
+                assert (await cluster.send_write()).success
+                for s in cluster.servers.values():
+                    await s.group_add(gone)
+                for s in cluster.servers.values():
+                    await s.group_remove(gone.group_id,
+                                         delete_directory=True)
+                for pid in list(cluster.servers):
+                    await cluster.kill_server(pid)
+                servers = [_bare_server(cluster, pid)
+                           for pid in cluster._stopped]
+                await asyncio.gather(*(s.start() for s in servers))
+                for s in servers:
+                    assert s.group_ids() == [gid]
+                    assert [p.name for p in _peer_root(tmp_path, s.peer_id)
+                            .iterdir()] == ["_sharedlog"]
+                for s in servers:
+                    cluster.servers[s.peer_id] = s
+                cluster._stopped.clear()
+                await cluster.wait_for_leader()
+                assert (await cluster.send_write()).message.content == b"2"
+            finally:
+                await cluster.close()
+
+        run(body())
+
+    def test_without_the_key_each_group_keeps_its_own_files(self, tmp_path):
+        """The per-group layout as it is without raft.tpu.log.shared:
+        lock, raft-meta, raft-meta.conf, sm/ and tmp/ in each group's
+        directory, and no shard."""
+        async def body():
+            cluster = MiniCluster(3, storage_root=str(tmp_path))
+            gid = cluster.group.group_id
+            await cluster.start()
+            try:
+                await cluster.wait_for_leader()
+                assert (await cluster.send_write()).success
+                for pid in cluster.servers:
+                    group_dir = _peer_root(tmp_path, pid) / str(gid.uuid)
+                    assert sorted(p.name for p in group_dir.iterdir()) == [
+                        "current", "in_use.lock", "sm", "tmp"]
+                    names = {p.name for p in (group_dir / "current")
+                             .iterdir()}
+                    assert {"raft-meta", "raft-meta.conf"} <= names
+                    assert not (_peer_root(tmp_path, pid)
+                                / "_sharedlog").exists()
+            finally:
+                await cluster.close()
+
+        run(body())
